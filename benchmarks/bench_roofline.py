@@ -130,7 +130,7 @@ def main(argv=None):
         q, f = (int(t) for t in args.mesh.lower().split("x"))
     else:
         q, f = 1, len(jax.devices())
-    mesh = jax.make_mesh((q, f), ("query", "feature"))
+    mesh = D.make_mesh((q, f), ("query", "feature"))
 
     n, p = (64, 4096) if args.quick else (256, 1 << 14)
     K = args.num_lambdas or (8 if args.quick else 16)

@@ -21,6 +21,8 @@ def main() -> None:
     # float64 for solver-grade duality gaps (paper used doubles)
     import jax
     jax.config.update("jax_enable_x64", True)
+    from repro.launch.cli import use_compile_cache
+    use_compile_cache()
 
     from . import (bench_basic_rules, bench_batched, bench_dpp_family,
                    bench_group, bench_kernels, bench_roofline,

@@ -52,7 +52,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     q, f = (int(t) for t in args.mesh.lower().split("x"))
-    mesh = jax.make_mesh((q, f), ("query", "feature"))
+    mesh = D.make_mesh((q, f), ("query", "feature"))
     print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))}")
 
     n, p = (128, 1 << 12) if args.quick else (256, 1 << 15)

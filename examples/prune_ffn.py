@@ -22,6 +22,7 @@ import numpy as np
 from repro.configs.common import dense_lm
 from repro.core import (GroupPathConfig, group_lambda_max, group_lasso_path,
                         lambda_grid)
+from repro.core.distributed import make_mesh
 from repro.data import SyntheticLM, device_batch
 from repro.models import model as M
 from repro.models.layers import ffn_forward, rmsnorm
@@ -30,7 +31,7 @@ from repro.train import steps as ST
 
 
 def main():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = dense_lm("prunable", n_layers=2, d_model=128, n_heads=4,
                    n_kv_heads=4, d_head=32, d_ff=256, vocab=4000)
     tc = ST.TrainConfig(opt=adamw.OptConfig(lr=3e-3, warmup_steps=5,

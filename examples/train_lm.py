@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs.common import dense_lm
 from repro.checkpoint import latest_step, restore, save
+from repro.core.distributed import make_mesh
 from repro.data import SyntheticLM, device_batch
 from repro.optim import adamw
 from repro.train import steps as ST
@@ -43,7 +44,7 @@ def main():
     args = ap.parse_args()
 
     dshape = tuple(int(x) for x in args.mesh.split("x"))
-    mesh = jax.make_mesh(dshape, ("data", "model")[: len(dshape)])
+    mesh = make_mesh(dshape, ("data", "model")[: len(dshape)])
 
     if args.tiny:
         cfg = dense_lm("lm-tiny", n_layers=4, d_model=256, n_heads=4,

@@ -30,9 +30,13 @@ ScreenBackend (name ``"shard:<tile>"``) that
 ``LassoSession.fit(X, mesh=...)`` drops into the unsharded engines.
 
 Everything here is written with `shard_map` for explicit collective control
-(the hillclimb in EXPERIMENTS.md §Perf compares against the GSPMD/pjit
-auto-sharded version, `pjit_screen`). ``check_rep=False`` throughout: a
-``pallas_call`` has no replication rule under shard_map.
+(the GSPMD/pjit auto-sharded version is `pjit_screen`). ``check_vma=False``
+throughout: a ``pallas_call`` has no replication rule under shard_map.
+
+Meshes have ``Auto`` axes (:func:`make_mesh`, :func:`auto_mesh`): the
+engines index, gather and reduce feature-sharded arrays with plain jnp and
+leave the collectives to GSPMD, which an ``Explicit`` axis (the default of
+``jax.make_mesh``) refuses.
 
 The same code paths lower on the production meshes of launch/mesh.py —
 `launch/dryrun.py` compiles them at (16,16) and (2,16,16).
@@ -45,8 +49,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from ..kernels import ops
 from .engine import resolve_backend
@@ -57,6 +61,22 @@ from .solver import resolve_solver_backend
 #: feature (model-parallel) axis — a mesh without this axis is pure
 #: feature sharding (the pre-2D layout, still fully supported).
 QUERY_AXIS = "query"
+
+
+def make_mesh(shape, names, *, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto`` (see the module doc)."""
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
+
+
+def auto_mesh(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis ``Auto``: the same devices and axis names,
+    so a mesh built by plain ``jax.make_mesh`` is accepted as well."""
+    auto = (AxisType.Auto,) * len(mesh.axis_names)
+    if tuple(mesh.axis_types) == auto:
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names, axis_types=auto)
 
 
 def query_axes(mesh: Mesh) -> tuple[str, ...]:
@@ -148,9 +168,11 @@ def sharded_backend(mesh: Mesh, tile=None) -> ops.ScreenBackend:
     resolve_tiles` shrinks the kernel tiles to the local block so a narrow
     shard doesn't pay full-tile padding. Outputs stay feature-sharded
     (batched centres additionally shard over the query axis when B divides
-    it). The solver ops pass through to the tile unchanged: the path
-    driver's reduced buckets are gathered REPLICATED, so the fused solver
-    kernels run on whole (replicated) arrays without remapping.
+    it). The solver ops run the tile's kernel whole on every device: the
+    path driver's reduced buckets are gathered REPLICATED, and a Mosaic
+    kernel in a program that spans several devices must sit inside
+    ``shard_map`` (the compiler cannot partition it), so they are wrapped
+    with replicated specs.
 
     ``tile`` is a backend name, a ScreenBackend, or None (auto-detect:
     ``REPRO_SCREEN_BACKEND`` → ``INTERPRET=1`` → platform default). The
@@ -173,7 +195,7 @@ def sharded_backend(mesh: Mesh, tile=None) -> ops.ScreenBackend:
         w = wrapped.get(key)
         if w is None:
             w = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+                          out_specs=out_specs, check_vma=False)
             wrapped[key] = w
         return w
 
@@ -197,11 +219,31 @@ def sharded_backend(mesh: Mesh, tile=None) -> ops.ScreenBackend:
         q = _qspec(mesh, centre.shape[0])
         rho_b = jnp.broadcast_to(rho, centre.shape[:1])
         # sumsq is query-independent — identical on every query shard, so
-        # its out_spec mentions only the feature axes (check_rep=False
+        # its out_spec mentions only the feature axes (check_vma=False
         # takes the local copy)
         w = _shmap(("fs", 2, q), tile.fused_scores,
                    (P(None, f), P(q, None), P(q)), (P(q, f), P(f)))
         return w(X, centre, rho_b)
+
+    def replicated(fn):
+        """``fn`` run whole on every device. Array keywords (``valid=``)
+        become operands; the others (``sweeps=``) stay static."""
+        if fn is None:
+            return None
+
+        def call(*args, **kw):
+            ops_kw = {k: v for k, v in kw.items()
+                      if isinstance(v, (jax.Array, np.ndarray))}
+            static = tuple(sorted((k, v) for k, v in kw.items()
+                                  if k not in ops_kw))
+            n, names = len(args), tuple(ops_kw)
+
+            def local(*a):
+                return fn(*a[:n], **dict(zip(names, a[n:])), **dict(static))
+
+            w = _shmap(("rep", fn, n, names, static), local, P(), P())
+            return w(*args, *ops_kw.values())
+        return call
 
     return ops.ScreenBackend(
         name=f"shard:{tile.name}",
@@ -210,9 +252,9 @@ def sharded_backend(mesh: Mesh, tile=None) -> ops.ScreenBackend:
         # group shards would have to respect group boundaries — group mesh
         # sessions stay on the GSPMD jnp path (see LassoSession.fit)
         group_scores=tile.group_scores,
-        fista_step=tile.fista_step,
-        cd_gram_sweep=tile.cd_gram_sweep,
-        prox_step=tile.prox_step,
+        fista_step=replicated(tile.fista_step),
+        cd_gram_sweep=replicated(tile.cd_gram_sweep),
+        prox_step=replicated(tile.prox_step),
     )
 
 
@@ -236,14 +278,15 @@ def make_dist_ops(mesh: Mesh, backend=None):
 
     @functools.partial(
         shard_map, mesh=mesh, in_specs=(xspec, rspec), out_specs=rspec,
-        check_rep=False,
+        check_vma=False,
     )
     def lambda_max_d(Xb, y):
         """λ_max = max_j |x_jᵀy|. Collectives: one scalar pmax."""
         return _pmax(jnp.max(jnp.abs(tile.matvec(Xb, y))), axes)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(xspec, bspec, rspec), out_specs=rspec
+        shard_map, mesh=mesh, in_specs=(xspec, bspec, rspec), out_specs=rspec,
+        check_vma=False,
     )
     def matvec_d(Xb, bb, y):
         """r = y − Xβ. Collectives: one N-vector psum."""
@@ -252,7 +295,7 @@ def make_dist_ops(mesh: Mesh, backend=None):
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(xspec, rspec, rspec, rspec), out_specs=(bspec, bspec),
-        check_rep=False,
+        check_vma=False,
     )
     def screen_scores_d(Xb, centre, rho, eps):
         """EDPP scores + discard mask per local feature block. Zero comms.
@@ -263,7 +306,7 @@ def make_dist_ops(mesh: Mesh, backend=None):
 
     @functools.partial(
         shard_map, mesh=mesh, in_specs=(xspec, rspec), out_specs=rspec,
-        check_rep=False,
+        check_vma=False,
     )
     def sup_corr_d(Xb, r):
         """‖Xᵀr‖_∞ (for λ_max-style reductions and dual scaling)."""
@@ -320,7 +363,7 @@ def dist_edpp_screen_cached(mesh: Mesh, X, y, lam_next, lam_prev,
         shard_map, mesh=mesh,
         in_specs=(P(None, f), P(), P(), P(f), P()),
         out_specs=(P(f), P(f)),
-        check_rep=False,
+        check_vma=False,
     )
     def score_d(Xb, centre, rho, norms_b, eps_):
         scores = jnp.abs(tile.matvec(Xb, centre)) + rho * norms_b
@@ -345,7 +388,7 @@ def dist_edpp_screen_sparse(mesh: Mesh, X, X_active, y, lam_next, lam_prev,
 
     @functools.partial(
         shard_map, mesh=mesh, in_specs=(P(None, f), P(f), P()),
-        out_specs=P(),
+        out_specs=P(), check_vma=False,
     )
     def sparse_matvec(Xa_b, ba_b, y):
         return y - _psum(Xa_b @ ba_b, axes)
@@ -363,7 +406,7 @@ def dist_edpp_screen_sparse(mesh: Mesh, X, X_active, y, lam_next, lam_prev,
         shard_map, mesh=mesh,
         in_specs=(P(None, f), P(), P(), P(f), P()),
         out_specs=(P(f), P(f)),
-        check_rep=False,
+        check_vma=False,
     )
     def score_d(Xb, centre, rho, norms_b, eps_):
         scores = jnp.abs(tile.matvec(Xb, centre)) + rho * norms_b
@@ -404,6 +447,7 @@ def dist_edpp_screen_batched(mesh: Mesh, X, Y, lam_next, lam_prev,
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(P(None, f), P(q, f), P(q, None)), out_specs=P(q, None),
+        check_vma=False,
     )
     def matvec_b(Xb, bb, Y):
         """R = Y − βXᵀ for the batch: ONE (B_local, N) psum over the
@@ -427,7 +471,7 @@ def dist_edpp_screen_batched(mesh: Mesh, X, Y, lam_next, lam_prev,
         shard_map, mesh=mesh,
         in_specs=(P(None, f), P(q, None), P(q), P(f), P()),
         out_specs=(P(q, f), P(q, f)),
-        check_rep=False,
+        check_vma=False,
     )
     def score_b(Xb, centre, rho, norms_b, eps_):
         """Batched local scores: zero comms, the backend's batched matvec
@@ -465,7 +509,7 @@ def dist_fista_batched(mesh: Mesh, X, Y, lam, beta0, lipschitz, *,
         shard_map, mesh=mesh,
         in_specs=(P(None, f), P(q, None), P(q, f), P(q, f), P(), P(q)),
         out_specs=(P(q, f), P(q, f), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def one_iter(Xb, Y, beta_b, z_b, t, lam):
         XZ = _psum(z_b @ Xb.T, axes)      # (B_local, N): one collective
@@ -498,7 +542,7 @@ def dist_power_iteration(mesh: Mesh, X, iters: int = 30, backend=None):
     @functools.partial(
         shard_map, mesh=mesh, in_specs=(P(None, f), P(f)),
         out_specs=(P(f), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def body_sm(Xb, vb):
         u = _psum(Xb @ vb, axes)                     # (N,) replicated
@@ -520,7 +564,8 @@ def dist_power_iteration(mesh: Mesh, X, iters: int = 30, backend=None):
     v, _ = jax.lax.fori_loop(0, iters, body, (v, jnp.asarray(0.0, X.dtype)))
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(P(None, f), P(f)), out_specs=P()
+        shard_map, mesh=mesh, in_specs=(P(None, f), P(f)), out_specs=P(),
+        check_vma=False,
     )
     def rayleigh(Xb, vb):
         u = _psum(Xb @ vb, axes)
@@ -573,7 +618,7 @@ def dist_fista(mesh: Mesh, X, y, lam, beta0, lipschitz, *,
         shard_map, mesh=mesh,
         in_specs=(P(None, f), P(), P(f), P(f), P(), P(None)),
         out_specs=(P(f), P(f), P(), P(None)),
-        check_rep=False,
+        check_vma=False,
     )
     def one_iter(Xb, y, beta_b, z_b, t, Xz_prev):
         t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))

@@ -150,7 +150,7 @@ def block_scores(Xb, centre, rho, col_norms=None):
     ref.edpp_screen_ref / the fused kernel's finish step, so sharded and
     single-chip screens agree bitwise on the same block.
     """
-    dot = Xb.T @ centre
+    dot = jnp.matmul(Xb.T, centre, precision=jax.lax.Precision.HIGHEST)
     if col_norms is None:
         col_norms = jnp.sqrt(jnp.sum(jnp.square(Xb), axis=0))
     return jnp.abs(dot) + rho * col_norms

@@ -102,5 +102,9 @@ def gap_from_residual(r, dot, beta, lam, y):
 
 
 def duality_gap(X, y, beta, lam):
-    r = y - X @ beta
-    return gap_from_residual(r, X.T @ r, beta, lam, y)
+    """The certificate P(β) − D(θ̃), both X passes at full f32 precision
+    (TPU's default f32 matmul is one bf16 pass)."""
+    hi = jax.lax.Precision.HIGHEST
+    r = y - jnp.matmul(X, beta, precision=hi)
+    return gap_from_residual(r, jnp.matmul(X.T, r, precision=hi), beta, lam,
+                             y)

@@ -25,8 +25,9 @@ resolves the screen backend to the PER-SHARD dispatcher
 :func:`repro.core.distributed.sharded_backend` — the same Pallas/jnp tile
 kernels as the single-chip engines, run on each local block under
 ``shard_map`` (``session.backend_name == "shard:<tile>"``). Reduced solves
-run the tile backend directly on replicated gathered buckets, so mesh
-masks are bit-identical to the unsharded engine's (docs/distributed.md).
+run the tile's solver kernels whole on every device, on replicated
+gathered buckets (solver backend ``"shard:<tile>"`` too), so mesh masks
+are bit-identical to the unsharded engine's (docs/distributed.md).
 Group mesh sessions remain GSPMD + ``jnp`` (partial support: any other
 backend raises). Every call returns the same unified
 :class:`~repro.core.path.PathResult` with a leading batch axis.
@@ -59,6 +60,7 @@ reproduce the old masks bit-for-bit. See docs/api.md.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import warnings
 from typing import Callable
 
@@ -83,7 +85,7 @@ from .path import (
     _path_driver,
     lambda_grid,
 )
-from .solver import SOLVERS, SolverEngine
+from .solver import SOLVERS, SolverEngine, resolve_solver_backend
 
 # Every rule the engines dispatch (core/screening.py RULES + the non-sphere
 # tests). The group engine supports the {edpp, strong, none} subset.
@@ -354,6 +356,23 @@ def GroupPathConfig(**kw) -> PathConfig:
     return PathConfig(**kw)
 
 
+def _full_precision(entry):
+    """Run a session entry point with every f32 matmul at full precision.
+
+    On TPU the default f32 matmul is one bf16 pass (≈2⁻⁹ relative error on
+    a batched dot, measured on a v5e). That breaks the two things the path
+    rests on: screens decide at 1 − 1e-6 (their dots, the dual point θ
+    built from Xβ) and FISTA stalls far above a 1e-6 relative gap when its
+    gradient carries that error. Every jitted program the session traces —
+    XLA dots and the Pallas kernel bodies alike — is traced under
+    ``HIGHEST``; on CPU this changes nothing."""
+    @functools.wraps(entry)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return entry(*args, **kwargs)
+    return wrapped
+
+
 class LassoSession:
     """A fitted dictionary + resolved engine choices; query it many times.
 
@@ -378,6 +397,7 @@ class LassoSession:
 
     # ------------------------------------------------------------------ fit
     @classmethod
+    @_full_precision
     def fit(cls, X, *, groups: int | None = None, mesh=None,
             config: PathConfig | None = None,
             geometry=None) -> "LassoSession":
@@ -410,6 +430,9 @@ class LassoSession:
                 "geometry was fitted off-mesh, so its X would silently "
                 "bypass the column-sharded placement")
 
+        if mesh is not None:
+            from . import distributed as dist
+            mesh = dist.auto_mesh(mesh)
         self = object.__new__(cls)
         self.config = cfg
         self.groups = m
@@ -428,7 +451,6 @@ class LassoSession:
                             f"group mesh sessions run GSPMD with the jnp "
                             f"backend (sharded group kernels are not "
                             f"supported yet); got {what} backend {name!r}")
-            from . import distributed as dist
             X = dist.place_dictionary(mesh, X)
         self.X = jnp.asarray(X)
         if self.X.ndim != 2:
@@ -549,6 +571,7 @@ class LassoSession:
         return dict(self._eig_stats)
 
     # ----------------------------------------------------------------- path
+    @_full_precision
     def path(self, Y, lambdas=None, *, num_lambdas: int = 100,
              lo_frac: float = 0.05, hi_frac: float = 1.0,
              config: PathConfig | None = None) -> PathResult:
@@ -612,6 +635,7 @@ class LassoSession:
         self._eig_cache.clear()
 
     # ------------------------------------------------------------- update
+    @_full_precision
     def update(self, add=None, drop=None, *, workspaces=()):
         """Edit the fitted dictionary in place: drop columns, append new
         ones, keep every cache that stays valid warm.
@@ -713,9 +737,13 @@ class LassoSession:
             from . import distributed as dist
             if self.groups > 1 and backend is None:
                 backend = "jnp"
-            # Reduced solves run the tile backend directly on replicated
-            # gathered buckets; keep y off the query sharding so Pallas
-            # tiles only ever see plain replicated arrays.
+            elif self.groups == 1:
+                # the tile's solver kernels, run whole on every device
+                # under shard_map (sharded_backend)
+                backend = self._resolve_for_session(
+                    resolve_solver_backend(backend))
+            # Reduced solves run on replicated gathered buckets; keep y
+            # off the query sharding so the tiles see whole arrays.
             y = jax.device_put(y, dist.replicated(self.mesh))
         return SolverEngine(
             y, solver=cfg.solve.resolved_strategy(self.groups),

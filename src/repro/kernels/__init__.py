@@ -22,7 +22,6 @@ tiling/VMEM budgets and how to add a backend.
 from .ops import (  # noqa: F401
     BACKENDS,
     GRAM_BUCKET_MAX,
-    INTERPRET,
     ScreenBackend,
     cd_gram_sweep,
     edpp_screen,

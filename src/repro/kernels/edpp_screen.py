@@ -47,6 +47,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .ref import HIGHEST
+
 
 def resolve_tiles(n: int, p: int, bn: int | None = None,
                   bp: int | None = None) -> tuple[int, int]:
@@ -63,6 +65,31 @@ def resolve_tiles(n: int, p: int, bn: int | None = None,
     if bp is None:
         bp = min(512, -(-p // 128) * 128)
     return bn, bp
+
+
+def check_compilable(interpret: bool, *arrays) -> None:
+    """Refuse float64 operands for a compiled (Mosaic) kernel, which has no
+    f64 support. Interpret mode runs f64 as given (x64 CPU runs)."""
+    if interpret:
+        return
+    wide = [a.dtype for a in arrays if a.dtype == jnp.float64]
+    if wide:
+        raise TypeError(
+            "the compiled pallas kernels take float32 or bfloat16 operands, "
+            "got float64: run without jax_enable_x64, cast the inputs, or "
+            "use the 'jnp' backend")
+
+
+def _dot(o, x):
+    """(Bp, bn) @ (bn, bp) in full f32 on the MXU. The screen decisions
+    compare these dots against 1 − ε with ε = 1e-6, so the default TPU
+    precision (one bf16 pass, ~2⁻⁹ relative error) is not safe here:
+    ``HIGHEST`` lowers to Mosaic's fp32 contraction."""
+    return jax.lax.dot_general(
+        o, x, (((1,), (0,)), ((), ())),
+        precision=HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
 
 
 def _centre_block(centre: jax.Array, n_pad: int):
@@ -91,17 +118,14 @@ def _screen_kernel(o_ref, rho_ref, x_ref, dot_ref, ss_ref, scores_ref, *,
     x = x_ref[...]                                    # (bn, bp)
     o = o_ref[...].astype(jnp.float32)                # (Bp, bn)
     x32 = x.astype(jnp.float32)
-    # MXU: (Bp, bn) @ (bn, bp) -> (Bp, bp)
-    dot_ref[...] += jax.lax.dot_general(
-        o, x32, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    # MXU: (Bp, bn) @ (bn, bp) -> (Bp, bp), full f32 (see _dot)
+    dot_ref[...] += _dot(o, x32)
     # VPU: running column sum-of-squares (query-independent: one row)
     ss_ref[...] += jnp.sum(x32 * x32, axis=0, keepdims=True)
 
     @pl.when(j == n_tiles - 1)
     def _finish():
-        rho = rho_ref[...][:, None]                   # (Bp, 1)
+        rho = rho_ref[...]                            # (Bp, 1)
         scores_ref[...] = jnp.abs(dot_ref[...]) + rho * jnp.sqrt(ss_ref[...])
 
 
@@ -124,6 +148,7 @@ def edpp_screen_scores(
     Tiles default to :func:`resolve_tiles` (shrink-to-problem, 512 cap) so
     shard-local blocks under ``shard_map`` don't pay full-tile padding.
     """
+    check_compilable(interpret, X, centre, jnp.asarray(rho))
     n, p = X.shape
     bn, bp = resolve_tiles(n, p, bn, bp)
     n_pad = -n % bn
@@ -132,7 +157,8 @@ def edpp_screen_scores(
     op, b, squeeze = _centre_block(centre, n_pad)
     bq = op.shape[0]
     rho_arr = jnp.pad(
-        jnp.broadcast_to(jnp.asarray(rho, jnp.float32), (b,)), (0, bq - b))
+        jnp.broadcast_to(jnp.asarray(rho, jnp.float32), (b,)),
+        (0, bq - b))[:, None]
 
     n_tiles = (n + n_pad) // bn
     p_tiles = (p + p_pad) // bp
@@ -142,7 +168,7 @@ def edpp_screen_scores(
         grid=(p_tiles, n_tiles),
         in_specs=[
             pl.BlockSpec((bq, bn), lambda i, j: (0, j)),       # centres
-            pl.BlockSpec(memory_space=pl.ANY),                 # rho (Bp,)
+            pl.BlockSpec((bq, 1), lambda i, j: (0, 0)),        # rho (Bp, 1)
             pl.BlockSpec((bn, bp), lambda i, j: (j, i)),       # X tile
         ],
         out_specs=[
@@ -170,10 +196,7 @@ def _matvec_kernel(o_ref, x_ref, dot_ref, *, n_tiles: int):
 
     x32 = x_ref[...].astype(jnp.float32)
     o = o_ref[...].astype(jnp.float32)
-    dot_ref[...] += jax.lax.dot_general(
-        o, x32, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    dot_ref[...] += _dot(o, x32)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bp", "interpret"))
@@ -189,6 +212,7 @@ def screen_matvec(
     are cached across the λ-path (X is fixed along the path). ``centre`` may
     be (B, n): one pass over X yields all B correlation rows (B, p). Tiles
     default to :func:`resolve_tiles` (shard-local blocks stay unpadded)."""
+    check_compilable(interpret, X, centre)
     n, p = X.shape
     bn, bp = resolve_tiles(n, p, bn, bp)
     n_pad = -n % bn

@@ -1,14 +1,16 @@
-"""Pallas TPU kernel: fused group-EDPP screening scores (Corollary 21 LHS).
+"""Group-EDPP screening scores (Corollary 21 LHS) on the screening kernel.
 
 For contiguous groups of size m:  gscores[g] = ‖X_gᵀ·o‖₂.
 
-Same streaming structure as edpp_screen (one HBM pass over X, f32 VMEM
-accumulator per feature tile); the per-group reduction (reshape to (bp/m, m),
-square, sum, sqrt) is fused into the last sample tile, so the p-sized dot
-vector never round-trips to HBM — only the G-sized group scores do.
-
-Constraint: m must divide bp (checked); bp/m must still be a multiple of the
-lane width for the output tile, so the wrapper rounds bp up accordingly.
+The one HBM pass over X is the ``screen_matvec`` kernel (edpp_screen.py),
+whose tiles are lane-aligned for every p. The per-group reduction (reshape
+the p-vector of dots to (p/m, m), square, sum, sqrt) runs on that vector
+afterwards. Fusing it into the kernel's last sample tile would need a
+feature tile that is a multiple of both 128 lanes and 128·m features (so
+that the (1, bp/m) output tile is lane-aligned too) — 1280 columns for
+m = 10, and no tile at all for the m in the thousands that wide groups
+use. The unfused reduction re-reads a p-vector of dots, 4·p bytes against
+the kernel's 4·n·p, so it costs 1/n of the pass.
 """
 
 from __future__ import annotations
@@ -17,69 +19,22 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from .edpp_screen import screen_matvec
 
 
-def _group_kernel(o_ref, x_ref, dot_ref, gs_ref, *, n_tiles: int, m: int):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        dot_ref[...] = jnp.zeros_like(dot_ref)
-
-    x32 = x_ref[...].astype(jnp.float32)
-    o = o_ref[...].astype(jnp.float32)
-    dot_ref[...] += jax.lax.dot_general(
-        o, x32, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(j == n_tiles - 1)
-    def _finish():
-        d = dot_ref[...]                      # (1, bp)
-        gsq = jnp.sum(jnp.square(d.reshape(-1, m)), axis=1)
-        gs_ref[...] = jnp.sqrt(gsq).reshape(1, -1)
-
-
-@functools.partial(jax.jit, static_argnames=("m", "bn", "bp", "interpret"))
+@functools.partial(jax.jit, static_argnames=("m", "interpret"))
 def group_screen_scores(
     X: jax.Array,
     centre: jax.Array,
     m: int,
     *,
-    bn: int = 512,
-    bp: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
-    """gscores[g] = ‖X_gᵀ·centre‖ for contiguous equal groups of size m."""
-    n, p = X.shape
-    assert p % m == 0, "p must be divisible by the group size"
-    G = p // m
-    if bp % m != 0:
-        bp = ((bp + m - 1) // m) * m
-    n_pad = -n % bn
-    p_pad = -p % bp
-    Xp = jnp.pad(X, ((0, n_pad), (0, p_pad)))
-    op = jnp.pad(centre, (0, n_pad)).reshape(1, -1)
-    n_tiles = (n + n_pad) // bn
-    p_tiles = (p + p_pad) // bp
-    bg = bp // m                                # groups per tile
-
-    _, gs = pl.pallas_call(
-        functools.partial(_group_kernel, n_tiles=n_tiles, m=m),
-        grid=(p_tiles, n_tiles),
-        in_specs=[
-            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((bn, bp), lambda i, j: (j, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bp), lambda i, j: (0, i)),   # dot accumulator
-            pl.BlockSpec((1, bg), lambda i, j: (0, i)),   # group scores
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, p + p_pad), jnp.float32),
-            jax.ShapeDtypeStruct((1, (p + p_pad) // m), jnp.float32),
-        ],
-        interpret=interpret,
-    )(op, Xp)
-    return gs[0, :G]
+    """gscores[g] = ‖X_gᵀ·centre‖ for contiguous equal groups of size m
+    (any m that divides p)."""
+    p = X.shape[1]
+    if p % m:
+        raise ValueError(f"p={p} is not divisible by the group size m={m}")
+    dot = screen_matvec(X, centre, interpret=interpret)
+    return jnp.sqrt(jnp.sum(jnp.square(dot.reshape(-1, m)), axis=1))

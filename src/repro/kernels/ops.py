@@ -1,8 +1,9 @@
 """Jit'd public wrappers + backend dispatch for the Pallas screening kernels.
 
-On CPU (this container) the kernels run in ``interpret=True`` mode; on TPU
-they compile to Mosaic. ``INTERPRET`` auto-detects the backend so the same
-call sites work in both places.
+On TPU the kernels compile to Mosaic; on a CPU they run on the Pallas
+interpreter (``interpret=True``). The platform is read when a backend is
+resolved or a kernel is called, never at import, so importing this module
+does not claim an accelerator.
 
 ``BACKENDS`` is the registry the :class:`repro.core.engine.ScreeningEngine`
 dispatches through. Each entry is a :class:`ScreenBackend` with three ops
@@ -15,7 +16,11 @@ sharing one contract (see docs/kernels.md):
 
 Backends: ``pallas`` (compiled Mosaic, TPU), ``interpret`` (the same kernel
 bodies on the Pallas interpreter — CI/CPU), ``jnp`` (the pure-jnp oracles of
-ref.py, also the GSPMD-friendly fallback). All accumulate in f32.
+ref.py, also the GSPMD-friendly fallback). All accumulate in f32, and every
+dot runs at ``Precision.HIGHEST`` on every backend: the default TPU matmul
+precision is one bf16 pass, too coarse for a 1 − 1e-6 screening threshold
+and for FISTA's 1e-6 duality-gap stop. The compiled ``pallas`` kernels
+refuse float64 operands.
 
 Every op is **batch-polymorphic** over the query operands (see ref.py):
 ``centre``/``r``/``z``/``beta`` may carry a leading batch axis (B, ·) with
@@ -40,8 +45,6 @@ from .edpp_screen import edpp_screen_scores, resolve_tiles, screen_matvec
 from .group_screen import group_screen_scores
 from .prox_step import prox_step
 from .solver_step import GRAM_BUCKET_MAX, cd_gram_sweep, fista_step
-
-INTERPRET = jax.default_backend() != "tpu"
 
 
 class ScreenBackend(NamedTuple):
@@ -76,6 +79,10 @@ def _kernel_backend(name: str, interpret: bool) -> ScreenBackend:
         cd_gram_sweep=functools.partial(cd_gram_sweep, interpret=interpret),
         prox_step=functools.partial(prox_step, interpret=interpret),
     )
+
+
+def _interpret_by_default() -> bool:
+    return jax.default_backend() != "tpu"
 
 
 def default_backend_name(env_var: str) -> str:
@@ -227,7 +234,7 @@ def edpp_screen(X, centre, rho, eps: float = 1e-6, *, col_norms=None,
     Returns (discard_mask, scores, sumsq). If ``col_norms`` (‖x_j‖₂) is
     provided — cached across a λ-path — only the matvec kernel runs.
     """
-    it = INTERPRET if interpret is None else interpret
+    it = _interpret_by_default() if interpret is None else interpret
     if col_norms is not None:
         dot = screen_matvec(X, centre, interpret=it)
         rho = jnp.asarray(rho)
@@ -246,7 +253,7 @@ def group_edpp_screen(X, centre, rho, m: int, spec_norms, eps: float = 1e-6,
 
     gscores[g] = ‖X_gᵀ·centre‖; discard iff gscores[g] < √m − rho·‖X_g‖₂ − eps.
     """
-    it = INTERPRET if interpret is None else interpret
+    it = _interpret_by_default() if interpret is None else interpret
     gscores = group_screen_scores(X, centre, m, interpret=it)
     thresh = jnp.sqrt(float(m)) - rho * spec_norms - eps
     return gscores < thresh, gscores
@@ -274,5 +281,4 @@ __all__ = [
     "prox_step",
     "resolve_tiles",
     "screen_matvec",
-    "INTERPRET",
 ]

@@ -21,10 +21,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .edpp_screen import check_compilable
+from .solver_step import scalar_cols
+
 
 def _prox_kernel(s_ref, z_ref, g_ref, b_ref, beta_ref, znew_ref):
-    s = s_ref[...]                                    # (3, Bp)
-    step, lam, mom = s[0][:, None], s[1][:, None], s[2][:, None]
+    s = s_ref[...]                                    # (Bp, 3)
+    step, lam, mom = s[:, 0:1], s[:, 1:2], s[:, 2:3]
     u = z_ref[...] - step * g_ref[...]
     t = step * lam
     beta_new = jnp.sign(u) * jnp.maximum(jnp.abs(u) - t, 0.0)
@@ -47,6 +50,7 @@ def prox_step(
     """Fused FISTA update over p-vectors (any length; zero padded).
     z/g/beta_old may carry a leading batch axis (B, p); step/lam/mom are
     then scalar-or-(B,) per-query parameters."""
+    check_compilable(interpret, z, g, beta_old)
     squeeze = z.ndim == 1
     z2 = z[None, :] if squeeze else z
     g2 = g[None, :] if squeeze else g
@@ -58,17 +62,14 @@ def prox_step(
     zp = jnp.pad(z2, ((0, b_pad), (0, p_pad)))
     gp = jnp.pad(g2, ((0, b_pad), (0, p_pad)))
     bp_old = jnp.pad(bo2, ((0, b_pad), (0, p_pad)))
-    scalars = jnp.stack([
-        jnp.pad(jnp.broadcast_to(jnp.asarray(s, z.dtype), (b,)), (0, b_pad))
-        for s in (step, lam, mom)
-    ])
+    scalars = scalar_cols(b, b_pad, z.dtype, step, lam, mom)
     p_tiles = (p + p_pad) // bp
 
     beta_new, z_new = pl.pallas_call(
         _prox_kernel,
         grid=(p_tiles,),
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),          # scalars (3, Bp)
+            pl.BlockSpec((bq, 3), lambda i: (0, 0)),    # scalars (Bp, 3)
             pl.BlockSpec((bq, bp), lambda i: (0, i)),
             pl.BlockSpec((bq, bp), lambda i: (0, i)),
             pl.BlockSpec((bq, bp), lambda i: (0, i)),

@@ -38,6 +38,16 @@ import jax
 import jax.numpy as jnp
 
 
+#: Precision of every dot here and in the kernels. TPU's default f32 matmul
+#: is one bf16 pass (~2⁻⁹ relative error): a screen decides at 1 − 1e-6,
+#: and FISTA stalls above a 1e-6 gap on a gradient that coarse.
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _matmul(a, b):
+    return jnp.matmul(a, b, precision=HIGHEST)
+
+
 def _acc_dtype(X: jax.Array):
     """Accumulation dtype: f32 for f32/bf16 inputs (the kernels' contract),
     but NEVER downcast — f64 inputs (jax_enable_x64 callers) stay f64."""
@@ -64,11 +74,11 @@ def edpp_screen_ref(X: jax.Array, centre: jax.Array, rho) -> tuple[jax.Array, ja
     ca = centre.astype(acc)
     sumsq = jnp.sum(jnp.square(Xa), axis=0)
     if ca.ndim == 2:
-        dot = ca @ Xa                                 # (B, p)
+        dot = _matmul(ca, Xa)                         # (B, p)
         rho_b = _per_query(rho, ca.shape[0], acc)
         scores = jnp.abs(dot) + rho_b[:, None] * jnp.sqrt(sumsq)
         return scores, sumsq
-    dot = Xa.T @ ca
+    dot = _matmul(Xa.T, ca)
     scores = jnp.abs(dot) + jnp.asarray(rho, acc) * jnp.sqrt(sumsq)
     return scores, sumsq
 
@@ -78,8 +88,8 @@ def screen_matvec_ref(X: jax.Array, centre: jax.Array) -> jax.Array:
     Batched: centre (B, n) → dot (B, p), one logical pass over X for all B."""
     acc = _acc_dtype(X)
     if centre.ndim == 2:
-        return centre.astype(acc) @ X.astype(acc)
-    return X.astype(acc).T @ centre.astype(acc)
+        return _matmul(centre.astype(acc), X.astype(acc))
+    return _matmul(X.astype(acc).T, centre.astype(acc))
 
 
 def group_screen_ref(X: jax.Array, centre: jax.Array, m: int) -> jax.Array:
@@ -88,7 +98,7 @@ def group_screen_ref(X: jax.Array, centre: jax.Array, m: int) -> jax.Array:
         gscores[g] = ‖X_gᵀ·centre‖₂
     """
     acc = _acc_dtype(X)
-    dot = X.astype(acc).T @ centre.astype(acc)
+    dot = _matmul(X.astype(acc).T, centre.astype(acc))
     return jnp.linalg.norm(dot.reshape(-1, m), axis=1)
 
 
@@ -134,9 +144,9 @@ def fista_step_ref(X: jax.Array, r: jax.Array, z: jax.Array,
     """
     acc = _acc_dtype(X)
     if r.ndim == 2:
-        g = r.astype(acc) @ X.astype(acc)             # (B, p)
+        g = _matmul(r.astype(acc), X.astype(acc))     # (B, p)
     else:
-        g = X.astype(acc).T @ r.astype(acc)
+        g = _matmul(X.astype(acc).T, r.astype(acc))
         step = jnp.asarray(step, acc)
         lam = jnp.asarray(lam, acc)
         mom = jnp.asarray(mom, acc)
@@ -166,7 +176,7 @@ def cd_gram_sweep_ref(G: jax.Array, c: jax.Array, beta: jax.Array, lam,
     p = G.shape[0]
     if beta.ndim == 2:
         lam_b = _per_query(lam, beta.shape[0], beta.dtype)
-        q = beta @ G                                  # (B, p); G symmetric
+        q = _matmul(beta, G)                          # (B, p); G symmetric
 
         def coord_b(i, carry):
             beta, q = carry
@@ -187,7 +197,7 @@ def cd_gram_sweep_ref(G: jax.Array, c: jax.Array, beta: jax.Array, lam,
         beta, _ = jax.lax.fori_loop(0, sweeps * p, coord_b, (beta, q))
         return beta
 
-    q = G @ beta
+    q = _matmul(G, beta)
 
     def coord(i, carry):
         beta, q = carry

@@ -18,7 +18,7 @@ Two kernels, mirroring the screening kernels' structure (edpp_screen.py):
     after screening has bucket ≤ n columns, so G is bucket² ≪ n·bucket and
     the whole sweep runs out of VMEM with zero HBM traffic per coordinate.
     The per-coordinate update is expressed in masked vector ops (one-hot
-    selects + a dynamic row slice), VPU-friendly and Mosaic-compilable —
+    selects + a dynamic row read of the G ref), VPU-friendly and Mosaic-compilable —
     no scalar gather from the lane dimension.
 
 Batch axis
@@ -34,7 +34,7 @@ single-query arithmetic exactly.
 
 Accumulation follows ref._acc_dtype: f32 for f32/bf16 inputs, f64 is never
 downcast (x64 benchmark runs keep solver-grade precision in interpret
-mode). Semantics are DEFINED by ref.fista_step_ref / ref.cd_gram_sweep_ref;
+mode; the compiled kernels refuse f64, which Mosaic cannot lower). Semantics are DEFINED by ref.fista_step_ref / ref.cd_gram_sweep_ref;
 tests/test_kernels.py sweeps shapes/dtypes against them.
 
 bf16 X is a first-class input: under ``SolveSpec(solve_dtype="bfloat16")``
@@ -52,8 +52,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .edpp_screen import resolve_tiles
-from .ref import _acc_dtype
+from .edpp_screen import check_compilable, resolve_tiles
+from .ref import HIGHEST, _acc_dtype
 
 # VMEM guard for cd_gram_sweep: G is (b, b) f32/f64 and must fit on-chip
 # alongside its (Bp, b) vectors. 1024² f32 = 4 MiB ≪ 16 MiB/core.
@@ -67,11 +67,13 @@ def _q2d(v: jax.Array):
     return v, v.shape[0], False
 
 
-def _scalar_rows(b: int, b_pad: int, acc, *params) -> jax.Array:
-    """Stack per-query scalar-or-(B,) params into a (len(params), Bp) array."""
-    rows = [jnp.pad(jnp.broadcast_to(jnp.asarray(s, acc), (b,)), (0, b_pad))
-            for s in params]
-    return jnp.stack(rows)
+def scalar_cols(b: int, b_pad: int, dtype, *params) -> jax.Array:
+    """Per-query scalar-or-(B,) params as the columns of a (Bp, k) array:
+    a whole-array VMEM operand whose column slices broadcast against the
+    (Bp, ·) query rows without a relayout."""
+    cols = [jnp.pad(jnp.broadcast_to(jnp.asarray(s, dtype), (b,)),
+                    (0, b_pad)) for s in params]
+    return jnp.stack(cols, axis=1)
 
 
 def _fista_step_kernel(s_ref, r_ref, x_ref, z_ref, b_ref,
@@ -86,13 +88,14 @@ def _fista_step_kernel(s_ref, r_ref, x_ref, z_ref, b_ref,
     r = r_ref[...].astype(acc)                       # (Bp, bn)
     # MXU: (Bp, bn) @ (bn, bp) -> (Bp, bp) gradient partial
     g_ref[...] += jax.lax.dot_general(
-        r, x, (((1,), (0,)), ((), ())), preferred_element_type=acc,
+        r, x, (((1,), (0,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=acc,
     )
 
     @pl.when(j == n_tiles - 1)
     def _finish():
-        s = s_ref[...]                               # (3, Bp)
-        step, lam, mom = s[0][:, None], s[1][:, None], s[2][:, None]
+        s = s_ref[...]                               # (Bp, 3)
+        step, lam, mom = s[:, 0:1], s[:, 1:2], s[:, 2:3]
         u = z_ref[...].astype(acc) - step * g_ref[...]
         t = step * lam
         beta_new = jnp.sign(u) * jnp.maximum(jnp.abs(u) - t, 0.0)
@@ -125,6 +128,7 @@ def fista_step(
     this runs once per *inner iteration*, so padding a 30×80 reduced bucket
     to a 512×512 tile would multiply the whole solve's flops.
     """
+    check_compilable(interpret, X, r, z, beta_old)
     n, p = X.shape
     bn, bp = resolve_tiles(n, p, bn, bp)
     acc = _acc_dtype(X)
@@ -139,7 +143,7 @@ def fista_step(
     rp = jnp.pad(r2, ((0, b_pad), (0, n_pad)))
     zp = jnp.pad(z2, ((0, b_pad), (0, p_pad)))
     bp_old = jnp.pad(bo2, ((0, b_pad), (0, p_pad)))
-    scalars = _scalar_rows(b, b_pad, acc, step, lam, mom)
+    scalars = scalar_cols(b, b_pad, acc, step, lam, mom)
     n_tiles = (n + n_pad) // bn
     p_tiles = (p + p_pad) // bp
 
@@ -147,7 +151,7 @@ def fista_step(
         functools.partial(_fista_step_kernel, n_tiles=n_tiles, acc=acc),
         grid=(p_tiles, n_tiles),
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),                 # scalars
+            pl.BlockSpec((bq, 3), lambda i, j: (0, 0)),        # scalars
             pl.BlockSpec((bq, bn), lambda i, j: (0, j)),       # residuals
             pl.BlockSpec((bn, bp), lambda i, j: (j, i)),       # X tile
             pl.BlockSpec((bq, bp), lambda i, j: (0, i)),       # z
@@ -174,34 +178,34 @@ def fista_step(
 
 def _cd_gram_kernel(s_ref, g_ref, c_ref, b_ref, v_ref, out_ref, *,
                     p: int, sweeps: int, acc):
-    lam = s_ref[...][:, None]                        # (Bp, 1)
-    G = g_ref[...].astype(acc)                       # (p, p), VMEM-resident
+    lam = s_ref[...]                                 # (Bp, 1)
     c = c_ref[...].astype(acc)                       # (Bp, p)
     beta0 = b_ref[...].astype(acc)                   # (Bp, p)
     valid = v_ref[...].astype(acc)                   # (Bp, p)
     q0 = jax.lax.dot_general(                        # q = βG (G symmetric)
-        beta0, G, (((1,), (0,)), ((), ())), preferred_element_type=acc)
+        beta0, g_ref[...].astype(acc), (((1,), (0,)), ((), ())),
+        precision=HIGHEST, preferred_element_type=acc)
     iota = jax.lax.broadcasted_iota(jnp.int32, (1, p), 1)
+
+    def pick(v, onehot):                             # v[:, j] as (rows, 1)
+        return jnp.sum(jnp.where(onehot, v, 0.0), axis=1, keepdims=True)
 
     def coord(i, carry):
         beta, q = carry
         j = i % p
         onehot = iota == j                                 # (1, p)
-        row = jax.lax.dynamic_slice(G, (j, 0), (1, p))     # G_j,: == G_:,j
-        gjj = jnp.sum(jnp.where(onehot, row, 0.0))
-        bj = jnp.sum(jnp.where(onehot, beta, 0.0), axis=1)     # (Bp,)
-        cj = jnp.sum(jnp.where(onehot, c, 0.0), axis=1)
-        qj = jnp.sum(jnp.where(onehot, q, 0.0), axis=1)
-        vj = jnp.sum(jnp.where(onehot, valid, 0.0), axis=1)
-        rho = cj - qj + gjj * bj
+        row = g_ref[pl.ds(j, 1), :].astype(acc)            # G_j,: == G_:,j
+        gjj = pick(row, onehot)                            # (1, 1)
+        bj = pick(beta, onehot)                            # (Bp, 1)
+        rho = pick(c, onehot) - pick(q, onehot) + gjj * bj
         bn_ = jnp.where(
             gjj > 0,
-            jnp.sign(rho) * jnp.maximum(jnp.abs(rho) - lam[:, 0], 0.0)
+            jnp.sign(rho) * jnp.maximum(jnp.abs(rho) - lam, 0.0)
             / jnp.maximum(gjj, 1e-30),
             0.0,
-        ) * vj
-        beta = jnp.where(onehot, bn_[:, None], beta)
-        q = q + row * (bn_ - bj)[:, None]
+        ) * pick(valid, onehot)
+        beta = jnp.where(onehot, bn_, beta)
+        q = q + row * (bn_ - bj)
         return beta, q
 
     beta, _ = jax.lax.fori_loop(0, sweeps * p, coord, (beta0, q0))
@@ -227,6 +231,7 @@ def cd_gram_sweep(
     Batched: c/beta (B, p) share the one (p, p) Gram block; lam is
     scalar-or-(B,); ``valid`` (B, p) pins screened-out columns per query.
     """
+    check_compilable(interpret, G, c, beta)
     p = G.shape[0]
     if p > GRAM_BUCKET_MAX:
         raise ValueError(
@@ -245,14 +250,13 @@ def cd_gram_sweep(
     cp = jnp.pad(c2, ((0, b_pad), (0, p_pad)))
     bp_ = jnp.pad(beta2, ((0, b_pad), (0, p_pad)))
     vp_ = jnp.pad(valid2.astype(acc), ((0, b_pad), (0, p_pad)))
-    scalars = jnp.pad(jnp.broadcast_to(jnp.asarray(lam, acc), (b,)),
-                      (0, b_pad))
+    scalars = scalar_cols(b, b_pad, acc, lam)
 
     out = pl.pallas_call(
         functools.partial(_cd_gram_kernel, p=p + p_pad, sweeps=sweeps,
                           acc=acc),
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),        # lam (Bp,)
+            pl.BlockSpec((bq, 1), lambda: (0, 0)),    # lam (Bp, 1)
             pl.BlockSpec((p + p_pad, p + p_pad), lambda: (0, 0)),
             pl.BlockSpec((bq, p + p_pad), lambda: (0, 0)),
             pl.BlockSpec((bq, p + p_pad), lambda: (0, 0)),

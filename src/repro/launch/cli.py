@@ -7,13 +7,14 @@ they are defined:
   * :func:`add_problem_args`   — ``--n --p --nnz --corr --seed``
   * :func:`add_engine_args`    — ``--rule --solver --backend
                                  --solver-backend``
-  * :func:`add_x64_arg`        — ``--x64 / --no-x64`` (per-driver default:
-                                 solve.py defaults ON for repro-grade
-                                 float64 paths, serve.py OFF for f32
-                                 serving)
+  * :func:`add_x64_arg`        — ``--x64 / --no-x64`` (default off: the
+                                 library runs f32, and the compiled
+                                 kernels refuse float64)
   * :func:`setup_jax`          — applies the x64 choice BEFORE any jax
-                                 import touches arrays (call it first in
-                                 ``main``)
+                                 import touches arrays and turns on the
+                                 compile cache (call it first in ``main``)
+  * :func:`use_compile_cache`  — JAX's persistent compilation cache at a
+                                 fixed path
   * :func:`path_config`        — a :class:`repro.core.PathConfig` from the
                                  parsed flags (imports repro.core, so only
                                  call it after :func:`setup_jax`)
@@ -22,6 +23,12 @@ they are defined:
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
+
+#: The compile cache's default home: fixed, inside the checkout, and listed
+#: in .gitignore. A path that moved between runs would never hit.
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def add_problem_args(ap: argparse.ArgumentParser, *, n: int, p: int,
@@ -120,6 +127,7 @@ def make_mesh(args):
     if spec is None:
         return None
     import jax
+    from repro.core import distributed
     try:
         q, f = (int(t) for t in spec.lower().split("x"))
     except ValueError:
@@ -132,20 +140,34 @@ def make_mesh(args):
             f"--mesh {spec} needs {q * f} devices but only {n_dev} are "
             f"visible (on CPU set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={q * f})")
-    return jax.make_mesh((q, f), ("query", "feature"))
+    return distributed.make_mesh((q, f), ("query", "feature"))
 
 
-def add_x64_arg(ap: argparse.ArgumentParser, *, default: bool) -> None:
+def add_x64_arg(ap: argparse.ArgumentParser) -> None:
     ap.add_argument("--x64", action=argparse.BooleanOptionalAction,
-                    default=default,
-                    help="float64 solves (solve.py defaults on for repro; "
-                         "serve.py defaults off — the f32 serving config)")
+                    default=False,
+                    help="float64 paths with the jnp backends (the compiled "
+                         "pallas kernels take f32/bf16 only)")
+
+
+def use_compile_cache() -> None:
+    """Keep compiled programs in JAX's persistent cache across processes.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and
+    nothing is set here; otherwise the cache lives at
+    :data:`COMPILE_CACHE_DIR`."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
 
 
 def setup_jax(args) -> None:
-    """Apply ``--x64`` before any jax array exists. Call first in main()."""
+    """Apply ``--x64`` before any jax array exists and turn on the compile
+    cache. Call first in main()."""
     import jax
     jax.config.update("jax_enable_x64", bool(args.x64))
+    use_compile_cache()
 
 
 def path_config(args, *, solver_tol: float | None = None, **extra):

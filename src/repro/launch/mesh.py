@@ -3,7 +3,7 @@ importing this module never touches jax device state)."""
 
 from __future__ import annotations
 
-import jax
+from repro.core.distributed import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -11,7 +11,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     for the 2-pod / 512-chip dry-run."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh_for(devices: int, model_parallel: int = 16):
@@ -19,4 +19,4 @@ def make_mesh_for(devices: int, model_parallel: int = 16):
     model = min(model_parallel, devices)
     while devices % model:
         model //= 2
-    return jax.make_mesh((devices // model, model), ("data", "model"))
+    return make_mesh((devices // model, model), ("data", "model"))

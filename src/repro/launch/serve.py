@@ -18,7 +18,8 @@ a deterministic query stream (``data.pipeline.QueryStream``, keyed by
     schema-checked ``BENCH_serve.json`` (p50/p99 admission→completion
     latency, queries/sec, batch-fill and dispatch-reason telemetry).
 
-Precision: serving defaults to f32 (``--x64`` opts into float64). The λ
+Precision: serving runs f32 (``--x64`` opts into float64 on the jnp
+backends; the compiled pallas kernels refuse it). The λ
 grids stop at ``--hi-frac`` (default 0.95) of each query's λ_max so the
 bitwise exactness contract applies (docs/api.md#exactness-contract).
 See docs/serving.md#continuous-batching.
@@ -48,7 +49,7 @@ def _parse_args(argv=None):
     cli.add_engine_args(ap)
     cli.add_mesh_arg(ap)
     cli.add_serve_args(ap)
-    cli.add_x64_arg(ap, default=False)
+    cli.add_x64_arg(ap)
     ap.add_argument("--num-queries", type=int, default=128)
     ap.add_argument("--num-lambdas", type=int, default=16,
                     help="per-query λ-grid points (each query gets the "

@@ -8,10 +8,10 @@ workspace pass over X happens exactly once) and the path is solved
 through ``session.path`` — group mode is just ``fit(..., groups=m)``.
 Checkpoints (λ_k, β_k) per grid point; a killed run resumes mid-path.
 
-Precision: ``--x64`` (the default here — reproduction-grade paths)
-enables jax_enable_x64 BEFORE any jax import touches arrays; ``--no-x64``
-runs the f32 serving configuration (what launch/serve.py uses by
-default). Flag wiring shared with serve.py lives in launch/cli.py.
+Precision: f32 by default, as in launch/serve.py. ``--x64`` enables
+jax_enable_x64 BEFORE any jax import touches arrays, for float64 paths on
+the jnp backends (the compiled pallas kernels refuse float64). Flag wiring
+shared with serve.py lives in launch/cli.py.
 """
 
 from __future__ import annotations
@@ -27,7 +27,7 @@ def _parse_args(argv=None):
     cli.add_problem_args(ap, n=150, p=3000, nnz=60)
     cli.add_engine_args(ap)
     cli.add_mesh_arg(ap)
-    cli.add_x64_arg(ap, default=True)
+    cli.add_x64_arg(ap)
     ap.add_argument("--num-lambdas", type=int, default=100)
     ap.add_argument("--group-size", type=int, default=0,
                     help=">0 switches to group Lasso with this group size")
@@ -46,6 +46,7 @@ def main(argv=None):
     from repro.data import group_lasso_problem, lasso_problem  # noqa: E402
 
     groups = args.group_size if args.group_size > 0 else None
+    dtype = "float64" if args.x64 else "float32"
     ckpt_fn = None
     if args.ckpt_dir:                  # group and plain paths both resume
         def ckpt_fn(k, lam, beta):
@@ -54,7 +55,8 @@ def main(argv=None):
     if groups:
         m = args.group_size
         X, y, _ = group_lasso_problem(args.n, args.p, m,
-                                      active_groups=args.nnz // m + 1)
+                                      active_groups=args.nnz // m + 1,
+                                      dtype=dtype)
         if args.solver == "fista":     # the plain-Lasso default
             args.solver = "group_fista"
         elif not args.solver.startswith("group"):
@@ -67,9 +69,12 @@ def main(argv=None):
                 f"group_* strategy")
     else:
         X, y, _ = lasso_problem(args.n, args.p, nnz=args.nnz,
-                                corr=args.corr)
+                                corr=args.corr, dtype=dtype)
 
-    cfg = cli.path_config(args, checkpoint_fn=ckpt_fn)
+    # the library's 1e-8 relative gap is below f32 resolution (2⁻²³ ≈
+    # 1.2e-7): f32 stops at serve.py's 1e-6, float64 keeps 1e-8
+    cfg = cli.path_config(args, solver_tol=None if args.x64 else 1e-6,
+                          checkpoint_fn=ckpt_fn)
     sess = LassoSession.fit(X, groups=groups, mesh=cli.make_mesh(args),
                             config=cfg)
 

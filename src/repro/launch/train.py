@@ -19,6 +19,7 @@ import numpy as np
 
 from repro import configs
 from repro.checkpoint import latest_step, restore, save
+from repro.core.distributed import make_mesh
 from repro.data import SyntheticLM, device_batch
 from repro.optim import adamw
 from repro.train import steps as ST
@@ -40,7 +41,7 @@ def main():
 
     shape = tuple(int(x) for x in args.mesh.split("x"))
     names = ("pod", "data", "model")[-len(shape):]
-    mesh = jax.make_mesh(shape, names)
+    mesh = make_mesh(shape, names)
 
     cfg = configs.get_tiny(args.arch) if args.tiny \
         else configs.get_config(args.arch)

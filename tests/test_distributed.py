@@ -11,7 +11,7 @@ import numpy as np, jax, jax.numpy as jnp
 from repro.core import distributed as D
 from repro.core import lambda_max, edpp_mask, DualState, fista
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = D.make_mesh((2, 4), ("data", "model"))
 rng = np.random.default_rng(0)
 N, p = 64, 512
 X = rng.standard_normal((N, p)).astype(np.float32)
@@ -48,7 +48,7 @@ MULTIPOD_CODE = r"""
 import numpy as np, jax, jax.numpy as jnp
 from repro.core import distributed as D
 from repro.core import lambda_max
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = D.make_mesh((2, 2, 2), ("pod", "data", "model"))
 rng = np.random.default_rng(1)
 N, p = 32, 256
 X = rng.standard_normal((N, p)).astype(np.float32)
@@ -78,7 +78,7 @@ import numpy as np, jax, jax.numpy as jnp
 from repro.core import distributed as D
 from repro.core import lambda_max, edpp_mask, make_dual_state, fista
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = D.make_mesh((2, 4), ("data", "model"))
 rng = np.random.default_rng(2)
 N, p, B = 48, 512, 4
 X = rng.standard_normal((N, p)).astype(np.float32)
@@ -136,6 +136,7 @@ def test_distributed_batched_matches_per_query(subproc):
 
 SHARD_PARITY_CODE = r"""
 import numpy as np, jax, jax.numpy as jnp
+from repro.core import distributed as D
 from repro.core.session import LassoSession, PathConfig
 
 def beta_err_tol(y, solver_tol, kappa=25.0):
@@ -159,7 +160,7 @@ for tile in ("jnp", "interpret"):
     r0 = ref.path(Y, grids)
     r0_single = ref.path(Y[0], grids[0])
     for q, f in [(1, 1), (1, 2), (2, 2), (1, 8)]:
-        mesh = jax.make_mesh((q, f), ("query", "feature"))
+        mesh = D.make_mesh((q, f), ("query", "feature"))
         sess = LassoSession.fit(X, mesh=mesh, config=cfg)
         assert sess.backend_name == f"shard:{tile}", sess.backend_name
         r = sess.path(Y, grids)
@@ -191,6 +192,7 @@ def test_sharded_session_mask_parity_sweep(subproc):
 
 BF16_CUT_PARITY_CODE = r"""
 import numpy as np, jax, jax.numpy as jnp
+from repro.core import distributed as D
 from repro.core.session import LassoSession, PathConfig
 
 rng = np.random.default_rng(13)
@@ -214,7 +216,7 @@ for tile in ("jnp", "interpret"):
         Y, grids)
     r_cut0 = LassoSession.fit(X, config=cfg_cut).path(Y, grids)
     for q, f in [(1, 2), (2, 2), (1, 8)]:
-        mesh = jax.make_mesh((q, f), ("query", "feature"))
+        mesh = D.make_mesh((q, f), ("query", "feature"))
         # bf16 screen copy on the mesh: the narrow f32 fallback re-gathers
         # sharded columns, masks must equal the f32 UNSHARDED session's
         r16 = LassoSession.fit(X, mesh=mesh, config=cfg16).path(Y, grids)
@@ -251,6 +253,7 @@ def test_sharded_bf16_and_cut_mask_parity(subproc):
 
 SOLVE_DTYPE_PARITY_CODE = r"""
 import numpy as np, jax, jax.numpy as jnp
+from repro.core import distributed as D
 from repro.core.session import LassoSession, PathConfig
 
 def beta_err_tol(y, solver_tol, kappa=25.0):
@@ -273,7 +276,7 @@ r0 = LassoSession.fit(X, config=PathConfig(**kw)).path(Y, grids)
 r0_single = LassoSession.fit(X, config=PathConfig(**kw)).path(Y[0], grids[0])
 cfg16 = PathConfig(solve_dtype="bfloat16", **kw)
 for q, f in [(1, 2), (2, 2), (1, 8)]:
-    mesh = jax.make_mesh((q, f), ("query", "feature"))
+    mesh = D.make_mesh((q, f), ("query", "feature"))
     sess = LassoSession.fit(X, mesh=mesh, config=cfg16)
     r = sess.path(Y, grids)
     # the gap certificates stream f32 X, so the bf16 iteration stream must
@@ -306,3 +309,54 @@ def test_sharded_solve_dtype_bf16_parity(subproc):
     streams the f32 shards."""
     out = subproc(SOLVE_DTYPE_PARITY_CODE, devices=8)
     assert "SOLVE_DTYPE_PARITY_OK" in out
+
+
+KERNELS_UNDER_SHARD_MAP_CODE = r"""
+import numpy as np, jax
+from repro.core import distributed as D
+from repro.core.session import LassoSession, PathConfig
+from repro.kernels import ops
+
+calls = []
+
+def spy(op, fn):
+    def call(*a, **k):
+        calls.append((op, jax.sharding.get_abstract_mesh().manual_axes))
+        return fn(*a, **k)
+    return call
+
+tile = ops.BACKENDS["interpret"]
+spied = tile._replace(**{op: spy(op, getattr(tile, op)) for op in (
+    "matvec", "fused_scores", "fista_step", "cd_gram_sweep")})
+rng = np.random.default_rng(3)
+n, p, B = 32, 256, 4
+X = rng.standard_normal((n, p)).astype(np.float32)
+Y = np.stack([(X[:, :6] @ rng.uniform(-1, 1, 6)).astype(np.float32)
+              for _ in range(B)])
+mesh = D.make_mesh((2, 2), ("query", "feature"))
+for solver in ("fista", "cd"):
+    cfg = PathConfig(backend=spied, solver_backend=spied, solver=solver)
+    sess = LassoSession.fit(X, mesh=mesh, config=cfg)
+    res = sess.path(Y, num_lambdas=4, lo_frac=0.3, hi_frac=0.95)
+    live = [s for s in res.stats if s.bucket]
+    assert live and all(s.solver_backend == "shard:interpret"
+                        for s in live), [s.solver_backend for s in live]
+    sess.path(Y[0], num_lambdas=4, lo_frac=0.3, hi_frac=0.95)
+used = {op for op, _ in calls}
+assert used == {"matvec", "fused_scores", "fista_step", "cd_gram_sweep"}, used
+outside = sorted({op for op, axes in calls
+                  if set(axes) != {"query", "feature"}})
+assert not outside, f"kernels traced outside shard_map: {outside}"
+print("KERNELS_UNDER_SHARD_MAP_OK")
+"""
+
+
+def test_mesh_session_runs_every_kernel_under_shard_map(subproc):
+    """A compiled Pallas (Mosaic) kernel in a program that spans several
+    devices must sit inside a shard_map over every mesh axis: the compiler
+    cannot partition it, even on replicated operands. Interpret mode has
+    no such limit, so this pins it on the CPU: on a 2×2 mesh every tile
+    kernel a session calls — screens and the reduced solves' fista/cd
+    steps — is traced with all mesh axes manual."""
+    out = subproc(KERNELS_UNDER_SHARD_MAP_CODE, devices=4)
+    assert "KERNELS_UNDER_SHARD_MAP_OK" in out

@@ -1,6 +1,7 @@
 """Per-kernel shape/dtype sweeps vs the ref.py pure-jnp oracles
 (interpret=True executes the Pallas kernel body on CPU)."""
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -113,6 +114,36 @@ def test_cd_gram_sweep_rejects_oversized():
     G = jnp.zeros((b, b), jnp.float32)
     with pytest.raises(ValueError, match="GRAM_BUCKET_MAX"):
         ops.cd_gram_sweep(G, jnp.zeros(b), jnp.zeros(b), 0.1, interpret=True)
+
+
+@pytest.mark.parametrize("kernel", ["screen_matvec", "edpp_screen_scores",
+                                    "fista_step", "prox_step",
+                                    "cd_gram_sweep", "group_screen_scores"])
+def test_compiled_kernels_refuse_float64(kernel):
+    """Mosaic has no f64: the compiled kernels raise a clear error instead
+    of failing deep in lowering, while interpret mode still accepts f64."""
+    n, p = 16, 128
+    with jax.enable_x64(True):
+        X = jnp.ones((n, p), jnp.float64)
+        v_n, v_p = jnp.ones(n, jnp.float64), jnp.ones(p, jnp.float64)
+        call = {
+            "screen_matvec": lambda it: ops.screen_matvec(
+                X, v_n, interpret=it),
+            "edpp_screen_scores": lambda it: ops.edpp_screen_scores(
+                X, v_n, 0.5, interpret=it),
+            "fista_step": lambda it: ops.fista_step(
+                X, v_n, v_p, v_p, 0.1, 0.2, 0.3, interpret=it),
+            "prox_step": lambda it: ops.prox_step(
+                v_p, v_p, v_p, 0.1, 0.2, 0.3, interpret=it),
+            "cd_gram_sweep": lambda it: ops.cd_gram_sweep(
+                X.T @ X, v_p, v_p, 0.1, interpret=it),
+            "group_screen_scores": lambda it: ops.group_screen_scores(
+                X, v_n, 8, interpret=it),
+        }[kernel]
+        with pytest.raises(TypeError, match="float64"):
+            call(False)
+        out = call(True)
+        assert np.isfinite(np.asarray(jax.tree.leaves(out)[0])).all()
 
 
 def test_kernel_screening_matches_rule():

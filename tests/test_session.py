@@ -350,7 +350,7 @@ def test_mesh_session_honours_explicit_backend():
     res_m = sess_m.path(y, grid)
     assert res_m.stats[1].screen_backend == "shard:interpret"
     live = [s for s in res_m.stats if s.bucket]
-    assert live and all(s.solver_backend == "interpret" for s in live)
+    assert live and all(s.solver_backend == "shard:interpret" for s in live)
     res = LassoSession.fit(X, config=cfg).path(y, grid)
     np.testing.assert_array_equal(res_m.masks, res.masks)
 

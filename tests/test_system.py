@@ -16,6 +16,7 @@ from repro import configs
 from repro.core import (GroupPathConfig, PathConfig, group_lambda_max,
                         group_lasso_path, lambda_grid, lambda_max,
                         lasso_path)
+from repro.core.distributed import make_mesh
 from repro.data import SyntheticLM, device_batch
 from repro.optim import adamw
 from repro.train import steps as ST
@@ -45,7 +46,7 @@ def test_lasso_model_selection_end_to_end(rng):
 
 def test_train_loop_loss_decreases():
     """Production train_step (jitted, sharded, AdamW) on a 1-device mesh."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = configs.get_tiny("yi-9b")
     tc = ST.TrainConfig(opt=adamw.OptConfig(lr=5e-3, warmup_steps=5,
                                             total_steps=60))
