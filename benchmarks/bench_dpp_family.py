@@ -19,16 +19,21 @@ upgrades (docs/screening-rules.md, docs/kernels.md):
     rules (``edpp``) and ≤ 0.6× for the two-dot per-piece-margin rules
     (``gap``, ``gap_cut``, ``dome`` — the stacked bf16 matvec keeps
     ``x_passes == 1`` where the f32 engine needs 2; the narrow f32
-    fallback gather is counted in the bytes).
+    fallback gather is counted in the bytes). The bytes are the
+    ``ScreeningEngine``'s own counter (``total_screen_bytes``), read on a
+    replay of the path's screens (:func:`engine_screen_bytes`).
 
 Every arm lands in the ``bench_dpp_family`` section of BENCH_solver.json
-with ``rejection_rate`` and ``bytes_per_screen`` columns
-(tools/check_bench_schema.py enforces the row schema).
+with a ``rejection_rate`` column (tools/check_bench_schema.py enforces
+the row schema).
 """
 
 from __future__ import annotations
 
+import jax.numpy as jnp
 import numpy as np
+
+from repro.core import ScreeningEngine
 
 from .common import (beta_err_tol, emit, grid_for, ground_truth, run_rule,
                      write_bench_section)
@@ -50,7 +55,7 @@ DATASETS_SMOKE = {
 
 RULES = ["dpp", "imp1", "imp2", "edpp", "gap", "gap_cut", "dome"]
 
-# f32 vs bf16 A/B arms: rule → max allowed bytes_per_screen ratio. edpp
+# f32 vs bf16 A/B arms: rule → max allowed screen-bytes ratio. edpp
 # keeps the single-dot 0.55 bar; the two-dot rules (per-piece margins,
 # stacked matvec) get the ISSUE 9 0.6 bar — their f32 baseline already
 # needs 2 passes, the bf16 path does everything in 1.
@@ -73,7 +78,6 @@ def _row(name, rule, dtype, num_lambdas, r):
         "dataset": name, "rule": rule, "screen_dtype": dtype,
         "num_lambdas": int(num_lambdas),
         "rejection_rate": float(r.rejection.mean()),
-        "bytes_per_screen": float(r.screen_bytes_per_step),
         "speedup_vs_unscreened": float(r.speedup),
         "wall_time_s": float(r.path_time_s),
         "max_beta_err": float(r.max_beta_err),
@@ -87,8 +91,26 @@ def _emit_rule(name, tag, r):
          f"speedup={r.speedup:.2f} mean_rej={r.rejection.mean():.4f}"
          f" screen_s={r.screen_time_s:.3f}"
          f" hbm_passes_per_step={r.x_passes_per_step:.2f}"
-         f" jnp_hbm_passes={r.jnp_x_passes}"
-         f" bytes_per_screen={r.screen_bytes_per_step:.0f}")
+         f" jnp_hbm_passes={r.jnp_x_passes}")
+
+
+def engine_screen_bytes(X, y, grid, betas, rule, screen_dtype):
+    """HBM bytes the ScreeningEngine streams over the path's screens, by
+    its own counter: every λ below λ_max screened from the dual state of
+    the step before, built from ``betas`` (the reference path), as the
+    sequential driver threads it."""
+    eng = ScreeningEngine(jnp.asarray(X, jnp.float32),
+                          jnp.asarray(y, jnp.float32),
+                          screen_dtype=screen_dtype)
+    state = eng.state_at_lambda_max()
+    lmax = float(eng.lam_max)
+    for k, lam in enumerate(grid):
+        if lam >= lmax:
+            continue
+        eng.screen(float(lam), state, rule=rule)
+        state = eng.make_state(jnp.asarray(betas[k], jnp.float32),
+                               float(lam))
+    return eng.total_screen_bytes
 
 
 def run(full: bool = False, num_lambdas: int = 100, datasets=None,
@@ -132,8 +154,10 @@ def run(full: bool = False, num_lambdas: int = 100, datasets=None,
             assert np.array_equal(rb.masks, f32.masks), \
                 f"{name}/{rule}: bfloat16 masks differ from float32 " \
                 "(margin fallback broken)"
-            ratio = rb.screen_bytes_per_step / max(f32.screen_bytes_per_step,
-                                                   1e-30)
+            ratio = (engine_screen_bytes(X, y, grid, betas_ref, rule,
+                                         "bfloat16")
+                     / max(engine_screen_bytes(X, y, grid, betas_ref, rule,
+                                               "float32"), 1e-30))
             # ratio_slack covers the smoke set only: the narrow fallback
             # gather is size-bucketed (pow-2 + 3/4 midpoints, floor 8), so
             # at tiny p a ~40-column margin band rounds up to a 48-column
@@ -152,6 +176,8 @@ def run(full: bool = False, num_lambdas: int = 100, datasets=None,
                 f"{name}/{rule}: bf16 screen took " \
                 f"{rb.x_passes_per_step} passes (want 1 wide + narrow)"
             _emit_rule(name, f"{rule}-bf16", rb)
+            print(f"# dpp_family/{name}/{rule}-bf16 screen_bytes_ratio="
+                  f"{ratio:.3f} (bar {bar})")
             json_rows.append(_row(name, rule, "bfloat16", num_lambdas, rb))
             rows.append((name, f"{rule}-bf16", rb))
 

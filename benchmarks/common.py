@@ -92,7 +92,6 @@ class RuleResult:
     x_passes_per_query: float = 0.0  # amortised screen passes: passes/B —
     #                                  the axis bench_batched.py reports its
     #                                  multi-query runs on (docs/serving.md)
-    screen_bytes_per_step: float = 0.0  # HBM bytes per screen (dtype A/Bs)
     masks: np.ndarray | None = None     # per-λ discard masks (exactness A/Bs)
 
 
@@ -171,7 +170,6 @@ def run_rule(X, y, grid, rule, betas_ref, t_ref, solver_tol=1e-12,
         solver_x_passes_per_step=stats_means(res, "solver_x_passes"),
         batch_size=screened[0].batch_size if screened else 1,
         x_passes_per_query=stats_means(res, "x_passes_per_query"),
-        screen_bytes_per_step=stats_means(res, "screen_bytes"),
         masks=None if res.masks is None else np.asarray(res.masks),
     )
 

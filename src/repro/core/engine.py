@@ -53,6 +53,7 @@ import numpy as np
 from ..kernels import ops
 from . import group_screening as gscr
 from . import screening as scr
+from . import tracing
 
 # Full HBM passes over X that one screen costs, per rule: through the engine
 # (norms/argmax geometry cached in the workspace) vs the hand-rolled jnp
@@ -163,6 +164,7 @@ def block_scores(Xb, centre, rho, col_norms=None):
 # ---------------------------------------------------------------------------
 
 @jax.jit
+@jax.named_scope("screen")
 def _sphere_combine(dot, rho, col_norms, eps):
     if dot.ndim == 2:
         return jnp.abs(dot) + scr._col(rho) * col_norms \
@@ -171,6 +173,7 @@ def _sphere_combine(dot, rho, col_norms, eps):
 
 
 @jax.jit
+@jax.named_scope("screen")
 def _gap_combine_from(dot, sup_corr, y, lam_next, state, col_norms, eps):
     """The GAP combine with the feasibility rescale ``sup_corr = ‖Xᵀθ₀‖∞``
     supplied explicitly — shared by the one-pass f32 combine (sup_corr from
@@ -185,6 +188,7 @@ def _gap_combine_from(dot, sup_corr, y, lam_next, state, col_norms, eps):
 
 
 @jax.jit
+@jax.named_scope("screen")
 def _gap_combine(dot, y, lam_next, state, col_norms, eps):
     sup_corr = (jnp.max(jnp.abs(dot), axis=-1) if dot.ndim == 2
                 else jnp.max(jnp.abs(dot)))
@@ -193,6 +197,7 @@ def _gap_combine(dot, y, lam_next, state, col_norms, eps):
 
 
 @jax.jit
+@jax.named_scope("screen")
 def _strong_combine(dot, lam_next, lam_prev, eps):
     if dot.ndim == 2:
         return jnp.abs(dot) < scr._col(2.0 * lam_next - lam_prev - eps)
@@ -206,6 +211,7 @@ def _strong_combine(dot, lam_next, lam_prev, eps):
 # (kernels/ops.bf16_score_margin) and must be re-tested in full precision.
 
 @jax.jit
+@jax.named_scope("screen")
 def _sphere_combine_margin(dot, rho, col_norms, eps, margin):
     if dot.ndim == 2:
         scores = jnp.abs(dot) + scr._col(rho) * col_norms
@@ -217,6 +223,7 @@ def _sphere_combine_margin(dot, rho, col_norms, eps, margin):
 
 
 @jax.jit
+@jax.named_scope("screen")
 def _strong_combine_margin(dot, lam_next, lam_prev, eps, margin):
     if dot.ndim == 2:
         thresh = scr._col(2.0 * lam_next - lam_prev - eps)
@@ -227,12 +234,14 @@ def _strong_combine_margin(dot, lam_next, lam_prev, eps, margin):
 
 
 @jax.jit
+@jax.named_scope("screen")
 def _dome_combine(scores_c, gdot, col_norms, c, rho, ghat, b, eps):
     return scr.dome_scores(scores_c, gdot, col_norms, c, rho, ghat, b) \
         < 1.0 - eps
 
 
 @jax.jit
+@jax.named_scope("screen")
 def _gap_cut_combine_from(dot, gdot, sup_corr, y, lam_next, state, col_norms,
                           ghat, b, eps):
     """The gap_cut combine with ``sup_corr`` supplied explicitly (see
@@ -247,6 +256,7 @@ def _gap_cut_combine_from(dot, gdot, sup_corr, y, lam_next, state, col_norms,
 
 
 @jax.jit
+@jax.named_scope("screen")
 def _gap_cut_combine(dot, gdot, y, lam_next, state, col_norms, ghat, b, eps):
     """gap_cut: the GAP sphere's feasibility rescale (served by the dot the
     pass already produced, exactly like _gap_combine) composed with the
@@ -282,6 +292,7 @@ def _gap_cut_combine(dot, gdot, y, lam_next, state, col_norms, ghat, b, eps):
 # to the true threshold straddlers (tens of columns, not hundreds).
 
 @jax.jit
+@jax.named_scope("screen")
 def _dome_combine_margin(scores_c, gdot, e_c, e_g, col_norms, c, rho, ghat,
                          b, eps):
     t_b = scr.dome_t_b(c, rho, ghat, b)
@@ -293,6 +304,7 @@ def _dome_combine_margin(scores_c, gdot, e_c, e_g, col_norms, c, rho, ghat,
 
 
 @jax.jit
+@jax.named_scope("screen")
 def _gap_cand(dot, margin):
     """Argmax-candidate mask for the exact sup_corr recovery: every column
     whose bf16 upper bound |d̃_j| + m_j reaches the best lower bound
@@ -315,6 +327,7 @@ def _gap_cand(dot, margin):
 
 
 @jax.jit
+@jax.named_scope("screen")
 def _gap_combine_margin(dot, margin, sup_corr, y, lam_next, state,
                         col_norms, eps):
     """GAP margin combine with the EXACT f32 rescale in hand (see the
@@ -336,6 +349,7 @@ def _gap_combine_margin(dot, margin, sup_corr, y, lam_next, state,
 
 
 @jax.jit
+@jax.named_scope("screen")
 def _gap_cut_combine_margin(dot, gdot, e_c, e_g, sup_corr, y, lam_next,
                             state, col_norms, ghat, b, eps):
     """gap_cut margin combine with the exact rescale: the sphere geometry
@@ -354,6 +368,7 @@ def _gap_cut_combine_margin(dot, gdot, e_c, e_g, sup_corr, y, lam_next,
 
 
 @jax.jit
+@jax.named_scope("state")
 def _make_state(X, y, beta, lam, lmax, v1max):
     """Sequential DualState with the λ_max branch served from cache — no
     per-step Xᵀy pass (make_dual_state recomputes it every call)."""
@@ -371,6 +386,7 @@ def _make_state(X, y, beta, lam, lmax, v1max):
 
 
 @jax.jit
+@jax.named_scope("state")
 def _make_state_batched(X, y, beta, lam, lmax, v1max):
     """Batched `_make_state`: y/beta (B, ·), lam/lmax (B,), v1max (B, n).
     Each query selects its own eq. (17) branch."""
@@ -389,6 +405,7 @@ def _make_state_batched(X, y, beta, lam, lmax, v1max):
 
 
 @jax.jit
+@jax.named_scope("state")
 def _make_state_fit(y, fitted, beta, lam, lmax, v1max):
     """`_make_state` with the fitted values Xβ supplied by the caller.
 
@@ -412,6 +429,7 @@ def _make_state_fit(y, fitted, beta, lam, lmax, v1max):
 
 
 @jax.jit
+@jax.named_scope("state")
 def _make_state_batched_fit(y, fitted, beta, lam, lmax, v1max):
     """Batched `_make_state_fit`: y/fitted (B, n), beta (B, p), lam (B,)."""
     theta_seq = (y - fitted) / scr._col(lam)
@@ -429,6 +447,7 @@ def _make_state_batched_fit(y, fitted, beta, lam, lmax, v1max):
 
 
 @jax.jit
+@jax.named_scope("state")
 def _make_group_state(X, y, beta, lam, lmax, theta_max, v1max):
     theta_seq = (y - X @ beta) / lam
     at_max = lam >= lmax * (1.0 - 1e-12)
@@ -440,6 +459,7 @@ def _make_group_state(X, y, beta, lam, lmax, theta_max, v1max):
 
 
 @jax.jit
+@jax.named_scope("state")
 def _make_group_state_fit(y, fitted, beta, lam, lmax, theta_max, v1max):
     """`_make_group_state` from caller-supplied fitted values Xβ."""
     theta_seq = (y - fitted) / lam
@@ -452,6 +472,7 @@ def _make_group_state_fit(y, fitted, beta, lam, lmax, theta_max, v1max):
 
 
 @jax.jit
+@jax.named_scope("screen")
 def _group_edpp_geometry(y, lam_next, state):
     vp = gscr.group_v2_perp(y, lam_next, state)
     return state.theta + 0.5 * vp, 0.5 * jnp.linalg.norm(vp)
@@ -480,6 +501,7 @@ def _patch_slots_impl(X, vecs, slots, blk, vec_blocks, lo_dtypes):
 
 
 @jax.jit
+@jax.named_scope("screen")
 def _stream_fit_single(X, istar, y):
     """λ_max ray v₁ = sign(x*ᵀy)·x* and the DOME halfspace direction for a
     single query — the ONE jitted helper both the cold PathWorkspace fit
@@ -494,6 +516,7 @@ def _stream_fit_single(X, istar, y):
 
 
 @jax.jit
+@jax.named_scope("screen")
 def _stream_fit_batched(X, istar, y):
     """Batched twin of :func:`_stream_fit_single` — (B,) argmaxes."""
     acc = jnp.promote_types(X.dtype, jnp.float32)
@@ -828,17 +851,17 @@ class PathWorkspace:
         self.batch = None if y_arr.ndim == 1 else y_arr.shape[0]
         self.abs_xty = scores                     # |Xᵀy|, (p,) or (B, p)
         if self.batch is None:
-            self.istar = int(jnp.argmax(scores))
-            self.lam_max = float(scores[self.istar])
+            self.istar = int(tracing.fetch(jnp.argmax(scores)))
+            self.lam_max = float(tracing.fetch(scores[self.istar]))
             # eq. (17) at λ₀ = λ_max, + the DOME halfspace direction
             self.v1_at_lmax, self.ghat = _stream_fit_single(
                 self.X, jnp.asarray(self.istar, jnp.int32), self.y)
         else:
             istar = jnp.argmax(scores, axis=-1)               # (B,)
-            self.istar = np.asarray(istar)
-            self.lam_max = np.asarray(
+            self.istar = tracing.fetch(istar)
+            self.lam_max = tracing.fetch(
                 jnp.take_along_axis(scores, istar[:, None], axis=-1)[:, 0],
-                dtype=np.float64)                             # (B,)
+                np.float64)                                   # (B,)
             self.v1_at_lmax, self.ghat = _stream_fit_batched(
                 self.X, istar, self.y)
 
@@ -1014,7 +1037,7 @@ class ScreeningEngine:
         narrow full-precision pass IS the f32 decision. Returns
         (mask, extra_passes, extra_bytes)."""
         ws = self.ws
-        band_np = np.asarray(band)
+        band_np = tracing.fetch(band)
         cols = np.flatnonzero(
             band_np if band_np.ndim == 1 else band_np.any(axis=0))
         self.last_fallback_cols = int(cols.size)
@@ -1031,8 +1054,8 @@ class ScreeningEngine:
         idx_dev = jnp.asarray(idx)
         Xn = jnp.take(ws.X, idx_dev, axis=1)      # full-precision columns
         dec_n = recompute(Xn, idx_dev)
-        out = np.asarray(dec).copy()
-        out[..., cols] = np.asarray(dec_n)[..., :cols.size]
+        out = tracing.fetch(dec).copy()
+        out[..., cols] = tracing.fetch(dec_n)[..., :cols.size]
         return jnp.asarray(out), 1, float(ws.X.shape[0]) * bucket \
             * ws.X.dtype.itemsize
 
@@ -1049,7 +1072,7 @@ class ScreeningEngine:
         query's sup, so they never corrupt the max. Returns
         (sup_corr, gather_bytes)."""
         ws = self.ws
-        cand_np = np.asarray(cand)
+        cand_np = tracing.fetch(cand)
         cols = np.flatnonzero(
             cand_np if cand_np.ndim == 1 else cand_np.any(axis=0))
         p = ws.X.shape[1]
@@ -1345,8 +1368,8 @@ class GroupScreeningEngine:
         self.eps = eps
         gscores = self.backend.group_scores(self.X, self.y, m)   # ‖X_gᵀy‖
         gnorms = gscores / jnp.sqrt(float(m))
-        self.gstar = int(jnp.argmax(gnorms))
-        self.lam_max = float(gnorms[self.gstar])
+        self.gstar = int(tracing.fetch(jnp.argmax(gnorms)))
+        self.lam_max = float(tracing.fetch(gnorms[self.gstar]))
         Xstar = jax.lax.dynamic_slice_in_dim(
             self.X, self.gstar * m, m, axis=1)                   # (N, m)
         self.v1_at_lmax = Xstar @ (Xstar.T @ self.y)             # eq. (59)

@@ -32,6 +32,7 @@ def soft_threshold(u: jax.Array, thresh) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames="iters")
+@jax.named_scope("solve")
 def _power_iterate(X: jax.Array, v0: jax.Array, iters: int):
     v = v0 / (jnp.linalg.norm(v0) + 1e-30)
 

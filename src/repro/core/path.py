@@ -63,6 +63,7 @@ import numpy as np
 
 from . import screening as scr
 from . import group_screening as gscr
+from . import tracing
 
 
 def next_pow2(n: int) -> int:
@@ -96,20 +97,25 @@ class PathStepStats:
     batch_size: int = 1           # queries screened/solved together this step
     queries_converged: int = 0    # queries whose reduced solve converged
     x_passes_per_query: float = 0.0  # amortised screen passes: x_passes/B
-    screen_bytes: float = 0.0     # HBM bytes this step's screens streamed
-    #                               (bf16 screen_dtype ≈ halves this; the
-    #                               narrow fallback pass is counted in)
     screen_dtype_effective: str = ""  # dtype the screen stream actually ran
     #                               ("float32" when a bf16 request fell back)
     solve_dtype_effective: str = ""   # dtype the solver matvecs streamed
     solver_lo_iters: int = 0      # solver iterations run on the bf16 stream
-    solve_bytes: float = 0.0      # HBM bytes this step's solves streamed
-    #                               (bf16 iteration passes counted at 2 B/el,
-    #                               f32 certificates/polish at 4)
     geometry_version: int = 0     # dictionary version this step ran against
     #                               (0 at fit; +1 per session.update — lets
     #                               serve traces attribute results to the
     #                               dictionary they were computed on)
+    # Host-side breakdown of the step (core/tracing.py; each interval is
+    # also a span in the profiler's trace, docs/serving.md#tracing-a-
+    # served-path):
+    host_syncs: int = 0           # device→host reads (tracing.fetch)
+    host_sync_s: float = 0.0      # time spent in them
+    gather_time_s: float = 0.0    # path.gather: bucket columns + warm start
+    copyout_time_s: float = 0.0   # path.copyout: float64 β and the mask
+    state_time_s: float = 0.0     # path.state: the next step's dual state
+    step_time_s: float = 0.0      # path.step: the whole step
+    compiles: int = 0             # programs compiled or loaded from the
+    #                               persistent cache during the step
 
 
 @dataclasses.dataclass
@@ -189,6 +195,7 @@ class PathResult:
 
 
 @functools.partial(jax.jit, static_argnames=("bucket",))
+@jax.named_scope("gather")
 def _gather_cols(X: jax.Array, idx: jax.Array, valid: jax.Array, bucket: int):
     """Gather `bucket` columns (zero-filled where invalid)."""
     cols = jnp.take(X, idx, axis=1, mode="clip")
@@ -269,194 +276,225 @@ def _path_driver(X, Y, lambdas, cfg, *, m: int, screen_engine,
             "grids must be decreasing"
         K = lambdas.shape[1]
 
-    lmax = np.atleast_1d(np.asarray(screen_engine.lam_max,
-                                    dtype=np.float64))      # (B,)
-    state = screen_engine.state_at_lambda_max()
-    arange_m = np.arange(m)[None, :]
-    geo_version = int(getattr(getattr(screen_engine, "geometry", None),
-                              "version", 0))
+    with tracing.span("path.prologue"):
+        lmax = np.atleast_1d(np.asarray(screen_engine.lam_max,
+                                        dtype=np.float64))      # (B,)
+        state = screen_engine.state_at_lambda_max()
+        arange_m = np.arange(m)[None, :]
+        geo_version = int(getattr(getattr(screen_engine, "geometry", None),
+                                  "version", 0))
 
-    betas = np.zeros((B, K, p), dtype=np.float64)
-    masks = np.ones((B, K, units), dtype=bool)
-    stats: list[PathStepStats] = []
-    beta_prev = jnp.zeros((B, p), dtype=X.dtype)
-    # per-query completion: a query stays True iff every non-trivial
-    # reduced solve it took part in converged (PathResult.query_converged)
-    q_converged = np.ones((B,), dtype=bool)
+        betas = np.zeros((B, K, p), dtype=np.float64)
+        masks = np.ones((B, K, units), dtype=bool)
+        stats: list[PathStepStats] = []
+        beta_prev = jnp.zeros((B, p), dtype=X.dtype)
+        # per-query completion: a query stays True iff every non-trivial
+        # reduced solve it took part in converged (query_converged)
+        q_converged = np.ones((B,), dtype=bool)
 
+    steps = []                    # each λ step's tracing counters
     for k in range(K):
-        lam_vec = lambdas[None, k] if batch is None else lambdas[:, k]
-        live = lam_vec < lmax          # per-query trivial region (eq. 8)
-        if not live.any():             # β* = 0 for the whole batch
-            stats.append(PathStepStats(
-                float(lam_vec.max()), units, 0, 0, 0.0, 0, 0.0, 0.0,
-                batch_size=B, queries_converged=B,
-                geometry_version=geo_version))
-            if cfg.checkpoint_fn:
+        with tracing.step(k) as step:
+            steps.append(step)
+            lam_vec = lambdas[None, k] if batch is None else lambdas[:, k]
+            live = lam_vec < lmax          # per-query trivial region (eq. 8)
+            if not live.any():             # β* = 0 for the whole batch
+                stats.append(PathStepStats(
+                    float(lam_vec.max()), units, 0, 0, 0.0, 0, 0.0, 0.0,
+                    batch_size=B, queries_converged=B,
+                    geometry_version=geo_version))
+                if cfg.checkpoint_fn:
+                    if batch is None:
+                        cfg.checkpoint_fn(k, float(lam_vec[0]), np.zeros((p,)))
+                    else:
+                        cfg.checkpoint_fn(k, lam_vec, np.zeros((B, p)))
+                continue
+
+            # ---- screen (one fused kernel pass over X for ALL queries) --
+            with tracing.span("path.screen"):
+                t0 = time.perf_counter()
+                lam_dev = (float(lam_vec[0]) if batch is None
+                           else jnp.asarray(lam_vec, X.dtype))
+                discard = screen_engine.screen(lam_dev, state, rule=cfg.rule)
+                screen_passes = screen_engine.last_x_passes
+                screen_dtype_eff = getattr(screen_engine,
+                                           "last_effective_dtype", "float32")
+                if hybrid:
+                    discard = discard | screen_engine.screen(lam_dev, state,
+                                                             rule="strong")
+                    screen_passes += screen_engine.last_x_passes
+                discard_np = tracing.fetch(discard)
                 if batch is None:
-                    cfg.checkpoint_fn(k, float(lam_vec[0]), np.zeros((p,)))
-                else:
-                    cfg.checkpoint_fn(k, lam_vec, np.zeros((B, p)))
-            continue
+                    discard_np = discard_np[None, :]
+                # dead queries keep nothing
+                discard_np = discard_np | ~live[:, None]
+                screen_time = time.perf_counter() - t0
 
-        # ---- screen (one fused kernel pass over X for ALL queries) ------
-        t0 = time.perf_counter()
-        lam_dev = (float(lam_vec[0]) if batch is None
-                   else jnp.asarray(lam_vec, X.dtype))
-        discard = screen_engine.screen(lam_dev, state, rule=cfg.rule)
-        screen_passes = screen_engine.last_x_passes
-        screen_bytes = getattr(screen_engine, "last_screen_bytes", 0.0)
-        screen_dtype_eff = getattr(screen_engine, "last_effective_dtype",
-                                   "float32")
-        if hybrid:
-            discard = discard | screen_engine.screen(lam_dev, state,
-                                                     rule="strong")
-            screen_passes += screen_engine.last_x_passes
-            screen_bytes += getattr(screen_engine, "last_screen_bytes", 0.0)
-        discard_np = np.asarray(discard)
-        if batch is None:
-            discard_np = discard_np[None, :]
-        discard_np = discard_np | ~live[:, None]   # dead queries keep nothing
-        screen_time = time.perf_counter() - t0
-
-        # ---- reduced solve (+ strong-rule KKT loop) ----------------------
-        t0 = time.perf_counter()
-        kkt_rounds = 0
-        solves = gram_solves = gap_checks = 0
-        solver_x_passes = 0.0
-        solver_lo_iters = 0
-        solve_bytes = 0.0
-        solve_dtype_eff = "float32"
-        bucket = 0
-        res_iters, res_gap, q_conv = 0, 0.0, B
-        conv_vec = np.ones((B,), dtype=bool)
-        while True:
-            # union of survivors across the batch: one shared buffer
-            kept = np.flatnonzero((~discard_np).any(axis=0))
-            bucket = min(next_pow2(max(kept.size, bucket_min)), units)
-            if kept.size == 0:
-                beta_full = jnp.zeros((B, p), dtype=X.dtype)
-                fitted = jnp.zeros((B, X.shape[0]), dtype=X.dtype)
+            # ---- reduced solve (+ strong-rule KKT loop) ------------------
+            with tracing.span("path.solve"):
+                t0 = time.perf_counter()
+                kkt_rounds = 0
+                solves = gram_solves = gap_checks = 0
+                solver_x_passes = 0.0
+                solver_lo_iters = 0
+                gather_time = 0.0
+                solve_dtype_eff = "float32"
+                bucket = 0
                 res_iters, res_gap, q_conv = 0, 0.0, B
                 conv_vec = np.ones((B,), dtype=bool)
-            else:
-                col_idx = (kept[:, None] * m + arange_m).reshape(-1)
-                idx, valid = _pad_indices(col_idx, bucket * m)
-                Xr = _gather_cols(X, idx, valid, bucket * m)
-                if reshard is not None:
-                    Xr = reshard(Xr)
-                lo = None
-                if lo_gather is not None:
-                    lo = lo_gather(idx, valid, bucket * m)
-                    if reshard is not None:
-                        lo = (reshard(lo[0]),) + tuple(lo[1:])
+                while True:
+                    # union of survivors across the batch: one shared buffer
+                    kept = np.flatnonzero((~discard_np).any(axis=0))
+                    bucket = min(next_pow2(max(kept.size, bucket_min)), units)
+                    if kept.size == 0:
+                        beta_full = jnp.zeros((B, p), dtype=X.dtype)
+                        fitted = jnp.zeros((B, X.shape[0]), dtype=X.dtype)
+                        res_iters, res_gap, q_conv = 0, 0.0, B
+                        conv_vec = np.ones((B,), dtype=bool)
+                    else:
+                        with tracing.span("path.gather") as gather:
+                            col_idx = (kept[:, None] * m
+                                       + arange_m).reshape(-1)
+                            idx, valid = _pad_indices(col_idx, bucket * m)
+                            Xr = _gather_cols(X, idx, valid, bucket * m)
+                            if reshard is not None:
+                                Xr = reshard(Xr)
+                            lo = None
+                            if lo_gather is not None:
+                                lo = lo_gather(idx, valid, bucket * m)
+                                if reshard is not None:
+                                    lo = (reshard(lo[0]),) + tuple(lo[1:])
+                            if batch is None:
+                                beta0 = jnp.take(beta_prev[0], idx) * valid
+                            else:
+                                # per-query validity on the union buffer: each
+                                # query solves exactly its own reduced problem
+                                kept_q = np.repeat(~discard_np[:, kept], m,
+                                                   axis=1)
+                                vq_np = np.zeros((B, bucket * m),
+                                                 dtype=np.float32)
+                                vq_np[:, : col_idx.size] = kept_q
+                                vq = jnp.asarray(vq_np)
+                                beta0 = jnp.take(beta_prev, idx, axis=1) * vq
+                        gather_time += gather.seconds
+                        if batch is None:
+                            res = solver_engine.solve(Xr, float(lam_vec[0]),
+                                                      beta0, m=m, lo=lo)
+                            with tracing.span("path.scatter"):
+                                beta_full = (
+                                    jnp.zeros((p,), dtype=X.dtype)
+                                    .at[col_idx]
+                                    .set(res.beta[: col_idx.size])
+                                )[None, :]
+                            res_iters = int(tracing.fetch(res.iters))
+                            res_gap = float(tracing.fetch(res.gap))
+                            q_conv = int(bool(tracing.fetch(res.converged)))
+                            conv_vec = np.array(
+                                [bool(tracing.fetch(res.converged))])
+                            # fitted values from the reduced bucket
+                            # (replicated, shard-invariant) — feeds KKT and
+                            # the next dual state
+                            with tracing.span("path.scatter"):
+                                fitted = (Xr @ res.beta)[None, :]
+                        else:
+                            res = solver_engine.solve_batched(
+                                Xr, jnp.asarray(lam_vec, X.dtype), beta0,
+                                valid=vq, m=m, lo=lo)
+                            with tracing.span("path.scatter"):
+                                beta_full = (
+                                    jnp.zeros((B, p), dtype=X.dtype)
+                                    .at[:, col_idx]
+                                    .set(res.beta[:, : col_idx.size])
+                                )
+                            res_iters = int(tracing.fetch(jnp.max(res.iters)))
+                            res_gap = float(tracing.fetch(jnp.max(res.gap)))
+                            q_conv = int(tracing.fetch(jnp.sum(res.converged)))
+                            conv_vec = tracing.fetch(
+                                res.converged).astype(bool)
+                            with tracing.span("path.scatter"):
+                                fitted = res.beta @ Xr.T               # (B, n)
+                        solves += 1
+                        gram_solves += int(solver_engine.last_used_gram)
+                        gap_checks += solver_engine.last_gap_checks
+                        solver_x_passes += (solver_engine.last_x_passes
+                                            * (bucket * m) / p)
+                        solver_lo_iters += getattr(solver_engine,
+                                                   "last_lo_iters", 0)
+                        solve_dtype_eff = getattr(solver_engine,
+                                                  "last_effective_dtype",
+                                                  "float32")
+                    if not need_kkt:
+                        break
+                    with tracing.span("path.kkt"):
+                        if batch is None:
+                            viol = tracing.fetch(kkt_fn(
+                                beta_full[0], float(lam_vec[0]),
+                                jnp.asarray(discard_np[0]),
+                                fitted[0]))[None, :]
+                        else:
+                            viol = tracing.fetch(kkt_fn(
+                                beta_full, jnp.asarray(lam_vec, X.dtype),
+                                jnp.asarray(discard_np), fitted))
+                    viol = viol & live[:, None]
+                    if not viol.any() or kkt_rounds >= cfg.max_kkt_rounds:
+                        break
+                    kkt_rounds += 1
+                    discard_np = discard_np & ~viol
+                solve_time = time.perf_counter() - t0
+
+            with tracing.span("path.copyout") as copyout:
+                betas[:, k] = tracing.fetch(beta_full, np.float64)
+                masks[:, k] = discard_np
+            # a dead (trivial-region) query's lane is vacuously converged
+            q_converged &= conv_vec | ~live
+            stats.append(PathStepStats(
+                lam=(float(lam_vec[0]) if batch is None
+                     else float(lam_vec.max())),
+                n_discarded=int(discard_np.all(axis=0).sum()),
+                n_kept=int(kept.size),
+                solver_iters=res_iters, gap=res_gap, kkt_rounds=kkt_rounds,
+                screen_time_s=screen_time, solve_time_s=solve_time,
+                x_passes=screen_passes,
+                gap_checks=gap_checks,
+                gram_step_frac=gram_solves / solves if solves else 0.0,
+                solver_backend=solver_engine.backend_name,
+                screen_backend=screen_engine.backend_name,
+                bucket=bucket * m,
+                solver_x_passes=solver_x_passes,
+                batch_size=B,
+                queries_converged=q_conv,
+                x_passes_per_query=screen_passes / B,
+                screen_dtype_effective=screen_dtype_eff,
+                solve_dtype_effective=solve_dtype_eff,
+                solver_lo_iters=solver_lo_iters,
+                geometry_version=geo_version,
+                gather_time_s=gather_time,
+                copyout_time_s=copyout.seconds,
+            ))
+            if cfg.checkpoint_fn:
                 if batch is None:
-                    beta0 = jnp.take(beta_prev[0], idx) * valid
-                    res = solver_engine.solve(Xr, float(lam_vec[0]), beta0,
-                                              m=m, lo=lo)
-                    beta_full = (
-                        jnp.zeros((p,), dtype=X.dtype)
-                        .at[col_idx]
-                        .set(res.beta[: col_idx.size])
-                    )[None, :]
-                    res_iters, res_gap = int(res.iters), float(res.gap)
-                    q_conv = int(bool(res.converged))
-                    conv_vec = np.array([bool(res.converged)])
-                    # fitted values from the reduced bucket (replicated,
-                    # shard-invariant) — feeds KKT and the next dual state
-                    fitted = (Xr @ res.beta)[None, :]
+                    cfg.checkpoint_fn(k, float(lam_vec[0]), betas[0, k])
                 else:
-                    # per-query validity on the union buffer: each query
-                    # solves exactly its own reduced problem
-                    kept_q = np.repeat(~discard_np[:, kept], m, axis=1)
-                    vq_np = np.zeros((B, bucket * m), dtype=np.float32)
-                    vq_np[:, : col_idx.size] = kept_q
-                    vq = jnp.asarray(vq_np)
-                    beta0 = jnp.take(beta_prev, idx, axis=1) * vq
-                    res = solver_engine.solve_batched(
-                        Xr, jnp.asarray(lam_vec, X.dtype), beta0,
-                        valid=vq, m=m, lo=lo)
-                    beta_full = (
-                        jnp.zeros((B, p), dtype=X.dtype)
-                        .at[:, col_idx]
-                        .set(res.beta[:, : col_idx.size])
-                    )
-                    res_iters = int(jnp.max(res.iters))
-                    res_gap = float(jnp.max(res.gap))
-                    q_conv = int(jnp.sum(res.converged))
-                    conv_vec = np.asarray(res.converged).astype(bool)
-                    fitted = res.beta @ Xr.T               # (B, n)
-                solves += 1
-                gram_solves += int(solver_engine.last_used_gram)
-                gap_checks += solver_engine.last_gap_checks
-                solver_x_passes += (solver_engine.last_x_passes
-                                    * (bucket * m) / p)
-                solver_lo_iters += getattr(solver_engine,
-                                           "last_lo_iters", 0)
-                solve_bytes += getattr(solver_engine,
-                                       "last_solve_bytes", 0.0)
-                solve_dtype_eff = getattr(solver_engine,
-                                          "last_effective_dtype", "float32")
-            if not need_kkt:
-                break
-            if batch is None:
-                viol = np.asarray(kkt_fn(beta_full[0], float(lam_vec[0]),
-                                         jnp.asarray(discard_np[0]),
-                                         fitted[0]))[None, :]
-            else:
-                viol = np.asarray(kkt_fn(beta_full,
-                                         jnp.asarray(lam_vec, X.dtype),
-                                         jnp.asarray(discard_np), fitted))
-            viol = viol & live[:, None]
-            if not viol.any() or kkt_rounds >= cfg.max_kkt_rounds:
-                break
-            kkt_rounds += 1
-            discard_np = discard_np & ~viol
-        solve_time = time.perf_counter() - t0
+                    cfg.checkpoint_fn(k, lam_vec, betas[:, k])
 
-        betas[:, k] = np.asarray(beta_full, dtype=np.float64)
-        masks[:, k] = discard_np
-        # a dead (trivial-region) query's lane is vacuously converged
-        q_converged &= conv_vec | ~live
-        stats.append(PathStepStats(
-            lam=float(lam_vec[0]) if batch is None else float(lam_vec.max()),
-            n_discarded=int(discard_np.all(axis=0).sum()),
-            n_kept=int(kept.size),
-            solver_iters=res_iters, gap=res_gap, kkt_rounds=kkt_rounds,
-            screen_time_s=screen_time, solve_time_s=solve_time,
-            x_passes=screen_passes,
-            gap_checks=gap_checks,
-            gram_step_frac=gram_solves / solves if solves else 0.0,
-            solver_backend=solver_engine.backend_name,
-            screen_backend=screen_engine.backend_name,
-            bucket=bucket * m,
-            solver_x_passes=solver_x_passes,
-            batch_size=B,
-            queries_converged=q_conv,
-            x_passes_per_query=screen_passes / B,
-            screen_bytes=screen_bytes,
-            screen_dtype_effective=screen_dtype_eff,
-            solve_dtype_effective=solve_dtype_eff,
-            solver_lo_iters=solver_lo_iters,
-            solve_bytes=solve_bytes,
-            geometry_version=geo_version,
-        ))
-        if cfg.checkpoint_fn:
-            if batch is None:
-                cfg.checkpoint_fn(k, float(lam_vec[0]), betas[0, k])
-            else:
-                cfg.checkpoint_fn(k, lam_vec, betas[:, k])
+            beta_prev = beta_full
+            if cfg.sequential:
+                with tracing.span("path.state") as state_span:
+                    if batch is None:
+                        state = screen_engine.make_state(beta_full[0],
+                                                         float(lam_vec[0]),
+                                                         fitted=fitted[0])
+                    else:
+                        state = screen_engine.make_state(
+                            beta_full, jnp.asarray(lam_vec, X.dtype),
+                            fitted=fitted)
+                stats[-1].state_time_s = state_span.seconds
+            # basic variants keep `state` pinned at λmax (paper §4.1.1)
 
-        beta_prev = beta_full
-        if cfg.sequential:
-            if batch is None:
-                state = screen_engine.make_state(beta_full[0],
-                                                 float(lam_vec[0]),
-                                                 fitted=fitted[0])
-            else:
-                state = screen_engine.make_state(
-                    beta_full, jnp.asarray(lam_vec, X.dtype), fitted=fitted)
-        # basic variants keep `state` pinned at λmax (paper §4.1.1)
+    for st, step in zip(stats, steps):
+        st.host_syncs, st.host_sync_s = step.host_syncs, step.host_sync_s
+        st.compiles, st.step_time_s = step.compiles, step.seconds
+
     # Unified result: the leading batch axis is ALWAYS present (B = 1 for a
     # single query — the values are bit-identical to the squeezed layout).
     if batch is None:

@@ -268,6 +268,7 @@ SPHERE_RULES = {
 
 
 @functools.partial(jax.jit, static_argnames=("rule",))
+@jax.named_scope("screen")
 def make_sphere(rule: str, y, lam_next, state: DualState) -> SphereTest:
     """Jitted dispatch over the sequential sphere constructors."""
     return SPHERE_RULES[rule](y, lam_next, state)

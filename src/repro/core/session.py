@@ -70,6 +70,7 @@ import numpy as np
 
 from ..kernels import ops
 from . import screening as scr
+from . import tracing
 from .engine import (
     DictionaryGeometry,
     GroupDictionaryGeometry,
@@ -797,12 +798,13 @@ class LassoSession:
         return heuristic or hybrid or cfg.screen.paranoid
 
     def _lasso_path(self, y, lambdas, cfg, grid_kw) -> PathResult:
-        eng = ScreeningEngine(self.X, y, eps=cfg.screen.eps,
-                              geometry=self._geometry(cfg.screen.backend),
-                              screen_dtype=cfg.screen.screen_dtype)
-        if lambdas is None:
-            lambdas = lambda_grid(float(eng.lam_max), **grid_kw)
-        solver = self._solver_engine(y, cfg)
+        with tracing.span("path.prologue"):
+            eng = ScreeningEngine(self.X, y, eps=cfg.screen.eps,
+                                  geometry=self._geometry(cfg.screen.backend),
+                                  screen_dtype=cfg.screen.screen_dtype)
+            if lambdas is None:
+                lambdas = lambda_grid(float(eng.lam_max), **grid_kw)
+            solver = self._solver_engine(y, cfg)
         X = self.X
 
         def kkt_fn(beta_full, lam, discard, fitted=None):
@@ -828,19 +830,20 @@ class LassoSession:
             # (tests/test_batched_path.py).
             return self._lasso_path(Y[0], _squeeze_grid(lambdas), cfg,
                                     grid_kw)
-        eng = ScreeningEngine(self.X, Y, eps=cfg.screen.eps,
-                              geometry=self._geometry(cfg.screen.backend),
-                              screen_dtype=cfg.screen.screen_dtype)
-        if lambdas is None:
-            lambdas = np.stack([
-                lambda_grid(float(lm), **grid_kw)
-                for lm in np.atleast_1d(eng.lam_max)])
-        else:
-            lambdas = np.asarray(lambdas, dtype=np.float64)
-            if lambdas.ndim == 1:
-                lambdas = np.broadcast_to(
-                    lambdas, (B, lambdas.shape[0])).copy()
-        solver = self._solver_engine(Y, cfg)
+        with tracing.span("path.prologue"):
+            eng = ScreeningEngine(self.X, Y, eps=cfg.screen.eps,
+                                  geometry=self._geometry(cfg.screen.backend),
+                                  screen_dtype=cfg.screen.screen_dtype)
+            if lambdas is None:
+                lambdas = np.stack([
+                    lambda_grid(float(lm), **grid_kw)
+                    for lm in np.atleast_1d(eng.lam_max)])
+            else:
+                lambdas = np.asarray(lambdas, dtype=np.float64)
+                if lambdas.ndim == 1:
+                    lambdas = np.broadcast_to(
+                        lambdas, (B, lambdas.shape[0])).copy()
+            solver = self._solver_engine(Y, cfg)
         X = self.X
 
         def kkt_fn(beta_full, lam, discard, fitted=None):
@@ -855,11 +858,13 @@ class LassoSession:
 
     def _group_path(self, y, lambdas, cfg, grid_kw) -> PathResult:
         m = self.groups
-        eng = GroupScreeningEngine(self.X, y, m, eps=cfg.screen.eps,
-                                   geometry=self._geometry(cfg.screen.backend))
-        if lambdas is None:
-            lambdas = lambda_grid(float(eng.lam_max), **grid_kw)
-        solver = self._solver_engine(y, cfg)
+        with tracing.span("path.prologue"):
+            eng = GroupScreeningEngine(
+                self.X, y, m, eps=cfg.screen.eps,
+                geometry=self._geometry(cfg.screen.backend))
+            if lambdas is None:
+                lambdas = lambda_grid(float(eng.lam_max), **grid_kw)
+            solver = self._solver_engine(y, cfg)
         X = self.X
 
         def kkt_fn(beta_full, lam, discard, fitted=None):
@@ -931,6 +936,13 @@ def _merge_step_stats(steps: list[PathStepStats]) -> PathStepStats:
         kkt_rounds=max(s.kkt_rounds for s in steps),
         screen_time_s=sum(s.screen_time_s for s in steps),
         solve_time_s=sum(s.solve_time_s for s in steps),
+        host_syncs=sum(s.host_syncs for s in steps),
+        host_sync_s=sum(s.host_sync_s for s in steps),
+        gather_time_s=sum(s.gather_time_s for s in steps),
+        copyout_time_s=sum(s.copyout_time_s for s in steps),
+        state_time_s=sum(s.state_time_s for s in steps),
+        step_time_s=sum(s.step_time_s for s in steps),
+        compiles=sum(s.compiles for s in steps),
         x_passes=x_passes,
         gap_checks=sum(s.gap_checks for s in steps),
         gram_step_frac=float(np.mean([s.gram_step_frac for s in steps])),
@@ -941,10 +953,8 @@ def _merge_step_stats(steps: list[PathStepStats]) -> PathStepStats:
         batch_size=B,
         queries_converged=sum(s.queries_converged for s in steps),
         x_passes_per_query=x_passes / B,
-        screen_bytes=sum(s.screen_bytes for s in steps),
         screen_dtype_effective=steps[0].screen_dtype_effective,
         solve_dtype_effective=steps[0].solve_dtype_effective,
         solver_lo_iters=sum(s.solver_lo_iters for s in steps),
-        solve_bytes=sum(s.solve_bytes for s in steps),
         geometry_version=steps[0].geometry_version,
     )

@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import ops
+from . import tracing
 from .group_lasso import group_gap_from_residual, group_soft_threshold
 from .lasso import gap_from_residual, soft_threshold, top_eigenpair
 
@@ -107,6 +108,7 @@ def _cd_gram_op(backend: ops.ScreenBackend) -> Callable:
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("backend", "max_iter", "cadence"))
+@jax.named_scope("solve")
 def _fista_solve(backend, X, y, lam, beta0, lipschitz, tol,
                  max_iter: int, cadence: int) -> SolveResult:
     """FISTA with the fused gradient+prox+momentum kernel per iteration.
@@ -152,6 +154,7 @@ def _fista_solve(backend, X, y, lam, beta0, lipschitz, tol,
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "max_iter", "cadence"))
+@jax.named_scope("solve")
 def _fista_solve_lo(backend, X, X_lo, y, lam, beta0, lipschitz, tol,
                     max_iter: int, cadence: int, err_max,
                     cn_max) -> SolveResult:
@@ -220,6 +223,7 @@ def _fista_solve_lo(backend, X, X_lo, y, lam, beta0, lipschitz, tol,
 
 
 @functools.partial(jax.jit, static_argnames=("max_epochs", "cadence"))
+@jax.named_scope("solve")
 def _cd_solve(X, y, lam, beta0, tol, max_epochs: int,
               cadence: int) -> SolveResult:
     """Cyclic coordinate descent on matvecs (residual maintained).
@@ -273,6 +277,7 @@ def _cd_solve(X, y, lam, beta0, tol, max_epochs: int,
 
 @functools.partial(jax.jit, static_argnames=("backend", "max_epochs",
                                              "cadence"))
+@jax.named_scope("solve")
 def _cd_gram_solve(backend, X, y, lam, beta0, tol, max_epochs: int,
                    cadence: int) -> SolveResult:
     """Coordinate descent over the cached Gram system (n ≪ p regime).
@@ -332,6 +337,7 @@ def _gap_from_residual_batched(r, dot, beta, lam, y):
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "max_iter", "cadence"))
+@jax.named_scope("solve")
 def _fista_solve_batched(backend, X, Y, lam, beta0, valid, lipschitz, tol,
                          max_iter: int, cadence: int) -> SolveResult:
     """Batched FISTA: B queries share every pass over X (forward fits and
@@ -387,6 +393,7 @@ def _fista_solve_batched(backend, X, Y, lam, beta0, valid, lipschitz, tol,
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "max_iter", "cadence"))
+@jax.named_scope("solve")
 def _fista_solve_lo_batched(backend, X, X_lo, Y, lam, beta0, valid,
                             lipschitz, tol, max_iter: int, cadence: int,
                             err_max, cn_max) -> SolveResult:
@@ -452,6 +459,7 @@ def _fista_solve_lo_batched(backend, X, X_lo, Y, lam, beta0, valid,
 
 
 @functools.partial(jax.jit, static_argnames=("max_epochs", "cadence"))
+@jax.named_scope("solve")
 def _cd_solve_batched(X, Y, lam, beta0, valid, tol, max_epochs: int,
                       cadence: int) -> SolveResult:
     """Batched cyclic CD on matvecs: each coordinate update touches x_j
@@ -508,6 +516,7 @@ def _cd_solve_batched(X, Y, lam, beta0, valid, tol, max_epochs: int,
 
 @functools.partial(jax.jit, static_argnames=("backend", "max_epochs",
                                              "cadence"))
+@jax.named_scope("solve")
 def _cd_gram_solve_batched(backend, X, Y, lam, beta0, valid, tol,
                            max_epochs: int, cadence: int) -> SolveResult:
     """Batched Gram CD: ONE shared G = XᵀX (the dictionary Gram of the
@@ -549,6 +558,7 @@ def _cd_gram_solve_batched(backend, X, Y, lam, beta0, valid, tol,
 
 @functools.partial(jax.jit, static_argnames=("backend", "max_epochs",
                                              "cadence"))
+@jax.named_scope("solve")
 def _cd_gram_solve_lo(backend, X, X_lo, y, lam, beta0, tol, max_epochs: int,
                       cadence: int, err_max, cn_max) -> SolveResult:
     """Gram CD with the G build streamed off the bf16 dictionary copy:
@@ -601,6 +611,7 @@ def _cd_gram_solve_lo(backend, X, X_lo, y, lam, beta0, tol, max_epochs: int,
 
 @functools.partial(jax.jit, static_argnames=("backend", "max_epochs",
                                              "cadence"))
+@jax.named_scope("solve")
 def _cd_gram_solve_lo_batched(backend, X, X_lo, Y, lam, beta0, valid, tol,
                               max_epochs: int, cadence: int, err_max,
                               cn_max) -> SolveResult:
@@ -652,6 +663,7 @@ def _cd_gram_solve_lo_batched(backend, X, X_lo, Y, lam, beta0, valid, tol,
 
 
 @functools.partial(jax.jit, static_argnames=("m", "max_iter", "cadence"))
+@jax.named_scope("solve")
 def _group_fista_solve(X, y, lam, m: int, beta0, lipschitz, tol,
                        max_iter: int, cadence: int) -> SolveResult:
     """Block-FISTA for the group Lasso (pure-jnp body on every backend —
@@ -724,12 +736,14 @@ def _fista_strategy(eng: "SolverEngine", Xr, lam, beta0, m: int):
         # Phase 1: certified bf16 iterations while the gap certificate is
         # provably slack (see _fista_solve_lo). β stays f32 throughout.
         X_lo, err_max, cn_max = lo
-        res_lo = _fista_solve_lo(eng.backend, Xr, X_lo, eng.y, lam,
-                                 beta0.astype(jnp.float32), L, eng.tol,
-                                 eng.max_iter, eng.gap_check_cadence,
-                                 err_max, cn_max)
-        lo_it, lo_ck = int(res_lo.iters), int(res_lo.gap_checks)
-        if bool(res_lo.converged):
+        with tracing.span("solve.iterate"):
+            res_lo = _fista_solve_lo(eng.backend, Xr, X_lo, eng.y, lam,
+                                     beta0.astype(jnp.float32), L, eng.tol,
+                                     eng.max_iter, eng.gap_check_cadence,
+                                     err_max, cn_max)
+        lo_it = int(tracing.fetch(res_lo.iters))
+        lo_ck = int(tracing.fetch(res_lo.gap_checks))
+        if bool(tracing.fetch(res_lo.converged)):
             # The lo-phase gap certificate streams f32 X, so convergence
             # declared there IS convergence at the original tol — no
             # polish pass needed.
@@ -739,8 +753,9 @@ def _fista_strategy(eng: "SolverEngine", Xr, lam, beta0, m: int):
                     {"gram": False, "lo_iters": lo_it, "lo_checks": lo_ck})
         beta0 = res_lo.beta.astype(Xr.dtype)
     # Phase 2 (or the whole solve in f32): polish at the original tol.
-    res = _fista_solve(eng.backend, Xr, eng.y, lam, beta0, L, eng.tol,
-                       eng.max_iter, eng.gap_check_cadence)
+    with tracing.span("solve.iterate"):
+        res = _fista_solve(eng.backend, Xr, eng.y, lam, beta0, L, eng.tol,
+                           eng.max_iter, eng.gap_check_cadence)
     if lo is not None:
         res = SolveResult(res.beta, res.gap, res.iters + lo_it,
                           res.converged, res.gap_checks + lo_ck)
@@ -753,17 +768,22 @@ def _cd_strategy(eng: "SolverEngine", Xr, lam, beta0, m: int):
     lo = eng._take_lo()
     if b <= min(n, ops.GRAM_BUCKET_MAX):
         if lo is None:
-            res = _cd_gram_solve(eng.backend, Xr, eng.y, lam, beta0,
-                                 eng.tol, max_epochs, eng.gap_check_cadence)
+            with tracing.span("solve.iterate"):
+                res = _cd_gram_solve(eng.backend, Xr, eng.y, lam, beta0,
+                                     eng.tol, max_epochs,
+                                     eng.gap_check_cadence)
             return res, {"gram": True}
         # Phase 1: build G̃ off the bf16 copy (half-width bucket pass) and
         # sweep under the f32 gap certificate (see _cd_gram_solve_lo).
         X_lo, err_max, cn_max = lo
-        res_lo = _cd_gram_solve_lo(eng.backend, Xr, X_lo, eng.y, lam,
-                                   beta0, eng.tol, max_epochs,
-                                   eng.gap_check_cadence, err_max, cn_max)
-        lo_it, lo_ck = int(res_lo.iters), int(res_lo.gap_checks)
-        if bool(res_lo.converged):
+        with tracing.span("solve.iterate"):
+            res_lo = _cd_gram_solve_lo(eng.backend, Xr, X_lo, eng.y, lam,
+                                       beta0, eng.tol, max_epochs,
+                                       eng.gap_check_cadence, err_max,
+                                       cn_max)
+        lo_it = int(tracing.fetch(res_lo.iters))
+        lo_ck = int(tracing.fetch(res_lo.gap_checks))
+        if bool(tracing.fetch(res_lo.converged)):
             # the certificate streamed f32 X — convergence in the
             # bf16-built Gram phase is convergence at the original tol
             return res_lo, {
@@ -771,9 +791,11 @@ def _cd_strategy(eng: "SolverEngine", Xr, lam, beta0, m: int):
                 "lo_passes": 1.0,
                 "x_passes": 1.0 + lo_it * (b / max(n, 1)) + 2.0 * lo_ck}
         # Phase 2: rebuild the exact G (one f32 pass) and polish.
-        res = _cd_gram_solve(eng.backend, Xr, eng.y, lam, res_lo.beta,
-                             eng.tol, max_epochs, eng.gap_check_cadence)
-        hi_it, hi_ck = int(res.iters), int(res.gap_checks)
+        with tracing.span("solve.iterate"):
+            res = _cd_gram_solve(eng.backend, Xr, eng.y, lam, res_lo.beta,
+                                 eng.tol, max_epochs, eng.gap_check_cadence)
+        hi_it = int(tracing.fetch(res.iters))
+        hi_ck = int(tracing.fetch(res.gap_checks))
         res = SolveResult(res.beta, res.gap, res.iters + lo_it,
                           res.converged, res.gap_checks + lo_ck)
         return res, {
@@ -786,14 +808,17 @@ def _cd_strategy(eng: "SolverEngine", Xr, lam, beta0, m: int):
         # certified bf16 stream — this solve streams f32. A bucket-size
         # crossover is not a config error, so telemetry only, no warning.
         eng.last_effective_dtype = "float32"
-    res = _cd_solve(Xr, eng.y, lam, beta0, eng.tol, max_epochs,
-                    eng.gap_check_cadence)
+    with tracing.span("solve.iterate"):
+        res = _cd_solve(Xr, eng.y, lam, beta0, eng.tol, max_epochs,
+                        eng.gap_check_cadence)
     return res, {"gram": False}
 
 
 def _group_fista_strategy(eng: "SolverEngine", Xr, lam, beta0, m: int):
-    res = _group_fista_solve(Xr, eng.y, lam, m, beta0, eng.lipschitz(Xr),
-                             eng.tol, eng.max_iter, eng.gap_check_cadence)
+    L = eng.lipschitz(Xr)
+    with tracing.span("solve.iterate"):
+        res = _group_fista_solve(Xr, eng.y, lam, m, beta0, L, eng.tol,
+                                 eng.max_iter, eng.gap_check_cadence)
     return res, {"gram": False}
 
 
@@ -805,14 +830,14 @@ def _fista_strategy_batched(eng: "SolverEngine", Xr, lam, beta0, valid,
     res_lo = None
     if lo is not None:
         X_lo, err_max, cn_max = lo
-        res_lo = _fista_solve_lo_batched(eng.backend, Xr, X_lo, eng.y, lam,
-                                         beta0.astype(jnp.float32), valid,
-                                         L, eng.tol, eng.max_iter,
-                                         eng.gap_check_cadence, err_max,
-                                         cn_max)
-        lo_it = int(jnp.max(res_lo.iters))
-        lo_ck = int(res_lo.gap_checks)
-        if bool(jnp.all(res_lo.converged)):
+        with tracing.span("solve.iterate"):
+            res_lo = _fista_solve_lo_batched(
+                eng.backend, Xr, X_lo, eng.y, lam, beta0.astype(jnp.float32),
+                valid, L, eng.tol, eng.max_iter, eng.gap_check_cadence,
+                err_max, cn_max)
+        lo_it = int(tracing.fetch(jnp.max(res_lo.iters)))
+        lo_ck = int(tracing.fetch(res_lo.gap_checks))
+        if bool(tracing.fetch(jnp.all(res_lo.converged))):
             # every query converged against the f32 gap certificate inside
             # the lo phase — the batch needs no polish pass
             return (SolveResult(res_lo.beta.astype(Xr.dtype), res_lo.gap,
@@ -821,9 +846,11 @@ def _fista_strategy_batched(eng: "SolverEngine", Xr, lam, beta0, valid,
                     {"gram": False, "lo_iters": lo_it, "lo_checks": lo_ck,
                      "hi_iters": 0})
         beta0 = res_lo.beta.astype(Xr.dtype)
-    res = _fista_solve_batched(eng.backend, Xr, eng.y, lam, beta0, valid, L,
-                               eng.tol, eng.max_iter, eng.gap_check_cadence)
-    hi_it = int(jnp.max(res.iters))
+    with tracing.span("solve.iterate"):
+        res = _fista_solve_batched(eng.backend, Xr, eng.y, lam, beta0, valid,
+                                   L, eng.tol, eng.max_iter,
+                                   eng.gap_check_cadence)
+    hi_it = int(tracing.fetch(jnp.max(res.iters)))
     if res_lo is not None:
         res = SolveResult(res.beta, res.gap, res.iters + res_lo.iters,
                           res.converged, res.gap_checks + lo_ck)
@@ -837,30 +864,34 @@ def _cd_strategy_batched(eng: "SolverEngine", Xr, lam, beta0, valid, m: int):
     lo = eng._take_lo()
     if b <= min(n, ops.GRAM_BUCKET_MAX):
         if lo is None:
-            res = _cd_gram_solve_batched(eng.backend, Xr, eng.y, lam, beta0,
-                                         valid, eng.tol, max_epochs,
-                                         eng.gap_check_cadence)
+            with tracing.span("solve.iterate"):
+                res = _cd_gram_solve_batched(eng.backend, Xr, eng.y, lam,
+                                             beta0, valid, eng.tol,
+                                             max_epochs,
+                                             eng.gap_check_cadence)
             return res, {"gram": True}
         X_lo, err_max, cn_max = lo
-        res_lo = _cd_gram_solve_lo_batched(eng.backend, Xr, X_lo, eng.y,
-                                           lam, beta0, valid, eng.tol,
-                                           max_epochs,
-                                           eng.gap_check_cadence,
-                                           err_max, cn_max)
-        lo_it = int(jnp.max(res_lo.iters))
-        lo_ck = int(res_lo.gap_checks)
-        if bool(jnp.all(res_lo.converged)):
+        with tracing.span("solve.iterate"):
+            res_lo = _cd_gram_solve_lo_batched(eng.backend, Xr, X_lo, eng.y,
+                                               lam, beta0, valid, eng.tol,
+                                               max_epochs,
+                                               eng.gap_check_cadence,
+                                               err_max, cn_max)
+        lo_it = int(tracing.fetch(jnp.max(res_lo.iters)))
+        lo_ck = int(tracing.fetch(res_lo.gap_checks))
+        if bool(tracing.fetch(jnp.all(res_lo.converged))):
             # every query converged against the f32 gap certificate on the
             # bf16-built Gram — no exact rebuild needed
             return res_lo, {
                 "gram": True, "lo_iters": lo_it, "lo_checks": lo_ck,
                 "lo_passes": 1.0,
                 "x_passes": 1.0 + lo_it * (b / max(n, 1)) + 2.0 * lo_ck}
-        res = _cd_gram_solve_batched(eng.backend, Xr, eng.y, lam,
-                                     res_lo.beta, valid, eng.tol,
-                                     max_epochs, eng.gap_check_cadence)
-        hi_it = int(jnp.max(res.iters))
-        hi_ck = int(res.gap_checks)
+        with tracing.span("solve.iterate"):
+            res = _cd_gram_solve_batched(eng.backend, Xr, eng.y, lam,
+                                         res_lo.beta, valid, eng.tol,
+                                         max_epochs, eng.gap_check_cadence)
+        hi_it = int(tracing.fetch(jnp.max(res.iters)))
+        hi_ck = int(tracing.fetch(res.gap_checks))
         res = SolveResult(res.beta, res.gap, res.iters + res_lo.iters,
                           res.converged, res.gap_checks + lo_ck)
         return res, {
@@ -872,8 +903,9 @@ def _cd_strategy_batched(eng: "SolverEngine", Xr, lam, beta0, valid, m: int):
         # matvec CD past the Gram crossover: no certified bf16 stream —
         # f32 solve, telemetry only (bucket size is data, not config).
         eng.last_effective_dtype = "float32"
-    res = _cd_solve_batched(Xr, eng.y, lam, beta0, valid, eng.tol,
-                            max_epochs, eng.gap_check_cadence)
+    with tracing.span("solve.iterate"):
+        res = _cd_solve_batched(Xr, eng.y, lam, beta0, valid, eng.tol,
+                                max_epochs, eng.gap_check_cadence)
     return res, {"gram": False}
 
 
@@ -992,16 +1024,17 @@ class SolverEngine:
         """
         bucket = Xr.shape[1]
         v_prev = self._eig_cache.get(bucket)
-        if v_prev is None:
-            self._eig_stats["cold"] = self._eig_stats.get("cold", 0) + 1
-            eig, v = top_eigenpair(Xr, iters=self.power_iters,
-                                   seed=self.seed)
-        else:
-            self._eig_stats["warm"] = self._eig_stats.get("warm", 0) + 1
-            eig, v = top_eigenpair(Xr, iters=self.warm_power_iters,
-                                   v0=v_prev)
-        self._eig_cache[bucket] = v
-        return 1.05 * eig
+        with tracing.span("solve.lipschitz"):
+            if v_prev is None:
+                self._eig_stats["cold"] = self._eig_stats.get("cold", 0) + 1
+                eig, v = top_eigenpair(Xr, iters=self.power_iters,
+                                       seed=self.seed)
+            else:
+                self._eig_stats["warm"] = self._eig_stats.get("warm", 0) + 1
+                eig, v = top_eigenpair(Xr, iters=self.warm_power_iters,
+                                       v0=v_prev)
+            self._eig_cache[bucket] = v
+            return 1.05 * eig
 
     # -- mixed-precision lo-phase staging -------------------------------
     # The strategy signature is fixed at (eng, Xr, lam, beta0, m), so the
@@ -1055,14 +1088,14 @@ class SolverEngine:
         self.n_solves += 1
         self.last_used_gram = bool(info.get("gram", False))
         self.gram_solves += int(self.last_used_gram)
-        self.last_gap_checks = int(res.gap_checks)
+        self.last_gap_checks = int(tracing.fetch(res.gap_checks))
         self.total_gap_checks += self.last_gap_checks
         # Data-movement telemetry in passes over the *reduced* buffer:
         # FISTA reads Xr twice per iteration (fit + fused gradient), CD
         # streams the columns once per epoch, Gram CD reads Xr once to
         # build G (sweeps then cost b/n of a pass each); every gap check
         # adds two passes (residual + correlations).
-        it, ck = int(res.iters), self.last_gap_checks
+        it, ck = int(tracing.fetch(res.iters)), self.last_gap_checks
         n, b = Xr.shape
         if "x_passes" in info:
             # mixed-precision Gram CD computes its own total (two G
@@ -1127,7 +1160,7 @@ class SolverEngine:
         strategy = BATCHED_SOLVERS.get(self.solver)
         if strategy is not None:
             res, info = strategy(self, Xr, lam, beta0, valid, m)
-            self.last_gap_checks = int(res.gap_checks)
+            self.last_gap_checks = int(tracing.fetch(res.gap_checks))
             # Shared-pass accounting: one buffer pass serves the whole
             # batch, and each phase's loop runs until ITS last query
             # converges — the bf16 phase contributes 2·max(lo_iters)
@@ -1141,7 +1174,8 @@ class SolverEngine:
                 self.last_x_passes = float(info["x_passes"])
             else:
                 lo_ck = int(info.get("lo_checks", 0))
-                hi_it = int(info.get("hi_iters", int(jnp.max(res.iters))))
+                hi_it = int(info.get(
+                    "hi_iters", int(tracing.fetch(jnp.max(res.iters)))))
                 hi_ck = self.last_gap_checks - lo_ck
                 self.last_x_passes = (
                     _passes(hi_it, hi_ck, bool(info.get("gram", False)))
@@ -1174,11 +1208,12 @@ class SolverEngine:
                     r, info_b = SOLVERS[self.solver](
                         self, Xq, lam[qb], beta0[qb] * valid[qb], m)
                     parts.append(r)
-                    checks += int(r.gap_checks)
+                    checks += int(tracing.fetch(r.gap_checks))
                     gram_b = bool(info_b.get("gram", False))
                     gram = gram or gram_b
                     # passes here are per-query, NOT shared: sum them
-                    passes += _passes(int(r.iters), int(r.gap_checks),
+                    passes += _passes(int(tracing.fetch(r.iters)),
+                                      int(tracing.fetch(r.gap_checks)),
                                       gram_b)
             finally:
                 self.y = y_full

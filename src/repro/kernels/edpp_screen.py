@@ -130,6 +130,7 @@ def _screen_kernel(o_ref, rho_ref, x_ref, dot_ref, ss_ref, scores_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bp", "interpret"))
+@jax.named_scope("screen")
 def edpp_screen_scores(
     X: jax.Array,
     centre: jax.Array,
@@ -182,6 +183,7 @@ def edpp_screen_scores(
             jax.ShapeDtypeStruct((bq, p + p_pad), jnp.float32),
         ],
         interpret=interpret,
+        name="edpp_screen_scores",
     )(op, rho_arr, Xp)
     scores = scores[:b, :p]
     return (scores[0] if squeeze else scores), ss[0, :p]
@@ -200,6 +202,7 @@ def _matvec_kernel(o_ref, x_ref, dot_ref, *, n_tiles: int):
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bp", "interpret"))
+@jax.named_scope("screen")
 def screen_matvec(
     X: jax.Array,
     centre: jax.Array,
@@ -233,6 +236,7 @@ def screen_matvec(
         out_specs=pl.BlockSpec((bq, bp), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((bq, p + p_pad), jnp.float32),
         interpret=interpret,
+        name="screen_matvec",
     )(op, Xp)
     dot = dot[:b, :p]
     return dot[0] if squeeze else dot

@@ -24,6 +24,7 @@ from .edpp_screen import screen_matvec
 
 
 @functools.partial(jax.jit, static_argnames=("m", "interpret"))
+@jax.named_scope("screen")
 def group_screen_scores(
     X: jax.Array,
     centre: jax.Array,

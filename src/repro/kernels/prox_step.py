@@ -83,6 +83,7 @@ def prox_step(
             jax.ShapeDtypeStruct((bq, p + p_pad), z.dtype),
         ],
         interpret=interpret,
+        name="prox_step",
     )(scalars, zp, gp, bp_old)
     beta_new = beta_new[:b, :p]
     z_new = z_new[:b, :p]

@@ -168,6 +168,7 @@ def fista_step(
             jax.ShapeDtypeStruct((bq, p + p_pad), z.dtype),
         ],
         interpret=interpret,
+        name="fista_step",
     )(scalars, rp, Xp, zp, bp_old)
     beta_new = beta_new[:b, :p]
     z_new = z_new[:b, :p]
@@ -265,6 +266,7 @@ def cd_gram_sweep(
         out_specs=pl.BlockSpec((bq, p + p_pad), lambda: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((bq, p + p_pad), beta.dtype),
         interpret=interpret,
+        name="cd_gram_sweep",
     )(scalars, Gp, cp, bp_, vp_)
     out = out[:b, :p]
     return out[0] if squeeze else out

@@ -317,24 +317,28 @@ class SessionExecutor:
     def dispatch(self, Y, n_live: int, batch_id: int, now: float):
         import numpy as np
         import jax.numpy as jnp
-        try:
-            res = self.session.path(
-                jnp.asarray(Y), num_lambdas=self.num_lambdas,
-                lo_frac=self.lo_frac, hi_frac=self.hi_frac)
-        except Exception as e:               # surfaces at retire → isolate
-            return ImmediateHandle(failure=e)
-        qc = res.query_converged
-        lanes = []
-        for b in range(n_live):
-            view = res.query(b)
-            if not np.isfinite(view.betas).all():
-                lanes.append(LaneResult(result=view, converged=False,
-                                        error="non-finite result"))
-                continue
-            lanes.append(LaneResult(
-                result=view,
-                converged=bool(qc[b]) if qc is not None else True))
-        return ImmediateHandle(lanes=lanes)
+        from ..core import tracing
+        with tracing.span("serve.dispatch", batch_id=batch_id):
+            try:
+                res = self.session.path(
+                    jnp.asarray(Y), num_lambdas=self.num_lambdas,
+                    lo_frac=self.lo_frac, hi_frac=self.hi_frac)
+            except Exception as e:           # surfaces at retire → isolate
+                return ImmediateHandle(failure=e)
+            qc = res.query_converged
+            lanes = []
+            with tracing.span("serve.lanes"):
+                for b in range(n_live):
+                    view = res.query(b)
+                    if not np.isfinite(view.betas).all():
+                        lanes.append(LaneResult(
+                            result=view, converged=False,
+                            error="non-finite result"))
+                        continue
+                    lanes.append(LaneResult(
+                        result=view,
+                        converged=bool(qc[b]) if qc is not None else True))
+            return ImmediateHandle(lanes=lanes)
 
 
 class DelayedExecutor:

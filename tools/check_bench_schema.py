@@ -95,7 +95,6 @@ DPP_FAMILY_ROW_KEYS = {
     "screen_dtype",
     "num_lambdas",
     "rejection_rate",
-    "bytes_per_screen",
     "speedup_vs_unscreened",
     "wall_time_s",
     "max_beta_err",
