@@ -111,7 +111,8 @@ class PathStepStats:
     host_syncs: int = 0           # device→host reads (tracing.fetch)
     host_sync_s: float = 0.0      # time spent in them
     gather_time_s: float = 0.0    # path.gather: bucket columns + warm start
-    copyout_time_s: float = 0.0   # path.copyout: float64 β and the mask
+    copyout_time_s: float = 0.0   # path.copyout: β's kept columns (host
+    #                               scatter into float64) and the mask
     state_time_s: float = 0.0     # path.state: the next step's dual state
     step_time_s: float = 0.0      # path.step: the whole step
     compiles: int = 0             # programs compiled or loaded from the
@@ -200,6 +201,34 @@ def _gather_cols(X: jax.Array, idx: jax.Array, valid: jax.Array, bucket: int):
     """Gather `bucket` columns (zero-filled where invalid)."""
     cols = jnp.take(X, idx, axis=1, mode="clip")
     return cols * valid[None, :]
+
+
+@functools.partial(jax.jit, static_argnames=("p",))
+@jax.named_scope("scatter")
+def _step_epilogue(beta_r, iters, gap, converged, Xr, idx, valid, p: int):
+    """What follows a reduced solve, as one program keyed on (B, bucket,
+    n, p, dtype) alone, never on the kept count.
+
+    ``beta_r`` is the solve's (b,) or (B, b) β on the bucket, ``iters`` /
+    ``gap`` / ``converged`` its scalar or (B,) telemetry, ``idx``/``valid``
+    the padded bucket indices of :func:`_pad_indices`. Returns
+    ``beta_full`` (B, p) (β scattered back to p), ``fitted`` (B, n) = Xr·β_r
+    and the packed summary (B, b + 3): β_r, then iters, gap and converged
+    per query, for the host to read in one transfer."""
+    b = Xr.shape[1]
+    beta_r = beta_r.reshape(-1, b)
+    B = beta_r.shape[0]
+    # padded slots carry index 0, a real column: send them out of range
+    cols = jnp.where(valid > 0, idx, p)
+    beta_full = (jnp.zeros((B, p), Xr.dtype)
+                 .at[:, cols].set(beta_r, mode="drop"))
+    fitted = (Xr @ beta_r[0])[None, :] if B == 1 else beta_r @ Xr.T
+    dt = jnp.promote_types(jnp.promote_types(beta_r.dtype, gap.dtype),
+                           jnp.float32)
+    per_query = jnp.stack([a.reshape(B).astype(dt)
+                           for a in (iters, gap, converged)], axis=1)
+    summary = jnp.concatenate([beta_r.astype(dt), per_query], axis=1)
+    return beta_full, fitted, summary
 
 
 def _pad_indices(kept: np.ndarray, bucket: int):
@@ -340,8 +369,6 @@ def _path_driver(X, Y, lambdas, cfg, *, m: int, screen_engine,
                 gather_time = 0.0
                 solve_dtype_eff = "float32"
                 bucket = 0
-                res_iters, res_gap, q_conv = 0, 0.0, B
-                conv_vec = np.ones((B,), dtype=bool)
                 while True:
                     # union of survivors across the batch: one shared buffer
                     kept = np.flatnonzero((~discard_np).any(axis=0))
@@ -349,6 +376,7 @@ def _path_driver(X, Y, lambdas, cfg, *, m: int, screen_engine,
                     if kept.size == 0:
                         beta_full = jnp.zeros((B, p), dtype=X.dtype)
                         fitted = jnp.zeros((B, X.shape[0]), dtype=X.dtype)
+                        beta_r = None        # β = 0: betas already holds it
                         res_iters, res_gap, q_conv = 0, 0.0, B
                         conv_vec = np.ones((B,), dtype=bool)
                     else:
@@ -380,39 +408,24 @@ def _path_driver(X, Y, lambdas, cfg, *, m: int, screen_engine,
                         if batch is None:
                             res = solver_engine.solve(Xr, float(lam_vec[0]),
                                                       beta0, m=m, lo=lo)
-                            with tracing.span("path.scatter"):
-                                beta_full = (
-                                    jnp.zeros((p,), dtype=X.dtype)
-                                    .at[col_idx]
-                                    .set(res.beta[: col_idx.size])
-                                )[None, :]
-                            res_iters = int(tracing.fetch(res.iters))
-                            res_gap = float(tracing.fetch(res.gap))
-                            q_conv = int(bool(tracing.fetch(res.converged)))
-                            conv_vec = np.array(
-                                [bool(tracing.fetch(res.converged))])
-                            # fitted values from the reduced bucket
-                            # (replicated, shard-invariant) — feeds KKT and
-                            # the next dual state
-                            with tracing.span("path.scatter"):
-                                fitted = (Xr @ res.beta)[None, :]
                         else:
                             res = solver_engine.solve_batched(
                                 Xr, jnp.asarray(lam_vec, X.dtype), beta0,
                                 valid=vq, m=m, lo=lo)
-                            with tracing.span("path.scatter"):
-                                beta_full = (
-                                    jnp.zeros((B, p), dtype=X.dtype)
-                                    .at[:, col_idx]
-                                    .set(res.beta[:, : col_idx.size])
-                                )
-                            res_iters = int(tracing.fetch(jnp.max(res.iters)))
-                            res_gap = float(tracing.fetch(jnp.max(res.gap)))
-                            q_conv = int(tracing.fetch(jnp.sum(res.converged)))
-                            conv_vec = tracing.fetch(
-                                res.converged).astype(bool)
-                            with tracing.span("path.scatter"):
-                                fitted = res.beta @ Xr.T               # (B, n)
+                        # β back to p and the fitted values Xr·β_r, from the
+                        # reduced bucket (replicated, shard-invariant: they
+                        # feed KKT and the next dual state), then ONE read
+                        # of the packed summary
+                        with tracing.span("path.scatter"):
+                            beta_full, fitted, summary = _step_epilogue(
+                                res.beta, res.iters, res.gap, res.converged,
+                                Xr, idx, valid, p=p)
+                        summary = tracing.fetch(summary)
+                        beta_r = summary[:, :-3]
+                        res_iters = int(summary[:, -3].max())
+                        res_gap = float(summary[:, -2].max())
+                        conv_vec = summary[:, -1] > 0
+                        q_conv = int(conv_vec.sum())
                         solves += 1
                         gram_solves += int(solver_engine.last_used_gram)
                         gap_checks += solver_engine.last_gap_checks
@@ -443,7 +456,10 @@ def _path_driver(X, Y, lambdas, cfg, *, m: int, screen_engine,
                 solve_time = time.perf_counter() - t0
 
             with tracing.span("path.copyout") as copyout:
-                betas[:, k] = tracing.fetch(beta_full, np.float64)
+                # a host scatter of the kept columns into the float64
+                # buffer (f32 → f64 is exact; the rest stays 0)
+                if beta_r is not None:
+                    betas[:, k, col_idx] = beta_r[:, : col_idx.size]
                 masks[:, k] = discard_np
             # a dead (trivial-region) query's lane is vacuously converged
             q_converged &= conv_vec | ~live

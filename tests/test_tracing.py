@@ -154,6 +154,28 @@ def test_a_new_bucket_compiles_and_its_repeat_does_not():
     assert [s.compiles for s in again.stats] == [0] * 3
 
 
+def test_a_live_batched_fista_step_reads_five_times():
+    # the mask; the solver's iteration count, gap checks and iteration
+    # count again; the step epilogue's one packed read
+    X, Y = _problem(seed=1)
+    sess = LassoSession.fit(X)
+    res = sess.path(Y, num_lambdas=K, hi_frac=0.9)
+    assert all(st.n_kept > 0 for st in res.stats)
+    assert [st.host_syncs for st in res.stats] == [5] * K
+
+
+def test_kept_counts_inside_one_bucket_compile_nothing():
+    # two grids on one session: new kept counts, the same bucket
+    X, Y = _problem(n=56, p=384, b=2, seed=7)
+    sess = LassoSession.fit(X)
+    first = sess.path(Y, num_lambdas=4, hi_frac=0.6, lo_frac=0.4)
+    second = sess.path(Y, num_lambdas=4, hi_frac=0.5, lo_frac=0.3)
+    seen = {s.n_kept for s in first.stats}
+    assert {s.n_kept for s in second.stats} - seen
+    assert {s.bucket for s in second.stats} <= {s.bucket for s in first.stats}
+    assert sum(s.compiles for s in second.stats) == 0
+
+
 def test_merge_sums_the_tracing_fields():
     fields = {"host_syncs": (3, 5), "host_sync_s": (0.25, 0.5),
               "gather_time_s": (0.125, 0.25), "copyout_time_s": (1.0, 2.0),
@@ -194,6 +216,9 @@ def _lowered():
                                     ops.BACKENDS["jnp"], Xr,
                                     jnp.ones((B, N)), lam, beta, beta,
                                     1.0, 1e-6, 100, 10)),
+        "step_epilogue": ("scatter", lambda: path_mod._step_epilogue.lower(
+            beta, jnp.ones((B,), jnp.int32), lam, jnp.ones((B,), bool), Xr,
+            idx, jnp.ones((32,)), p=P)),
         "power_iterate": ("solve", lambda: lasso._power_iterate.lower(
             Xr, jnp.ones((32,)), 4)),
         "make_state_batched_fit": ("state", lambda:
