@@ -3,11 +3,11 @@ process (the compiled programs are shared between seeds):
 
 * the program: whole runs of a cell (a short window at the cell's own
   load) on many seeds, each printing the check's worst readings;
-* the control: for a few seeds, the plain reference put in the program's
-  place at the precision below the configuration's (``"high"``), on
-  queries drawn as the cell's runs draw them, read by the same check.
-  Every answer it gives is read, whether its own solver claimed the gap
-  or stopped at ``max_iter``.
+* the control: for a few seeds, the configuration's plain reference
+  (its ``reference`` module) put in the program's place at the precision
+  below the configuration's (``"high"``), on queries drawn as the cell's
+  runs draw them, read by the same check. Every answer it gives is read,
+  whether its own solver claimed the gap or stopped at ``max_iter``.
 
     python bench/calibrate.py --workload mnist.upper.sat --seconds 8 \\
         --seeds 101 102 ... --control-seeds 201 202 203 > readings.jsonl
@@ -32,7 +32,8 @@ sys.path.insert(1, str(ROOT / "src"))
 
 def control(cell, seed: int, precision: str) -> dict:
     import numpy as np
-    from bench import harness, reference
+    from bench import harness
+    reference = cell.reference()
     n_q = cell.checks["sample"]
     X, Y = harness.make_data(cell, seed, n_q)
     b = cell.mix["policy"]["b_max"]
@@ -46,7 +47,7 @@ def control(cell, seed: int, precision: str) -> dict:
         answers += [(lams[j], betas[j], masks[j]) for j in range(len(conv))]
         claimed += int(np.sum(conv))
     worst = reference.certify(np.asarray(X, np.float64), Y, answers,
-                              cell.mix["grid"])
+                              cell.mix["grid"], session)
     return {"kind": f"control:{precision}", "seed": seed,
             "claimed_converged": claimed, "of": n_q, "check": worst,
             "seconds": time.perf_counter() - t}

@@ -3,7 +3,10 @@ window through the program's serve loop, the metrics, and the check.
 
 A cell is found by name alone. ``BENCHMARK.json`` names its configuration
 and its traffic mix; the configuration's file names its generator
-(``bench/generators/<name>.py``); the mix is ``bench/mixes/<traffic>.json``;
+(``bench/generators/<name>.py``), its plain reference (``reference``, a
+path in the checkout) and what ``LassoSession.fit`` takes (``session``:
+solver settings, and where given a ``mesh`` and ``groups``); the mix is
+``bench/mixes/<traffic>.json``;
 the check's limits are ``bench/checks/<cell>.json``; each per-layer metric
 is read by ``bench/layer_metrics/<metric>.py``. Adding any of them takes new
 files and new entries, and no edit here.
@@ -25,7 +28,7 @@ from pathlib import Path
 
 import numpy as np
 
-from bench import reference, stats, traffic
+from bench import stats, traffic
 from bench import trace as trace_mod
 
 BENCH = Path(__file__).resolve().parent
@@ -79,6 +82,11 @@ class Cell:
 
     def layer_metric(self, name: str):
         return load_module(self.bench / "layer_metrics" / f"{name}.py")
+
+    def reference(self):
+        """The configuration's own plain reference module: ``certify``
+        judges served answers, ``reference_path`` is the control."""
+        return load_module(self.root / self.config["reference"])
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -247,6 +255,25 @@ def devices(chips: int, require_tpu: bool):
     return devs[:chips]
 
 
+def fit_options(session: dict, devs) -> dict:
+    """What the configuration's ``session`` adds to ``LassoSession.fit``:
+    ``mesh`` ({"axes": [...], "shape": [...]}) as a mesh over the cell's
+    own devices, ``groups`` as an int. An absent key is not passed. A mesh
+    that does not hold exactly the cell's chips is an error."""
+    options = {}
+    if "mesh" in session:
+        from jax.sharding import Mesh
+        shape = tuple(int(k) for k in session["mesh"]["shape"])
+        if math.prod(shape) != len(devs):
+            raise ValueError(f"mesh {shape} holds {math.prod(shape)} "
+                             f"devices; the cell has {len(devs)} chips")
+        options["mesh"] = Mesh(np.array(devs).reshape(shape),
+                               tuple(session["mesh"]["axes"]))
+    if "groups" in session:
+        options["groups"] = int(session["groups"])
+    return options
+
+
 def session_config(session: dict):
     from repro.core import PathConfig, ScreenSpec, SolveSpec
     return PathConfig(
@@ -292,20 +319,18 @@ def _policy(mix: dict):
 def warm_up(executor, mix: dict, script, log) -> None:
     """Serve the window's first ``warmup.batches`` batches once, untimed.
 
-    The program compiles small programs for each distinct number of
-    features a lambda step keeps (it scatters the reduced solution back by
-    the kept indices with eager jax.numpy, ``core/path.py``), so the shapes
-    a window meets follow its own queries: warm-up queries from another
-    stream leave compiles inside the window, and compiling every count up
-    to the largest a window meets outlasts a first run on the chip
-    (PERF.md). A backlog is served in order in full batches, so replaying
-    its first batches, more than the window can serve, warms up the
-    shapes the window uses and no other. Not quite all of them: the
-    Lipschitz eigenvector each solve leaves in the session's per-bucket
-    cache starts the next, so a kept count at the margin can differ
-    between replay and window; each run logs what its window compiled.
-    Beyond that cache the program keeps nothing of a query between calls,
-    so the window's work is that of unseen queries."""
+    The program compiles a set of programs for each power-of-two bucket of
+    kept features that a lambda step meets: the bucket's column gather, its
+    jitted step epilogue, the Lipschitz power iterations and the solver
+    loops (``core/path.py``, ``core/solver.py``). Which buckets a window
+    meets follows its own queries. A backlog is served in order in full
+    batches, so replaying its first batches, more than the window can
+    serve, compiles every program the window uses and no other. The replay
+    also fills the session's per-bucket cache of Lipschitz eigenvectors,
+    which starts each next solve of that bucket, as it would be after any
+    served traffic; each run logs what its window compiled. Beyond that
+    cache the program keeps nothing of a query between calls, so the
+    window's work is that of unseen queries."""
     b = mix["policy"]["b_max"]
     count = min(mix["warmup"]["batches"], len(script) // b)
     for i in range(count):
@@ -326,6 +351,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     set_compile_cache(cell.root)
     devs = devices(cell.workload["chips"], require_tpu)
+    placement = fit_options(cell.config["session"], devs)
     peaks = _peaks(devs[0].device_kind, require_tpu)
     mix = cell.mix
     window = min(seconds, TRACE_SECONDS) if trace else float(seconds)
@@ -336,7 +362,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     log(f"data: X {X.shape}, {len(Y)} window queries")
 
     session = LassoSession.fit(X, config=session_config(
-        cell.config["session"]))
+        cell.config["session"]), **placement)
     executor = _executor(cell, session)
     warm_up(executor, mix, script, log)
     log(f"fit and warm-up done at {time.perf_counter() - t_process:.3f} s")
@@ -484,11 +510,12 @@ def layer_metrics(cell: Cell, record: dict) -> dict:
 # ---------------------------------------------------------------------------
 
 def check(cell: Cell, X64, Y, sample) -> dict:
-    """Certify every sampled answer against the plain reference; the worst
-    reading of each number beside its limit."""
-    worst = reference.certify(
+    """Certify every sampled answer against the configuration's plain
+    reference; the worst reading of each number beside its limit."""
+    worst = cell.reference().certify(
         X64, Y[[qid for qid, _ in sample]],
-        [(r.lambdas, r.betas, r.masks) for _, r in sample], cell.mix["grid"])
+        [(r.lambdas, r.betas, r.masks) for _, r in sample], cell.mix["grid"],
+        cell.config["session"])
     limits = cell.checks["limits"]
     out = {k: {"value": worst[k], "limit": limits[k]} for k in worst}
     out["compared"] = {"value": len(sample), "at_least": 1}

@@ -7,12 +7,15 @@ def window_dispatches(record: dict) -> list:
     return [d for d in record["dispatches"] if t0 <= d["t"] <= t_end]
 
 
+def live_steps_of(dispatches: list) -> list:
+    """Lambda steps of these dispatches that screened (a step where every
+    query of the batch sits at or above its own lambda_max does not)."""
+    return [s for d in dispatches for s in d["steps"] if s["x_passes"] > 0]
+
+
 def live_steps(record: dict) -> list:
-    """Lambda steps of the window's dispatches that screened (a step where
-    every query of the batch sits at or above its own lambda_max does
-    not)."""
-    return [s for d in window_dispatches(record) for s in d["steps"]
-            if s["x_passes"] > 0]
+    """Live lambda steps of the window's dispatches."""
+    return live_steps_of(window_dispatches(record))
 
 
 def mean(values):

@@ -12,7 +12,9 @@ discard mask and a coefficient vector beta. It is right when
   scaled to be feasible for every feature, discarded ones included, so a
   discard of a feature the optimum needs shows as a gap.
 
-:func:`certify` computes both numbers in float64 on the host. It sees only
+:func:`certify` computes both numbers in float64 on the host. The harness
+finds this module by the configuration's ``reference`` key and calls its
+``certify`` (and the control its ``reference_path``) by name. It sees only
 the data (made by the benchmark from the seed) and the served answers.
 
 :func:`reference_path` is a plain Lasso path (sequential EDPP of the paper's
@@ -27,10 +29,11 @@ import functools
 import numpy as np
 
 
-def certify(X64, ys, answers, grid) -> dict:
+def certify(X64, ys, answers, grid, session) -> dict:
     """Worst relative grid error and worst relative full-problem duality gap
     over served paths, in float64. ``ys`` (Q, n); ``answers`` Q tuples
-    (lambdas (K,), betas (K, p), masks (K, p))."""
+    (lambdas (K,), betas (K, p), masks (K, p)). ``session`` is the
+    configuration's ``session``; the plain Lasso needs none of it."""
     ys = np.asarray(ys, np.float64)
     lam_max = np.max(np.abs(ys @ X64), axis=1)                  # (Q,)
     fracs = np.linspace(grid["hi_frac"], grid["lo_frac"],

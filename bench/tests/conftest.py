@@ -24,6 +24,7 @@ TINY_CONFIG = {
     "session": {"rule": "edpp", "strategy": "fista", "tol": 1e-6,
                 "max_iter": 5000, "dtype": "float32",
                 "matmul_precision": "highest"},
+    "reference": "bench/reference.py",
     "reduced": [], "assumed": {},
 }
 TINY_MIX = {
