@@ -44,6 +44,7 @@ on every rule and backend.
 
 from __future__ import annotations
 
+import functools
 import warnings
 
 import jax
@@ -472,10 +473,55 @@ def _make_group_state_fit(y, fitted, beta, lam, lmax, theta_max, v1max):
 
 
 @jax.jit
+@jax.named_scope("state")
+def _make_group_state_batched_fit(y, fitted, lam, lmax, theta_max, v1max):
+    """Batched `_make_group_state_fit`: y/fitted (B, n), lam/lmax (B,).
+    Each query selects its own λ̄_max branch."""
+    theta_seq = (y - fitted) / lam[:, None]
+    at_max = lam >= lmax * (1.0 - 1e-12)                 # (B,)
+    at_col = at_max[:, None]
+    return gscr.GroupDualState(
+        theta=jnp.where(at_col, theta_max, theta_seq),
+        lam=jnp.where(at_max, lmax, lam).astype(y.dtype),
+        v1=jnp.where(at_col, v1max, y / lam[:, None] - theta_seq),
+    )
+
+
+@jax.jit
 @jax.named_scope("screen")
 def _group_edpp_geometry(y, lam_next, state):
     vp = gscr.group_v2_perp(y, lam_next, state)
-    return state.theta + 0.5 * vp, 0.5 * jnp.linalg.norm(vp)
+    return state.theta + 0.5 * vp, 0.5 * jnp.linalg.norm(vp, axis=-1)
+
+
+@jax.jit
+@jax.named_scope("screen")
+def _group_edpp_combine(gscores, rho, spec_norms, sqm, eps):
+    """Corollary 21's test on the kernel's scores, (G,) or (B, G)."""
+    return gscores < sqm - scr._col(rho) * spec_norms - eps
+
+
+@jax.jit
+@jax.named_scope("screen")
+def _group_strong_combine(gscores, lam_next, lam_prev, sqm, eps):
+    return gscores < scr._col(sqm * (2.0 * lam_next - lam_prev) - eps)
+
+
+@functools.partial(jax.jit, static_argnames="m")
+@jax.named_scope("screen")
+def _group_fit_batched(X, gscores, y, m: int):
+    """The batch's λ̄_max (B,) and λ̄_max ray v̄₁ = X*X*ᵀy (B, n) (eq. 59)
+    from the (B, G) scores ‖X_gᵀy‖: each query's argmax group stays on the
+    device, and only λ̄_max is read to the host."""
+    gnorms = gscores / jnp.sqrt(float(m))
+    gstar = jnp.argmax(gnorms, axis=-1)                            # (B,)
+    lmax = jnp.take_along_axis(gnorms, gstar[:, None], axis=-1)[:, 0]
+
+    def ray(g, yq):
+        xs = jax.lax.dynamic_slice_in_dim(X, g * m, m, axis=1)     # (n, m)
+        return xs @ (xs.T @ yq)
+
+    return lmax, jax.vmap(ray)(gstar, y)
 
 
 _group_spec_norms = jax.jit(gscr.group_spectral_norms, static_argnames="m")
@@ -1352,6 +1398,13 @@ class GroupScreeningEngine:
     :class:`GroupDictionaryGeometry`) to reuse a prefitted dictionary across
     queries — the spectral norms are then served from cache and only the
     per-query ``‖X_gᵀy‖`` pass runs here.
+
+    Batched (one fitted dictionary, B queries): construct with ``y`` of
+    shape (B, n). The fit is one pass over X for the batch and one read of
+    its λ̄_max (B,); ``screen`` takes per-query λ (B,) and a batched
+    :class:`~repro.core.group_screening.GroupDualState` and returns a
+    (B, G) mask from ONE pass over X (``last_x_passes`` counts passes per
+    batch), as :class:`ScreeningEngine` does for the Lasso.
     """
 
     def __init__(self, X, y, m: int, backend: str | None = None,
@@ -1367,12 +1420,17 @@ class GroupScreeningEngine:
         self.m = m
         self.eps = eps
         gscores = self.backend.group_scores(self.X, self.y, m)   # ‖X_gᵀy‖
-        gnorms = gscores / jnp.sqrt(float(m))
-        self.gstar = int(tracing.fetch(jnp.argmax(gnorms)))
-        self.lam_max = float(tracing.fetch(gnorms[self.gstar]))
-        Xstar = jax.lax.dynamic_slice_in_dim(
-            self.X, self.gstar * m, m, axis=1)                   # (N, m)
-        self.v1_at_lmax = Xstar @ (Xstar.T @ self.y)             # eq. (59)
+        if self.y.ndim == 2:
+            lmax, self.v1_at_lmax = _group_fit_batched(self.X, gscores,
+                                                       self.y, m)
+            self.lam_max = tracing.fetch(lmax, np.float64)       # (B,)
+        else:
+            gnorms = gscores / jnp.sqrt(float(m))
+            gstar = int(tracing.fetch(jnp.argmax(gnorms)))
+            self.lam_max = float(tracing.fetch(gnorms[gstar]))
+            Xstar = jax.lax.dynamic_slice_in_dim(
+                self.X, gstar * m, m, axis=1)                    # (N, m)
+            self.v1_at_lmax = Xstar @ (Xstar.T @ self.y)         # eq. (59)
         self.spec_norms = geometry.spec_norms
         self.n_screens = 0
         self.total_x_passes = 0
@@ -1381,19 +1439,30 @@ class GroupScreeningEngine:
         self.last_screen_bytes = 0.0
 
     @property
-    def batch(self) -> None:
-        return None               # group screens are single-query (for now)
+    def batch(self) -> int | None:
+        return None if self.y.ndim == 1 else self.y.shape[0]
 
     @property
     def backend_name(self) -> str:
         return self.backend.name
 
+    def _lam_max_array(self) -> jax.Array:
+        return jnp.asarray(self.lam_max, self.X.dtype)
+
     def state_at_lambda_max(self) -> gscr.GroupDualState:
-        lmax = jnp.asarray(self.lam_max, self.X.dtype)
-        return gscr.GroupDualState(theta=self.y / lmax, lam=lmax,
+        lmax = self._lam_max_array()
+        return gscr.GroupDualState(theta=self.y / scr._col(lmax), lam=lmax,
                                    v1=self.v1_at_lmax)
 
     def make_state(self, beta, lam, *, fitted=None) -> gscr.GroupDualState:
+        """Sequential dual state from the solution at λ (KKT eq. 52).
+        Batched: beta (B, p), lam (B,) and ``fitted`` (B, n), which the
+        batched path always supplies."""
+        if self.batch is not None:
+            lmax = self._lam_max_array()
+            return _make_group_state_batched_fit(
+                self.y, fitted, jnp.asarray(lam, self.X.dtype), lmax,
+                self.y / scr._col(lmax), self.v1_at_lmax)
         if fitted is not None:
             return _make_group_state_fit(
                 self.y, fitted, beta, lam, self.lam_max,
@@ -1413,19 +1482,25 @@ class GroupScreeningEngine:
 
     def screen(self, lam_next, state: gscr.GroupDualState,
                rule: str = "edpp") -> jax.Array:
-        """Discard mask bool[G] for λ_next."""
+        """Discard mask for λ_next: bool[G] for a scalar λ, bool[B, G] for
+        per-query λ (B,) — one pass over X either way."""
         G = self.X.shape[1] // self.m
-        sqm = jnp.sqrt(float(self.m))
+        sqm = float(np.sqrt(self.m))
         if rule == "none":
             self._count(0)
-            return jnp.zeros((G,), dtype=bool)
+            shape = (G,) if self.batch is None else (self.batch, G)
+            return jnp.zeros(shape, dtype=bool)
+        if self.batch is not None:
+            lam_next = jnp.asarray(lam_next, self.X.dtype)
         if rule == "strong":
             gscores = self.backend.group_scores(
-                self.X, state.theta * state.lam, self.m)
-            mask = gscores < sqm * (2.0 * lam_next - state.lam) - self.eps
+                self.X, state.theta * scr._col(state.lam), self.m)
+            mask = _group_strong_combine(gscores, lam_next, state.lam, sqm,
+                                         self.eps)
         else:
             centre, rho = _group_edpp_geometry(self.y, lam_next, state)
             gscores = self.backend.group_scores(self.X, centre, self.m)
-            mask = gscores < sqm - rho * self.spec_norms - self.eps
+            mask = _group_edpp_combine(gscores, rho, self.spec_norms, sqm,
+                                       self.eps)
         self._count(1)
         return mask

@@ -6,7 +6,10 @@ estimate θ*(λ) in a ball (Theorem 19, via the ray Lemma 18 + firm
 nonexpansiveness), take the sup of ‖X_gᵀθ‖ over the ball (Theorem 20), test
 against √n_g.
 
-Equal contiguous groups of size ``m`` (the paper's §4.2 layout).
+Equal contiguous groups of size ``m`` (the paper's §4.2 layout). The
+per-step pieces the engine and the path driver use (``group_v2_perp``,
+``group_kkt_violations``) also take a (B, n) batch of queries with
+per-query λ (B,), row by row the single-query arithmetic.
 """
 
 from __future__ import annotations
@@ -76,6 +79,10 @@ def make_group_dual_state(X, y, beta, lam, lam_max_val, m: int) -> GroupDualStat
 
 def group_v2_perp(y, lam_next, state: GroupDualState) -> jax.Array:
     v1 = state.v1
+    if y.ndim == 2:                                       # (B, n) batch
+        v2 = y / jnp.asarray(lam_next)[:, None] - state.theta
+        denom = jnp.sum(jnp.square(v1), axis=-1) + 1e-30
+        return v2 - (jnp.sum(v1 * v2, axis=-1) / denom)[:, None] * v1
     v2 = y / lam_next - state.theta                       # eq. (68)
     denom = jnp.sum(jnp.square(v1)) + 1e-30
     return v2 - (jnp.dot(v1, v2) / denom) * v1            # eq. (69)
@@ -112,9 +119,16 @@ def group_strong_mask(X, y, lam_next, state: GroupDualState, m: int,
 def group_kkt_violations(X, y, beta, lam, discarded_groups, m: int,
                          tol: float = 1e-4, fitted=None):
     """Discarded groups violating ‖X_gᵀr‖ ≤ λ√n_g (KKT eq. 53).
-    ``fitted`` (= Xβ) skips the full X·β pass — see kkt_violations."""
-    r = y - (X @ beta if fitted is None else fitted)
-    scores = jnp.linalg.norm((X.T @ r).reshape(-1, m), axis=1)
+    ``fitted`` (= Xβ) skips the full X·β pass — see kkt_violations.
+    Batched: y/fitted (B, n), beta (B, p), lam (B,), discarded (B, G)."""
+    if y.ndim == 2:
+        r = y - (beta @ X.T if fitted is None else fitted)
+        dot = (r @ X).reshape(r.shape[0], -1, m)
+        lam = jnp.asarray(lam)[:, None]
+    else:
+        r = y - (X @ beta if fitted is None else fitted)
+        dot = (X.T @ r).reshape(-1, m)
+    scores = jnp.linalg.norm(dot, axis=-1)
     viol = scores > lam * jnp.sqrt(float(m)) * (1.0 + tol)
     return jnp.logical_and(viol, discarded_groups)
 

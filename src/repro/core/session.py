@@ -80,7 +80,6 @@ from .engine import (
 )
 from .path import (
     PathResult,
-    PathStepStats,
     _group_kkt_violations,
     _kkt_violations,
     _path_driver,
@@ -92,15 +91,21 @@ from .solver import SOLVERS, SolverEngine, resolve_solver_backend
 # tests). The group engine supports the {edpp, strong, none} subset.
 KNOWN_RULES = tuple(scr.RULES) + ("safe", "dome", "none")
 GROUP_RULES = ("edpp", "strong", "none")
+LASSO_ONLY_SOLVERS = ("cd",)
 
 
 def _check_group_rule(cfg: "PathConfig") -> None:
     """The group engine implements only the GROUP_RULES subset; anything
-    else would silently run group-EDPP under the wrong name."""
+    else would silently run group-EDPP under the wrong name. A solver of
+    the plain Lasso would solve another problem than the one screened."""
     if cfg.screen.rule not in GROUP_RULES:
         raise ValueError(
             f"group sessions support rules {GROUP_RULES}, got "
             f"{cfg.screen.rule!r}")
+    if cfg.solve.strategy in LASSO_ONLY_SOLVERS:
+        raise ValueError(
+            f"group sessions solve the group Lasso; the {cfg.solve.strategy!r}"
+            f" strategy solves the plain Lasso")
     if cfg.screen.screen_dtype != "float32":
         # the group kernel's ‖X_gᵀc‖ score has no margin bound yet, so a
         # silent bf16 run could mis-discard — fail loudly instead
@@ -167,7 +172,9 @@ class SolveSpec:
     construction against the live ``SOLVERS`` registry.
 
     ``strategy=None`` resolves per problem: ``fista`` for the Lasso,
-    ``group_fista`` when the session is fitted with ``groups=m``.
+    ``group_fista`` when the session is fitted with ``groups=m`` (where
+    ``fista`` also means ``group_fista``, and ``cd``, a plain-Lasso
+    solver, is refused).
     ``bucket_min=None`` resolves to 32 features / 16 groups.
 
     ``solve_dtype="bfloat16"`` streams the FISTA iteration matvecs through
@@ -205,7 +212,11 @@ class SolveSpec:
                 f"{self.solve_dtype!r}")
 
     def resolved_strategy(self, m: int = 1) -> str:
-        return self.strategy or ("group_fista" if m > 1 else "fista")
+        if m > 1 and self.strategy in (None, "fista"):
+            # block FISTA is FISTA's group form: `fista` soft-thresholds
+            # each feature and would solve the plain Lasso
+            return "group_fista"
+        return self.strategy or "fista"
 
 
 # Legacy flat keyword → (spec field) routing. The old PathConfig and
@@ -834,15 +845,7 @@ class LassoSession:
             eng = ScreeningEngine(self.X, Y, eps=cfg.screen.eps,
                                   geometry=self._geometry(cfg.screen.backend),
                                   screen_dtype=cfg.screen.screen_dtype)
-            if lambdas is None:
-                lambdas = np.stack([
-                    lambda_grid(float(lm), **grid_kw)
-                    for lm in np.atleast_1d(eng.lam_max)])
-            else:
-                lambdas = np.asarray(lambdas, dtype=np.float64)
-                if lambdas.ndim == 1:
-                    lambdas = np.broadcast_to(
-                        lambdas, (B, lambdas.shape[0])).copy()
+            lambdas = _batch_grids(lambdas, eng.lam_max, B, grid_kw)
             solver = self._solver_engine(Y, cfg)
         X = self.X
 
@@ -877,39 +880,45 @@ class LassoSession:
             kkt_fn=kkt_fn, reshard=self._reshard())
 
     def _group_path_batched(self, Y, lambdas, cfg, grid_kw) -> PathResult:
-        """B group paths against one fitted dictionary.
-
-        There is no fused batched group kernel (yet), so this loops the
-        single-query group driver — but the expensive fit (spectral norms)
-        is shared through the session geometry, and the result comes back
-        in the same unified batched layout as the Lasso drivers, with
-        per-step stats merged across the batch (additive telemetry summed,
-        ``batch_size=B``).
-        """
+        """B group paths against one fitted dictionary, batched end to
+        end as the Lasso's are: per λ step one group screen over X for the
+        whole batch, the kept groups union-bucketed, one batched
+        block-FISTA solve with per-query validity and convergence
+        freezing, per-query KKT rounds and trivial regions."""
         B = Y.shape[0]
         if B == 1:   # degenerate batch: same fast path as the Lasso driver
             return self._group_path(Y[0], _squeeze_grid(lambdas), cfg,
                                     grid_kw)
-        if lambdas is not None:
-            lam_arr = np.asarray(lambdas, dtype=np.float64)
-            if lam_arr.ndim == 1:
-                lam_arr = np.broadcast_to(
-                    lam_arr, (B, lam_arr.shape[0])).copy()
-            per_query = [lam_arr[b] for b in range(B)]
-        else:
-            per_query = [None] * B
-        results = [self._group_path(Y[b], per_query[b], cfg, grid_kw)
-                   for b in range(B)]
-        K = results[0].betas.shape[1]
-        stats = [_merge_step_stats([r.stats[k] for r in results])
-                 for k in range(K)]
-        return PathResult(
-            lambdas=np.stack([r.lambdas[0] for r in results]),
-            betas=np.stack([r.betas[0] for r in results]),
-            stats=stats,
-            masks=np.stack([r.masks[0] for r in results]),
-            query_converged=np.concatenate(
-                [r.query_converged for r in results]))
+        m = self.groups
+        with tracing.span("path.prologue"):
+            eng = GroupScreeningEngine(
+                self.X, Y, m, eps=cfg.screen.eps,
+                geometry=self._geometry(cfg.screen.backend))
+            lambdas = _batch_grids(lambdas, eng.lam_max, B, grid_kw)
+            solver = self._solver_engine(Y, cfg)
+        X = self.X
+
+        def kkt_fn(beta_full, lam, discard, fitted=None):
+            return _group_kkt_violations(X, Y, beta_full, lam, discard, m,
+                                         cfg.screen.kkt_tol, fitted)
+
+        return _path_driver(
+            X, Y, lambdas, cfg, m=m, screen_engine=eng,
+            solver_engine=solver, need_kkt=self._need_kkt(cfg),
+            kkt_fn=kkt_fn, batch=B, reshard=self._reshard())
+
+
+def _batch_grids(lambdas, lam_max, B: int, grid_kw) -> np.ndarray:
+    """The (B, K) grids of a batched path: the paper's grid over each
+    query's own λ_max when ``lambdas`` is None, a shared (K,) grid
+    broadcast, or per-query (B, K) grids as given."""
+    if lambdas is None:
+        return np.stack([lambda_grid(float(lm), **grid_kw)
+                         for lm in np.atleast_1d(lam_max)])
+    lambdas = np.asarray(lambdas, dtype=np.float64)
+    if lambdas.ndim == 1:
+        lambdas = np.broadcast_to(lambdas, (B, lambdas.shape[0])).copy()
+    return lambdas
 
 
 def _squeeze_grid(lambdas):
@@ -919,42 +928,3 @@ def _squeeze_grid(lambdas):
         return None
     lam = np.asarray(lambdas, dtype=np.float64)
     return lam[0] if lam.ndim == 2 else lam
-
-
-def _merge_step_stats(steps: list[PathStepStats]) -> PathStepStats:
-    """Merge one grid step's per-query stats into a batch-shaped entry:
-    additive telemetry (times, passes, checks) sums, worst-case fields
-    (iters, gap, kkt rounds, bucket) max, ``batch_size`` = B."""
-    B = len(steps)
-    x_passes = sum(s.x_passes for s in steps)
-    return PathStepStats(
-        lam=max(s.lam for s in steps),
-        n_discarded=min(s.n_discarded for s in steps),
-        n_kept=max(s.n_kept for s in steps),
-        solver_iters=max(s.solver_iters for s in steps),
-        gap=max(s.gap for s in steps),
-        kkt_rounds=max(s.kkt_rounds for s in steps),
-        screen_time_s=sum(s.screen_time_s for s in steps),
-        solve_time_s=sum(s.solve_time_s for s in steps),
-        host_syncs=sum(s.host_syncs for s in steps),
-        host_sync_s=sum(s.host_sync_s for s in steps),
-        gather_time_s=sum(s.gather_time_s for s in steps),
-        copyout_time_s=sum(s.copyout_time_s for s in steps),
-        state_time_s=sum(s.state_time_s for s in steps),
-        step_time_s=sum(s.step_time_s for s in steps),
-        compiles=sum(s.compiles for s in steps),
-        x_passes=x_passes,
-        gap_checks=sum(s.gap_checks for s in steps),
-        gram_step_frac=float(np.mean([s.gram_step_frac for s in steps])),
-        solver_backend=steps[0].solver_backend,
-        screen_backend=steps[0].screen_backend,
-        bucket=max(s.bucket for s in steps),
-        solver_x_passes=sum(s.solver_x_passes for s in steps),
-        batch_size=B,
-        queries_converged=sum(s.queries_converged for s in steps),
-        x_passes_per_query=x_passes / B,
-        screen_dtype_effective=steps[0].screen_dtype_effective,
-        solve_dtype_effective=steps[0].solve_dtype_effective,
-        solver_lo_iters=sum(s.solver_lo_iters for s in steps),
-        geometry_version=steps[0].geometry_version,
-    )

@@ -28,6 +28,9 @@ Key design points
   top eigenpair per bucket size and warm-starts power iteration from the
   cached eigenvector on reuse (the kept set drifts slowly along the path),
   so repeated path solves don't re-estimate from scratch.
+* **Batched twins.** ``fista``, ``cd`` and ``group_fista`` each have a
+  batched strategy (``BATCHED_SOLVERS``): B queries on one union bucket,
+  per-query λ and validity masks, converged queries frozen in place.
 * **Backend selection**: explicit ``backend=`` → ``REPRO_SOLVER_BACKEND``
   env var → ``INTERPRET=1`` (CI) → ``pallas`` on TPU → ``jnp``. Screen-only
   backends registered via :func:`repro.core.engine.register_backend` keep
@@ -702,6 +705,66 @@ def _group_fista_solve(X, y, lam, m: int, beta0, lipschitz, tol,
     return SolveResult(beta, gap, k, gap <= tol * scale, checks)
 
 
+@functools.partial(jax.jit, static_argnames=("m", "max_iter", "cadence"))
+@jax.named_scope("solve")
+def _group_fista_solve_batched(X, Y, lam, m: int, beta0, valid, lipschitz,
+                               tol, max_iter: int,
+                               cadence: int) -> SolveResult:
+    """Batched block-FISTA: B queries share every pass over the bucket X,
+    each with its own λ, its own kept groups (``valid`` (B, b), constant
+    over each group's m columns) and its own convergence freezing, as in
+    :func:`_fista_solve_batched`. The block soft-threshold acts on each
+    query's row."""
+    dtype = X.dtype
+    L = jnp.maximum(lipschitz, 1e-12)                 # shared: same buffer
+    step = 1.0 / L
+    scale = 0.5 * jnp.sum(jnp.square(Y), axis=-1) + 1e-30     # (B,)
+    gap_rows = jax.vmap(functools.partial(group_gap_from_residual, m=m))
+    block_soft = jax.vmap(functools.partial(group_soft_threshold, m=m))
+
+    def gap_of(beta):
+        r = Y - beta @ X.T
+        return gap_rows(r, r @ X, beta, lam, y=Y)
+
+    def body(state):
+        beta, z, t, k, _, conv, iters, checks = state
+        frozen = conv[:, None]
+
+        def one_step(carry, _):
+            beta, z, t = carry
+            g = (z @ X.T - Y) @ X
+            beta_new = (block_soft(z - step * g, step * lam)
+                        * valid).astype(dtype)
+            t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
+            z_new = (beta_new + ((t - 1.0) / t_new) * (beta_new - beta))
+            z_new = (z_new * valid).astype(dtype)
+            # converged queries are fixed points of further iterations
+            beta_new = jnp.where(frozen, beta, beta_new)
+            z_new = jnp.where(frozen, z, z_new)
+            return (beta_new, z_new, t_new), None
+
+        (beta, z, t), _ = jax.lax.scan(one_step, (beta, z, t), None,
+                                       length=cadence)
+        iters = iters + jnp.where(conv, 0, cadence)
+        gap = gap_of(beta)
+        conv = jnp.logical_or(conv, gap <= tol * scale)
+        return beta, z, t, k + cadence, gap, conv, iters, checks + 1
+
+    def cond(state):
+        _, _, _, k, _, conv, _, _ = state
+        return jnp.logical_and(k < max_iter, jnp.any(~conv))
+
+    t0 = jnp.asarray(1.0, dtype=dtype)
+    gap0 = gap_of(beta0)
+    conv0 = gap0 <= tol * scale
+    iters0 = jnp.zeros(Y.shape[:1], jnp.int32)
+    state = (beta0, beta0, t0, jnp.asarray(0), gap0, conv0, iters0,
+             jnp.asarray(1))
+    beta, _, _, _, gap, conv, iters, checks = jax.lax.while_loop(
+        cond, body, state)
+    return SolveResult(beta, gap, iters, conv, checks)
+
+
 # ---------------------------------------------------------------------------
 # Strategies + registry. A strategy is `(engine, Xr, lam, beta0, m) ->
 # (SolveResult, info)` with info = {"gram": bool} telemetry (+ "lo_iters" /
@@ -858,6 +921,16 @@ def _fista_strategy_batched(eng: "SolverEngine", Xr, lam, beta0, valid,
                  "hi_iters": hi_it}
 
 
+def _group_fista_strategy_batched(eng: "SolverEngine", Xr, lam, beta0, valid,
+                                  m: int):
+    L = eng.lipschitz(Xr)
+    with tracing.span("solve.iterate"):
+        res = _group_fista_solve_batched(Xr, eng.y, lam, m, beta0, valid, L,
+                                         eng.tol, eng.max_iter,
+                                         eng.gap_check_cadence)
+    return res, {"gram": False}
+
+
 def _cd_strategy_batched(eng: "SolverEngine", Xr, lam, beta0, valid, m: int):
     n, b = Xr.shape
     max_epochs = eng.max_iter // 10 + 1
@@ -921,6 +994,7 @@ SOLVERS: dict[str, Callable] = {
 BATCHED_SOLVERS: dict[str, Callable] = {
     "fista": _fista_strategy_batched,
     "cd": _cd_strategy_batched,
+    "group_fista": _group_fista_strategy_batched,
 }
 
 

@@ -96,10 +96,11 @@ def group_screen_ref(X: jax.Array, centre: jax.Array, m: int) -> jax.Array:
     """Group screening scores (Corollary 21 LHS): per contiguous group of m,
 
         gscores[g] = ‖X_gᵀ·centre‖₂
+
+    Batched: centre (B, n) → gscores (B, G), one logical pass over X.
     """
-    acc = _acc_dtype(X)
-    dot = _matmul(X.astype(acc).T, centre.astype(acc))
-    return jnp.linalg.norm(dot.reshape(-1, m), axis=1)
+    dot = screen_matvec_ref(X, centre)
+    return jnp.linalg.norm(dot.reshape(*dot.shape[:-1], -1, m), axis=-1)
 
 
 def prox_step_ref(z: jax.Array, g: jax.Array, beta_old: jax.Array,

@@ -7,7 +7,8 @@ the kernel may not load from, primitives Mosaic cannot lower. Nothing runs;
 each test asserts that the compiled program holds the Mosaic kernel
 (``tpu_custom_call``). Shapes are the served deployment's: the MNIST image
 dictionary 784 × 50000, a 784 × 512 reduced bucket for the solver step, a
-1024-column Gram block; and a mesh session's solver ops on the 2×2 mesh.
+1024-column Gram block; the group dictionary 250 × 200000 in groups of 10;
+and a mesh session's solver ops on the 2×2 mesh.
 
 The topology is described inside a module-scoped fixture (never at import):
 only one process at a time may load the TPU compiler's library, and every
@@ -28,6 +29,7 @@ from repro.kernels import (cd_gram_sweep, edpp_screen_scores, fista_step,
 N, P = 784, 50000
 BUCKET = 512
 GRAM_P = 1024
+GROUP_N, GROUP_P = 250, 200000
 
 
 @pytest.fixture(scope="module")
@@ -117,6 +119,14 @@ def test_group_screen_scores_compiles(one_chip, m):
     X = jax.ShapeDtypeStruct((N, P), jnp.float32, sharding=one_chip)
     centre, _ = _query(one_chip, 1, N)
     _compile(lambda X, c: group_screen_scores(X, c, m), X, centre)
+
+
+def test_batched_group_screen_scores_compiles(one_chip):
+    # the group deployment's shape: 250 × 200000 in groups of 10, B = 8
+    X = jax.ShapeDtypeStruct((GROUP_N, GROUP_P), jnp.float32,
+                             sharding=one_chip)
+    centre, _ = _query(one_chip, 8, GROUP_N)
+    _compile(lambda X, c: group_screen_scores(X, c, 10), X, centre)
 
 
 @pytest.mark.parametrize("op", ["fista_step", "cd_gram_sweep", "prox_step"])

@@ -281,6 +281,24 @@ def test_specs_validate_at_construction():
         gsess.path(np.ones(4), [0.1], config=PathConfig(rule="dpp"))
 
 
+def test_group_sessions_solve_the_group_lasso():
+    # `fista` on a group session is block FISTA, the same path as the
+    # default; `cd` solves the plain Lasso and is refused
+    X, Y = _problem(b=3)
+    grids = _grids(X, Y, num=4)
+    runs = {}
+    for strategy in (None, "fista"):
+        cfg = PathConfig(solve=SolveSpec(strategy=strategy, tol=1e-6))
+        sess = LassoSession.fit(X, groups=4, config=cfg)
+        runs[strategy] = sess.path(Y, grids)
+    for attr in ("betas", "masks"):
+        np.testing.assert_array_equal(getattr(runs[None], attr),
+                                      getattr(runs["fista"], attr))
+    with pytest.raises(ValueError, match="plain Lasso"):
+        LassoSession.fit(X, groups=4,
+                         config=PathConfig(solve=SolveSpec(strategy="cd")))
+
+
 def test_custom_registered_solver_passes_validation():
     from repro.core import SOLVERS, register_solver
     register_solver("fista_alias", SOLVERS["fista"])

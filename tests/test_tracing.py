@@ -2,7 +2,6 @@
 of one dispatch, the counters each step records, the layer scopes on the
 device ops, and answers unchanged with the profiler running."""
 
-import dataclasses
 import glob
 import os
 
@@ -15,9 +14,7 @@ from repro import LassoSession
 from repro.core import PathConfig, ScreenSpec, engine, tracing
 from repro.core import lasso, path as path_mod, screening as scr
 from repro.core import solver as solver_mod
-from repro.core.path import PathStepStats
-from repro.core.session import _merge_step_stats
-from repro.kernels import ops, screen_matvec
+from repro.kernels import group_screen_scores, ops, screen_matvec
 from repro.launch import serve_loop as sl
 
 N, P, B, K = 64, 512, 4, 4
@@ -176,17 +173,29 @@ def test_kept_counts_inside_one_bucket_compile_nothing():
     assert sum(s.compiles for s in second.stats) == 0
 
 
-def test_merge_sums_the_tracing_fields():
-    fields = {"host_syncs": (3, 5), "host_sync_s": (0.25, 0.5),
-              "gather_time_s": (0.125, 0.25), "copyout_time_s": (1.0, 2.0),
-              "state_time_s": (0.5, 0.75), "step_time_s": (4.0, 8.0),
-              "compiles": (1, 2)}
-    steps = [PathStepStats(1.0, 10, 5, 7, 0.0, 0, 0.1, 0.2,
-                           **{f: v[i] for f, v in fields.items()})
-             for i in range(2)]
-    merged = dataclasses.asdict(_merge_step_stats(steps))
-    for f, (a, b) in fields.items():
-        assert merged[f] == a + b, f
+def test_a_batched_group_step_screens_once_and_reads_four_times(
+        monkeypatch):
+    # the batch's λ̄_max is the prologue's one read; a live step reads the
+    # mask, the solver's gap checks and iteration count, and the step
+    # epilogue's packed summary
+    X, Y = _problem(seed=2)
+    sess = LassoSession.fit(X, groups=4)
+    calls = []
+    real = tracing.fetch
+
+    def counting(x, dtype=None):
+        calls.append(getattr(tracing._local, "step", None))
+        return real(x, dtype)
+
+    monkeypatch.setattr(tracing, "fetch", counting)
+    res = sess.path(Y, num_lambdas=K, hi_frac=0.9)
+    assert sum(c is None for c in calls) == 1
+    assert all(st.n_kept > 0 for st in res.stats)
+    for st in res.stats:
+        assert (st.batch_size, st.x_passes, st.host_syncs) == (B, 1, 4)
+        parts = (st.screen_time_s + st.solve_time_s + st.copyout_time_s
+                 + st.state_time_s)
+        assert st.step_time_s >= parts > 0
 
 
 def _state():
@@ -205,6 +214,9 @@ def _lowered():
     return {
         "screen_matvec": ("screen", lambda: screen_matvec.lower(
             X, jnp.ones((B, N)), interpret=True)),
+        "group_screen_scores": ("group_reduce", lambda:
+                                group_screen_scores.lower(
+                                    X, jnp.ones((B, N)), 4, interpret=True)),
         "make_sphere": ("screen", lambda: scr.make_sphere.lower(
             "edpp", jnp.ones((B, N)), lam, _state())),
         "sphere_combine": ("screen", lambda: engine._sphere_combine.lower(
