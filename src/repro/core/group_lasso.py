@@ -29,6 +29,61 @@ def group_soft_threshold(u: jax.Array, thresh, m: int) -> jax.Array:
     return (scale * ug).reshape(-1)
 
 
+# Member-major layout. A bucket of G groups of m columns is stored group-
+# major, column g·m + i for member i of group g. Member-major stores that
+# column at i·G + g: member i of every group is one contiguous run of G
+# columns, so on a TPU the groups lie along the 128 lanes and each group's
+# norm is a sum of m lane-dense slabs, where the group-major (…, G, m) view
+# fills m of 128 lanes. The block solver works in this order inside its
+# jitted body and converts at its boundary.
+
+def to_member_major(a: jax.Array, m: int, axis: int = -1) -> jax.Array:
+    """Reorder ``axis`` (G·m entries) from group-major to member-major:
+    entry g·m + i moves to i·G + g."""
+    axis %= a.ndim
+    split = a.shape[:axis] + (a.shape[axis] // m, m) + a.shape[axis + 1:]
+    return jnp.swapaxes(a.reshape(split), axis, axis + 1).reshape(a.shape)
+
+
+def to_group_major(a: jax.Array, m: int, axis: int = -1) -> jax.Array:
+    """Inverse of :func:`to_member_major`: the same reordering with the
+    roles of G and m exchanged."""
+    return to_member_major(a, a.shape[axis] // m, axis)
+
+
+def member_major_norms(v: jax.Array, m: int) -> jax.Array:
+    """Group norms (…, G) of a member-major (…, G·m) array: the square
+    root of a sum of m slabs of G columns."""
+    G = v.shape[-1] // m
+    slabs = jnp.square(v).reshape(v.shape[:-1] + (m, G))
+    return jnp.sqrt(jnp.sum(slabs, axis=-2))
+
+
+def member_major_soft_threshold(u: jax.Array, thresh, m: int) -> jax.Array:
+    """:func:`group_soft_threshold` on a member-major (…, G·m) array, with
+    ``thresh`` scalar or one per leading row."""
+    thresh = jnp.asarray(thresh)[..., None]
+    scale = jnp.maximum(
+        0.0, 1.0 - thresh * jnp.sqrt(float(m))
+        / (member_major_norms(u, m) + 1e-30))
+    G = u.shape[-1] // m
+    return (u.reshape(u.shape[:-1] + (m, G))
+            * scale[..., None, :]).reshape(u.shape)
+
+
+def member_major_gap_from_residual(r, dot, beta, lam, m: int, y):
+    """:func:`group_gap_from_residual` with ``dot`` = Xᵀr and ``beta``
+    member-major; leading axes of ``r``, ``dot``, ``beta``, ``y`` and
+    ``lam`` are batch axes (one gap per row)."""
+    ratio = jnp.max(member_major_norms(dot, m), axis=-1) / jnp.sqrt(float(m))
+    s = jnp.minimum(1.0, lam / (ratio + 1e-30))
+    gnorms = member_major_norms(beta, m)
+    return (0.5 * jnp.sum(jnp.square(r), axis=-1)
+            + lam * jnp.sqrt(float(m)) * jnp.sum(gnorms, axis=-1)
+            - 0.5 * jnp.sum(jnp.square(y), axis=-1)
+            + 0.5 * jnp.sum(jnp.square(s[..., None] * r - y), axis=-1))
+
+
 def group_lambda_max(X: jax.Array, y: jax.Array, m: int) -> jax.Array:
     """λ̄_max = max_g ‖X_gᵀy‖/√n_g (eq. 55)."""
     corr = (X.T @ y).reshape(-1, m)

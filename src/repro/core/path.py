@@ -117,6 +117,8 @@ class PathStepStats:
     step_time_s: float = 0.0      # path.step: the whole step
     compiles: int = 0             # programs compiled or loaded from the
     #                               persistent cache during the step
+    member_major_solves: int = 0  # reduced solves that iterated on the
+    #                               member-major bucket (group solver, m > 1)
 
 
 @dataclasses.dataclass
@@ -363,7 +365,7 @@ def _path_driver(X, Y, lambdas, cfg, *, m: int, screen_engine,
             with tracing.span("path.solve"):
                 t0 = time.perf_counter()
                 kkt_rounds = 0
-                solves = gram_solves = gap_checks = 0
+                solves = gram_solves = gap_checks = member_major = 0
                 solver_x_passes = 0.0
                 solver_lo_iters = 0
                 gather_time = 0.0
@@ -428,6 +430,7 @@ def _path_driver(X, Y, lambdas, cfg, *, m: int, screen_engine,
                         q_conv = int(conv_vec.sum())
                         solves += 1
                         gram_solves += int(solver_engine.last_used_gram)
+                        member_major += int(solver_engine.last_member_major)
                         gap_checks += solver_engine.last_gap_checks
                         solver_x_passes += (solver_engine.last_x_passes
                                             * (bucket * m) / p)
@@ -486,6 +489,7 @@ def _path_driver(X, Y, lambdas, cfg, *, m: int, screen_engine,
                 geometry_version=geo_version,
                 gather_time_s=gather_time,
                 copyout_time_s=copyout.seconds,
+                member_major_solves=member_major,
             ))
             if cfg.checkpoint_fn:
                 if batch is None:

@@ -52,7 +52,9 @@ import jax.numpy as jnp
 
 from ..kernels import ops
 from . import tracing
-from .group_lasso import group_gap_from_residual, group_soft_threshold
+from .group_lasso import (member_major_gap_from_residual,
+                          member_major_soft_threshold, to_group_major,
+                          to_member_major)
 from .lasso import gap_from_residual, soft_threshold, top_eigenpair
 
 
@@ -671,20 +673,26 @@ def _group_fista_solve(X, y, lam, m: int, beta0, lipschitz, tol,
                        max_iter: int, cadence: int) -> SolveResult:
     """Block-FISTA for the group Lasso (pure-jnp body on every backend —
     the block soft-threshold has no fused kernel yet). Zero-padded group
-    blocks are fixed points, so group buckets pass through."""
+    blocks are fixed points, so group buckets pass through.
+
+    The iteration runs on the member-major view of the bucket
+    (:func:`repro.core.group_lasso.to_member_major`): X's columns and the
+    iterate are reordered once on entry and β once on exit, so callers
+    see the group-major layout."""
     dtype = X.dtype
+    Xt = to_member_major(X.T, m, axis=0)       # (b, n): rows member-major
     L = jnp.maximum(lipschitz, 1e-12)
     step = 1.0 / L
     scale = 0.5 * jnp.sum(jnp.square(y)) + 1e-30
 
     def gap_of(beta):
-        r = y - X @ beta
-        return group_gap_from_residual(r, X.T @ r, beta, lam, m, y)
+        r = y - beta @ Xt
+        return member_major_gap_from_residual(r, Xt @ r, beta, lam, m, y)
 
     def one_step(carry, _):
         beta, z, t = carry
-        g = X.T @ (X @ z - y)
-        beta_new = group_soft_threshold(z - step * g, step * lam, m)
+        g = Xt @ (z @ Xt - y)
+        beta_new = member_major_soft_threshold(z - step * g, step * lam, m)
         t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
         z_new = beta_new + ((t - 1.0) / t_new) * (beta_new - beta)
         return (beta_new, z_new, t_new), None
@@ -699,10 +707,12 @@ def _group_fista_solve(X, y, lam, m: int, beta0, lipschitz, tol,
                                        length=cadence)
         return beta, z, t, k + cadence, gap_of(beta), checks + 1
 
+    beta0 = to_member_major(beta0, m)
     t0 = jnp.asarray(1.0, dtype=dtype)
     state = (beta0, beta0, t0, jnp.asarray(0), gap_of(beta0), jnp.asarray(1))
     beta, _, _, k, gap, checks = jax.lax.while_loop(cond, body, state)
-    return SolveResult(beta, gap, k, gap <= tol * scale, checks)
+    return SolveResult(to_group_major(beta, m), gap, k, gap <= tol * scale,
+                       checks)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "max_iter", "cadence"))
@@ -713,18 +723,19 @@ def _group_fista_solve_batched(X, Y, lam, m: int, beta0, valid, lipschitz,
     """Batched block-FISTA: B queries share every pass over the bucket X,
     each with its own λ, its own kept groups (``valid`` (B, b), constant
     over each group's m columns) and its own convergence freezing, as in
-    :func:`_fista_solve_batched`. The block soft-threshold acts on each
-    query's row."""
+    :func:`_fista_solve_batched`. Like :func:`_group_fista_solve` it
+    iterates on the member-major view: X, ``beta0`` and ``valid`` are
+    reordered on entry, β on exit."""
     dtype = X.dtype
+    Xt = to_member_major(X.T, m, axis=0)       # (b, n): rows member-major
+    valid = to_member_major(valid, m)
     L = jnp.maximum(lipschitz, 1e-12)                 # shared: same buffer
     step = 1.0 / L
     scale = 0.5 * jnp.sum(jnp.square(Y), axis=-1) + 1e-30     # (B,)
-    gap_rows = jax.vmap(functools.partial(group_gap_from_residual, m=m))
-    block_soft = jax.vmap(functools.partial(group_soft_threshold, m=m))
 
     def gap_of(beta):
-        r = Y - beta @ X.T
-        return gap_rows(r, r @ X, beta, lam, y=Y)
+        r = Y - beta @ Xt
+        return member_major_gap_from_residual(r, r @ Xt.T, beta, lam, m, Y)
 
     def body(state):
         beta, z, t, k, _, conv, iters, checks = state
@@ -732,8 +743,9 @@ def _group_fista_solve_batched(X, Y, lam, m: int, beta0, valid, lipschitz,
 
         def one_step(carry, _):
             beta, z, t = carry
-            g = (z @ X.T - Y) @ X
-            beta_new = (block_soft(z - step * g, step * lam)
+            g = (z @ Xt - Y) @ Xt.T
+            beta_new = (member_major_soft_threshold(z - step * g,
+                                                    step * lam, m)
                         * valid).astype(dtype)
             t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
             z_new = (beta_new + ((t - 1.0) / t_new) * (beta_new - beta))
@@ -754,6 +766,7 @@ def _group_fista_solve_batched(X, Y, lam, m: int, beta0, valid, lipschitz,
         _, _, _, k, _, conv, _, _ = state
         return jnp.logical_and(k < max_iter, jnp.any(~conv))
 
+    beta0 = to_member_major(beta0, m)
     t0 = jnp.asarray(1.0, dtype=dtype)
     gap0 = gap_of(beta0)
     conv0 = gap0 <= tol * scale
@@ -762,15 +775,16 @@ def _group_fista_solve_batched(X, Y, lam, m: int, beta0, valid, lipschitz,
              jnp.asarray(1))
     beta, _, _, _, gap, conv, iters, checks = jax.lax.while_loop(
         cond, body, state)
-    return SolveResult(beta, gap, iters, conv, checks)
+    return SolveResult(to_group_major(beta, m), gap, iters, conv, checks)
 
 
 # ---------------------------------------------------------------------------
 # Strategies + registry. A strategy is `(engine, Xr, lam, beta0, m) ->
 # (SolveResult, info)` with info = {"gram": bool} telemetry (+ "lo_iters" /
-# "lo_checks" / "hi_iters" from the mixed-precision fista two-phase, and
+# "lo_checks" / "hi_iters" from the mixed-precision fista two-phase,
 # "lo_passes" / "x_passes" pass-accounting overrides from the
-# mixed-precision Gram-CD two-phase).
+# mixed-precision Gram-CD two-phase, and "member_major" from the group
+# solver when it iterated on the member-major bucket).
 # ---------------------------------------------------------------------------
 
 _BF16_SOLVE_WARNED: set[str] = set()
@@ -882,7 +896,7 @@ def _group_fista_strategy(eng: "SolverEngine", Xr, lam, beta0, m: int):
     with tracing.span("solve.iterate"):
         res = _group_fista_solve(Xr, eng.y, lam, m, beta0, L, eng.tol,
                                  eng.max_iter, eng.gap_check_cadence)
-    return res, {"gram": False}
+    return res, {"gram": False, "member_major": m > 1}
 
 
 def _fista_strategy_batched(eng: "SolverEngine", Xr, lam, beta0, valid,
@@ -928,7 +942,7 @@ def _group_fista_strategy_batched(eng: "SolverEngine", Xr, lam, beta0, valid,
         res = _group_fista_solve_batched(Xr, eng.y, lam, m, beta0, valid, L,
                                          eng.tol, eng.max_iter,
                                          eng.gap_check_cadence)
-    return res, {"gram": False}
+    return res, {"gram": False, "member_major": m > 1}
 
 
 def _cd_strategy_batched(eng: "SolverEngine", Xr, lam, beta0, valid, m: int):
@@ -1076,6 +1090,7 @@ class SolverEngine:
         self.total_gap_checks = 0
         self.last_gap_checks = 0
         self.last_used_gram = False
+        self.last_member_major = False  # last solve ran member-major
         self.last_x_passes = 0.0   # HBM passes over the reduced buffer
         # Mixed-precision solve telemetry (solve_dtype="bfloat16"):
         self.last_lo_iters = 0             # bf16-phase iterations last solve
@@ -1161,6 +1176,7 @@ class SolverEngine:
         res, info = SOLVERS[self.solver](self, Xr, lam, beta0, m)
         self.n_solves += 1
         self.last_used_gram = bool(info.get("gram", False))
+        self.last_member_major = bool(info.get("member_major", False))
         self.gram_solves += int(self.last_used_gram)
         self.last_gap_checks = int(tracing.fetch(res.gap_checks))
         self.total_gap_checks += self.last_gap_checks
@@ -1306,6 +1322,7 @@ class SolverEngine:
             self.last_solve_bytes = passes * n * b * 4.0
         self.n_solves += 1
         self.last_used_gram = bool(info.get("gram", False))
+        self.last_member_major = bool(info.get("member_major", False))
         self.gram_solves += int(self.last_used_gram)
         self.total_gap_checks += self.last_gap_checks
         self.total_solve_bytes += self.last_solve_bytes

@@ -5,10 +5,12 @@ described rather than attached, so these tests catch what interpret mode
 cannot: block shapes off the (8, 128) tiling, operands in a memory space
 the kernel may not load from, primitives Mosaic cannot lower. Nothing runs;
 each test asserts that the compiled program holds the Mosaic kernel
-(``tpu_custom_call``). Shapes are the served deployment's: the MNIST image
-dictionary 784 × 50000, a 784 × 512 reduced bucket for the solver step, a
-1024-column Gram block; the group dictionary 250 × 200000 in groups of 10;
-and a mesh session's solver ops on the 2×2 mesh.
+(``tpu_custom_call``), or, for the block-FISTA solve, that its loops hold
+no lane-sparse group-major op. Shapes are the served deployment's: the
+MNIST image dictionary 784 × 50000, a 784 × 512 reduced bucket for the
+solver step, a 1024-column Gram block; the group dictionary 250 × 200000
+in groups of 10 and its widest block-FISTA bucket (8 × 20480); and a mesh
+session's solver ops on the 2×2 mesh.
 
 The topology is described inside a module-scoped fixture (never at import):
 only one process at a time may load the TPU compiler's library, and every
@@ -16,6 +18,7 @@ test worker imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -127,6 +130,48 @@ def test_batched_group_screen_scores_compiles(one_chip):
                              sharding=one_chip)
     centre, _ = _query(one_chip, 8, GROUP_N)
     _compile(lambda X, c: group_screen_scores(X, c, 10), X, centre)
+
+
+def _while_bodies(hlo: str) -> dict:
+    """{computation name: its instruction lines} for every computation a
+    while loop runs as its body, nested loops included."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        if line and not line.startswith(" ") and line.rstrip().endswith("{"):
+            cur = line.split()[0].lstrip("%")
+            comps[cur] = []
+        elif cur is not None and line.startswith("  "):
+            comps[cur].append(line.strip())
+    bodies = {name.split(",")[0] for lines in comps.values() for ln in lines
+              for name in re.findall(r"body=%?([\w.\-]+)", ln)}
+    return {name: comps[name] for name in bodies}
+
+
+def test_group_fista_loop_is_lane_dense(one_chip):
+    """The batched block-FISTA solve at the group deployment's widest
+    bucket (2048 groups of 10, B = 8) iterates on the member-major
+    bucket: no op of its loops holds the iterate as (8, 2048, 10), the
+    group-major view that fills 10 of 128 lanes."""
+    from repro.core.solver import _group_fista_solve_batched
+
+    B, G, m = 8, 2048, 10
+
+    def s(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def solve(X, Y, lam, beta0, valid, L, tol):
+        return _group_fista_solve_batched(X, Y, lam, m, beta0, valid, L, tol,
+                                          5000, 10)
+
+    with jax.default_matmul_precision("highest"):
+        hlo = jax.jit(solve).lower(
+            s(GROUP_N, G * m), s(B, GROUP_N), s(B), s(B, G * m),
+            s(B, G * m), s(), s()).compile().as_text()
+    bodies = _while_bodies(hlo)
+    assert len(bodies) >= 2              # the gap-check loop and its scan
+    for lines in bodies.values():
+        for line in lines:
+            assert f"[{B},{G},{m}]" not in line, line
 
 
 @pytest.mark.parametrize("op", ["fista_step", "cd_gram_sweep", "prox_step"])
