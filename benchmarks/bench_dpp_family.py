@@ -100,16 +100,17 @@ def engine_screen_bytes(X, y, grid, betas, rule, screen_dtype):
     the step before, built from ``betas`` (the reference path), as the
     sequential driver threads it."""
     eng = ScreeningEngine(jnp.asarray(X, jnp.float32),
-                          jnp.asarray(y, jnp.float32),
+                          jnp.asarray(y, jnp.float32)[None],  # batch of one
                           screen_dtype=screen_dtype)
     state = eng.state_at_lambda_max()
-    lmax = float(eng.lam_max)
+    lmax = float(eng.lam_max[0])
     for k, lam in enumerate(grid):
         if lam >= lmax:
             continue
-        eng.screen(float(lam), state, rule=rule)
-        state = eng.make_state(jnp.asarray(betas[k], jnp.float32),
-                               float(lam))
+        lam_v = jnp.asarray([lam], jnp.float32)
+        eng.screen(lam_v, state, rule=rule)
+        state = eng.make_state(jnp.asarray(betas[k], jnp.float32)[None],
+                               lam_v)
     return eng.total_screen_bytes
 
 

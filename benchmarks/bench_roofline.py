@@ -199,12 +199,13 @@ def main(argv=None):
     sb = D.sharded_backend(mesh, args.backend)
     arms = {}
     for dtype in ("float32", "bfloat16"):
-        eng = ScreeningEngine(Xd, yd, backend=sb, screen_dtype=dtype)
+        eng = ScreeningEngine(Xd, yd[None], backend=sb, screen_dtype=dtype)
         st0 = eng.state_at_lambda_max()
 
         def sweep():
-            return np.stack([np.asarray(eng.screen(float(lam), st0, "edpp"))
-                             for lam in grid])
+            return np.stack([np.asarray(eng.screen(
+                jnp.asarray([lam], jnp.float32), st0, "edpp"))[0]
+                for lam in grid])
         sweep(), sweep()                      # warm: compile + caches
         eng.total_screen_bytes = 0.0
         t0 = time.perf_counter()
@@ -281,19 +282,20 @@ def main(argv=None):
     lam_s = 0.3 * float(jnp.max(jnp.abs(Xs.T @ ys)))
     arms_s = {}
     for dtype in ("float32", "bfloat16"):
-        eng = SolverEngine(ys, tol=tol_s, gap_check_cadence=cadence,
+        eng = SolverEngine(ys[None], tol=tol_s, gap_check_cadence=cadence,
                            solve_dtype=dtype)
-        eng.solve(Xs, lam_s).beta.block_until_ready()    # warm compile
+        lam_v = jnp.asarray([lam_s], jnp.float32)
+        eng.solve(Xs, lam_v).beta.block_until_ready()    # warm compile
         t0 = time.perf_counter()
-        res = eng.solve(Xs, lam_s)
+        res = eng.solve(Xs, lam_v)
         res.beta.block_until_ready()
         dt = time.perf_counter() - t0
-        iters = max(int(res.iters), 1)
+        iters = max(int(res.iters[0]), 1)
         arms_s[dtype] = {
-            "beta": np.asarray(res.beta), "iters": iters,
+            "beta": np.asarray(res.beta[0]), "iters": iters,
             "lo_iters": eng.last_lo_iters, "wall_time_s": dt,
             "bytes_per_solve_iter": eng.last_solve_bytes / iters,
-            "converged": bool(res.converged),
+            "converged": bool(res.converged[0]),
             "effective_dtype": eng.last_effective_dtype,
         }
     per32 = arms_s["float32"]["bytes_per_solve_iter"]

@@ -5,9 +5,9 @@ Layering (see docs/screening-rules.md for the rule-by-rule map):
     session.py          LassoSession — THE front door: fit(X) once (owns
                         the DictionaryGeometry, resolved backends, the
                         per-bucket Lipschitz cache, optional mesh
-                        placement), then path(y | Y) dispatches to the
-                        single / batched / group / distributed drivers
-                        from input rank + groups + mesh, returning ONE
+                        placement), then path(y | Y) promotes a single
+                        y to a batch of one and runs the one driver for
+                        the session's groups and mesh, returning ONE
                         unified PathResult; PathConfig = ScreenSpec +
                         SolveSpec, validated at construction (docs/api.md)
     screening.py        rule geometry — every ball rule as a SphereTest
@@ -29,10 +29,9 @@ Layering (see docs/screening-rules.md for the rule-by-rule map):
                         per-bucket Lipschitz cache
     path.py             sequential λ-path driver (screen → reduce → solve →
                         KKT re-check): one generic _path_driver consuming
-                        both engines, single-query (lasso_path) or batched
-                        multi-query (lasso_path_batched: per-query λ-grids,
-                        union bucketing, convergence freezing —
-                        docs/serving.md)
+                        both engines over a (B, n) query batch (per-query
+                        λ-grids, union bucketing, convergence freezing —
+                        docs/serving.md); a single query is B = 1
     distributed.py      shard_map / pjit variants whose per-shard score and
                         solver-update blocks reuse the engines' arithmetic;
                         batched multi-query variants psum (B, N) blocks
@@ -74,7 +73,6 @@ from .lasso import (  # noqa: F401
     top_eigenpair,
 )
 from .solver import (  # noqa: F401
-    BATCHED_SOLVERS,
     FistaResult,
     GroupFistaResult,
     SOLVERS,

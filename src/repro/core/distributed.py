@@ -146,11 +146,11 @@ def place_dictionary(mesh: Mesh, X):
 
 
 def place_queries(mesh: Mesh, Y):
-    """Place query-side vectors on the mesh's 2D layout: a batch Y (B, n)
-    shards its leading axis over the ``query`` axis (when B divides it);
-    a single y (n,) — or a non-dividing batch — replicates."""
+    """Place a query batch Y (B, n) on the mesh's 2D layout: its leading
+    axis shards over the ``query`` axis when B divides it, and replicates
+    otherwise (a batch of one included)."""
     Y = jnp.asarray(Y)
-    spec = P(_qspec(mesh, Y.shape[0]), None) if Y.ndim == 2 else P()
+    spec = P(_qspec(mesh, Y.shape[0]), None)
     return jax.device_put(Y, NamedSharding(mesh, spec))
 
 

@@ -18,12 +18,13 @@ column norms ‖x_j‖ and the Gram/Lipschitz machinery depend on **X only**
 (:class:`DictionaryGeometry` — immutable, computed once, shared across
 every query against this dictionary), while |Xᵀy|, λ_max, the λ_max ray v₁
 and the dual state θ are cheap **per-query** state (:class:`PathWorkspace`
-= geometry + one query batch). A workspace built over a (B, n) batch of
-response vectors screens all B queries per single fused pass over X:
-``screen`` takes per-query λ (B,) and a batched
-:class:`~repro.core.screening.DualState` and returns a (B, p) mask — HBM
-traffic over X is amortised 1/B per query (the serving regime: one fitted
-dictionary, millions of y's).
+= geometry + one query batch). The engines hold a batch of queries,
+always: a workspace over a (B, n) batch of response vectors screens all B
+queries per single fused pass over X — ``screen`` takes per-query λ (B,)
+and a batched :class:`~repro.core.screening.DualState` and returns a
+(B, p) mask, so HBM traffic over X is amortised 1/B per query (the
+serving regime: one fitted dictionary, millions of y's). A single query
+is a batch of one.
 
 Backend registry
 ----------------
@@ -159,18 +160,15 @@ def block_scores(Xb, centre, rho, col_norms=None):
 
 
 # ---------------------------------------------------------------------------
-# Jitted combine steps (O(p) or O(B·p), applied to the kernel's single-pass
-# output). Each branches on a leading batch axis at trace time: batched
-# inputs use the (B, ·) arithmetic of the screening module's batched oracles.
+# Jitted combine steps (O(B·p), applied to the kernel's single-pass output),
+# with the (B, ·) arithmetic of the screening module's batched oracles.
 # ---------------------------------------------------------------------------
 
 @jax.jit
 @jax.named_scope("screen")
 def _sphere_combine(dot, rho, col_norms, eps):
-    if dot.ndim == 2:
-        return jnp.abs(dot) + scr._col(rho) * col_norms \
-            < 1.0 - scr._col(jnp.asarray(eps))
-    return jnp.abs(dot) + rho * col_norms < 1.0 - eps
+    return jnp.abs(dot) + scr._col(rho) * col_norms \
+        < 1.0 - scr._col(jnp.asarray(eps))
 
 
 @jax.jit
@@ -182,17 +180,14 @@ def _gap_combine_from(dot, sup_corr, y, lam_next, state, col_norms, eps):
     from the gathered f32 dots, see ``_gap_screen_margin`` notes)."""
     test = scr.gap_sphere(y, lam_next, state, sup_corr=sup_corr)
     s = jnp.maximum(1.0, sup_corr)
-    if dot.ndim == 2:
-        return jnp.abs(dot) / scr._col(s) \
-            + scr._col(test.rho) * col_norms < 1.0 - eps
-    return jnp.abs(dot) / s + test.rho * col_norms < 1.0 - eps
+    return jnp.abs(dot) / scr._col(s) \
+        + scr._col(test.rho) * col_norms < 1.0 - eps
 
 
 @jax.jit
 @jax.named_scope("screen")
 def _gap_combine(dot, y, lam_next, state, col_norms, eps):
-    sup_corr = (jnp.max(jnp.abs(dot), axis=-1) if dot.ndim == 2
-                else jnp.max(jnp.abs(dot)))
+    sup_corr = jnp.max(jnp.abs(dot), axis=-1)
     return _gap_combine_from(dot, sup_corr, y, lam_next, state, col_norms,
                              eps)
 
@@ -200,9 +195,7 @@ def _gap_combine(dot, y, lam_next, state, col_norms, eps):
 @jax.jit
 @jax.named_scope("screen")
 def _strong_combine(dot, lam_next, lam_prev, eps):
-    if dot.ndim == 2:
-        return jnp.abs(dot) < scr._col(2.0 * lam_next - lam_prev - eps)
-    return jnp.abs(dot) < 2.0 * lam_next - lam_prev - eps
+    return jnp.abs(dot) < scr._col(2.0 * lam_next - lam_prev - eps)
 
 
 # Margin-aware twins of the combines above, for the reduced-precision fast
@@ -214,22 +207,15 @@ def _strong_combine(dot, lam_next, lam_prev, eps):
 @jax.jit
 @jax.named_scope("screen")
 def _sphere_combine_margin(dot, rho, col_norms, eps, margin):
-    if dot.ndim == 2:
-        scores = jnp.abs(dot) + scr._col(rho) * col_norms
-        thresh = 1.0 - scr._col(jnp.asarray(eps))
-    else:
-        scores = jnp.abs(dot) + rho * col_norms
-        thresh = 1.0 - eps
+    scores = jnp.abs(dot) + scr._col(rho) * col_norms
+    thresh = 1.0 - scr._col(jnp.asarray(eps))
     return scores < thresh, jnp.abs(scores - thresh) <= margin
 
 
 @jax.jit
 @jax.named_scope("screen")
 def _strong_combine_margin(dot, lam_next, lam_prev, eps, margin):
-    if dot.ndim == 2:
-        thresh = scr._col(2.0 * lam_next - lam_prev - eps)
-    else:
-        thresh = 2.0 * lam_next - lam_prev - eps
+    thresh = scr._col(2.0 * lam_next - lam_prev - eps)
     a = jnp.abs(dot)
     return a < thresh, jnp.abs(a - thresh) <= margin
 
@@ -248,10 +234,7 @@ def _gap_cut_combine_from(dot, gdot, sup_corr, y, lam_next, state, col_norms,
     """The gap_cut combine with ``sup_corr`` supplied explicitly (see
     ``_gap_combine_from`` — same split, same fallback consumer)."""
     test = scr.gap_sphere(y, lam_next, state, sup_corr=sup_corr)
-    if dot.ndim == 2:
-        scores_c = dot / scr._col(jnp.maximum(1.0, sup_corr))
-    else:
-        scores_c = dot / jnp.maximum(1.0, sup_corr)
+    scores_c = dot / scr._col(jnp.maximum(1.0, sup_corr))
     return scr.dome_scores(scores_c, gdot, col_norms, test.centre, test.rho,
                            ghat, b) < 1.0 - eps
 
@@ -262,8 +245,7 @@ def _gap_cut_combine(dot, gdot, y, lam_next, state, col_norms, ghat, b, eps):
     """gap_cut: the GAP sphere's feasibility rescale (served by the dot the
     pass already produced, exactly like _gap_combine) composed with the
     half-space sup over ball ∩ cut."""
-    sup_corr = (jnp.max(jnp.abs(dot), axis=-1) if dot.ndim == 2
-                else jnp.max(jnp.abs(dot)))
+    sup_corr = jnp.max(jnp.abs(dot), axis=-1)
     return _gap_cut_combine_from(dot, gdot, sup_corr, y, lam_next, state,
                                  col_norms, ghat, b, eps)
 
@@ -321,10 +303,8 @@ def _gap_cand(dot, margin):
     a = jnp.abs(dot)
     abs_hi = a + margin
     abs_lo = jnp.maximum(a - margin, 0.0)
-    if dot.ndim == 2:
-        t = jnp.maximum(jnp.max(abs_lo, axis=-1), 1.0)
-        return abs_hi >= scr._col(t)
-    return abs_hi >= jnp.maximum(jnp.max(abs_lo), 1.0)
+    t = jnp.maximum(jnp.max(abs_lo, axis=-1), 1.0)
+    return abs_hi >= scr._col(t)
 
 
 @jax.jit
@@ -338,13 +318,9 @@ def _gap_combine_margin(dot, margin, sup_corr, y, lam_next, state,
     test = scr.gap_sphere(y, lam_next, state, sup_corr=sup_corr)
     s = jnp.maximum(1.0, sup_corr)
     a = jnp.abs(dot)
-    if dot.ndim == 2:
-        sc, rc = scr._col(s), scr._col(test.rho)
-        hi = (a + margin) / sc + rc * col_norms
-        lo = jnp.maximum(a - margin, 0.0) / sc + rc * col_norms
-    else:
-        hi = (a + margin) / s + test.rho * col_norms
-        lo = jnp.maximum(a - margin, 0.0) / s + test.rho * col_norms
+    sc, rc = scr._col(s), scr._col(test.rho)
+    hi = (a + margin) / sc + rc * col_norms
+    lo = jnp.maximum(a - margin, 0.0) / sc + rc * col_norms
     thresh = 1.0 - eps
     return hi < thresh, (hi >= thresh) & (lo < thresh)
 
@@ -359,8 +335,7 @@ def _gap_cut_combine_margin(dot, gdot, e_c, e_g, sup_corr, y, lam_next,
     `dome_score_bounds` call the dome margin combine makes."""
     test = scr.gap_sphere(y, lam_next, state, sup_corr=sup_corr)
     t_b = scr.dome_t_b(test.centre, test.rho, ghat, b)
-    s = scr._col(jnp.maximum(1.0, sup_corr)) if dot.ndim == 2 \
-        else jnp.maximum(1.0, sup_corr)
+    s = scr._col(jnp.maximum(1.0, sup_corr))
     lo, hi = scr.dome_score_bounds((dot - e_c) / s, (dot + e_c) / s,
                                    gdot - e_g, gdot + e_g, col_norms,
                                    test.rho, test.rho, t_b, t_b)
@@ -370,27 +345,10 @@ def _gap_cut_combine_margin(dot, gdot, e_c, e_g, sup_corr, y, lam_next,
 
 @jax.jit
 @jax.named_scope("state")
-def _make_state(X, y, beta, lam, lmax, v1max):
-    """Sequential DualState with the λ_max branch served from cache — no
-    per-step Xᵀy pass (make_dual_state recomputes it every call)."""
-    theta_seq = (y - X @ beta) / lam
-    at_max = lam >= lmax * (1.0 - 1e-12)
-    theta = jnp.where(at_max, y / lmax, theta_seq)
-    v1 = jnp.where(at_max, v1max, y / lam - theta_seq)
-    return scr.DualState(
-        theta=theta,
-        lam=jnp.where(at_max, lmax, jnp.asarray(lam, X.dtype)),
-        v1=v1,
-        at_lmax=jnp.asarray(at_max),
-        beta_l1=jnp.where(at_max, 0.0, jnp.sum(jnp.abs(beta))),
-    )
-
-
-@jax.jit
-@jax.named_scope("state")
 def _make_state_batched(X, y, beta, lam, lmax, v1max):
-    """Batched `_make_state`: y/beta (B, ·), lam/lmax (B,), v1max (B, n).
-    Each query selects its own eq. (17) branch."""
+    """Sequential DualState with the λ_max branch served from cache — no
+    per-step Xᵀy pass: y/beta (B, ·), lam/lmax (B,), v1max (B, n). Each
+    query selects its own eq. (17) branch."""
     theta_seq = (y - beta @ X.T) / scr._col(lam)
     at_max = lam >= lmax * (1.0 - 1e-12)                 # (B,)
     at_col = scr._col(at_max)
@@ -407,8 +365,9 @@ def _make_state_batched(X, y, beta, lam, lmax, v1max):
 
 @jax.jit
 @jax.named_scope("state")
-def _make_state_fit(y, fitted, beta, lam, lmax, v1max):
-    """`_make_state` with the fitted values Xβ supplied by the caller.
+def _make_state_batched_fit(y, fitted, beta, lam, lmax, v1max):
+    """`_make_state_batched` with the fitted values Xβ (B, n) supplied by
+    the caller.
 
     The path driver computes them from the *reduced bucket* (Xr·β_r — the
     bucket is gathered replicated), so the dual point costs no full-X pass
@@ -416,23 +375,6 @@ def _make_state_fit(y, fitted, beta, lam, lmax, v1max):
     runs: a column-sharded X·β would psum partial fits in a shard-count-
     dependent order, flipping last-bit mask decisions (docs/distributed.md
     exactness contract)."""
-    theta_seq = (y - fitted) / lam
-    at_max = lam >= lmax * (1.0 - 1e-12)
-    theta = jnp.where(at_max, y / lmax, theta_seq)
-    v1 = jnp.where(at_max, v1max, y / lam - theta_seq)
-    return scr.DualState(
-        theta=theta,
-        lam=jnp.where(at_max, lmax, jnp.asarray(lam, y.dtype)),
-        v1=v1,
-        at_lmax=jnp.asarray(at_max),
-        beta_l1=jnp.where(at_max, 0.0, jnp.sum(jnp.abs(beta))),
-    )
-
-
-@jax.jit
-@jax.named_scope("state")
-def _make_state_batched_fit(y, fitted, beta, lam, lmax, v1max):
-    """Batched `_make_state_fit`: y/fitted (B, n), beta (B, p), lam (B,)."""
     theta_seq = (y - fitted) / scr._col(lam)
     at_max = lam >= lmax * (1.0 - 1e-12)                 # (B,)
     at_col = scr._col(at_max)
@@ -449,34 +391,10 @@ def _make_state_batched_fit(y, fitted, beta, lam, lmax, v1max):
 
 @jax.jit
 @jax.named_scope("state")
-def _make_group_state(X, y, beta, lam, lmax, theta_max, v1max):
-    theta_seq = (y - X @ beta) / lam
-    at_max = lam >= lmax * (1.0 - 1e-12)
-    return gscr.GroupDualState(
-        theta=jnp.where(at_max, theta_max, theta_seq),
-        lam=jnp.where(at_max, lmax, jnp.asarray(lam, X.dtype)),
-        v1=jnp.where(at_max, v1max, y / lam - theta_seq),
-    )
-
-
-@jax.jit
-@jax.named_scope("state")
-def _make_group_state_fit(y, fitted, beta, lam, lmax, theta_max, v1max):
-    """`_make_group_state` from caller-supplied fitted values Xβ."""
-    theta_seq = (y - fitted) / lam
-    at_max = lam >= lmax * (1.0 - 1e-12)
-    return gscr.GroupDualState(
-        theta=jnp.where(at_max, theta_max, theta_seq),
-        lam=jnp.where(at_max, lmax, jnp.asarray(lam, y.dtype)),
-        v1=jnp.where(at_max, v1max, y / lam - theta_seq),
-    )
-
-
-@jax.jit
-@jax.named_scope("state")
 def _make_group_state_batched_fit(y, fitted, lam, lmax, theta_max, v1max):
-    """Batched `_make_group_state_fit`: y/fitted (B, n), lam/lmax (B,).
-    Each query selects its own λ̄_max branch."""
+    """Sequential group dual state from caller-supplied fitted values Xβ:
+    y/fitted (B, n), lam/lmax (B,). Each query selects its own λ̄_max
+    branch."""
     theta_seq = (y - fitted) / lam[:, None]
     at_max = lam >= lmax * (1.0 - 1e-12)                 # (B,)
     at_col = at_max[:, None]
@@ -548,23 +466,11 @@ def _patch_slots_impl(X, vecs, slots, blk, vec_blocks, lo_dtypes):
 
 @jax.jit
 @jax.named_scope("screen")
-def _stream_fit_single(X, istar, y):
-    """λ_max ray v₁ = sign(x*ᵀy)·x* and the DOME halfspace direction for a
-    single query — the ONE jitted helper both the cold PathWorkspace fit
+def _stream_fit_batched(X, istar, y):
+    """λ_max rays v₁ = sign(x*ᵀy)·x* and the DOME halfspace directions for
+    (B,) argmaxes — the ONE jitted helper both the cold PathWorkspace fit
     and update_workspace (core/update.py) go through, so a carried stream
     is bitwise-identical to a cold one by construction."""
-    acc = jnp.promote_types(X.dtype, jnp.float32)
-    xstar = X[:, istar]
-    sgn = jnp.sign(jnp.vdot(xstar.astype(acc), y.astype(acc)))
-    v1 = sgn * xstar
-    ghat = v1 / (jnp.linalg.norm(v1, axis=-1, keepdims=True) + 1e-30)
-    return v1, ghat
-
-
-@jax.jit
-@jax.named_scope("screen")
-def _stream_fit_batched(X, istar, y):
-    """Batched twin of :func:`_stream_fit_single` — (B,) argmaxes."""
     acc = jnp.promote_types(X.dtype, jnp.float32)
     xstar = X[:, istar].T
     sgn = jnp.sign(jnp.sum(xstar.astype(acc) * y.astype(acc), axis=-1))
@@ -872,44 +778,38 @@ class PathWorkspace:
     norms every sphere test needs); the λ_max ray v₁ = sign(x*ᵀy)·x* and
     ‖y‖ follow in O(n). Nothing here is recomputed per grid step.
 
-    ``y`` may be a (B, n) batch: the SAME single fused pass then fits all
-    B queries (scores (B, p)), and the per-query fields grow a leading
-    batch axis — ``lam_max``/``istar`` (B,), ``v1_at_lmax``/``ghat``
-    (B, n). Pass ``geometry=`` to reuse a prefitted dictionary: setup then
-    costs one batched matvec pass instead of the fused pass.
+    ``y`` is a (B, n) batch (a single query is a batch of one): the SAME
+    single fused pass fits all B queries (scores (B, p)), and every
+    per-query field has a leading batch axis — ``lam_max``/``istar`` (B,),
+    ``v1_at_lmax``/``ghat`` (B, n). Pass ``geometry=`` to reuse a
+    prefitted dictionary: setup then costs one batched matvec pass instead
+    of the fused pass.
     """
 
     def __init__(self, X, y, backend: str | None = None, *,
                  geometry: DictionaryGeometry | None = None):
+        y_arr = jnp.asarray(y)
         if geometry is None:
-            y_arr = jnp.asarray(y)
             backend_r = resolve_backend(backend)
             scores, sumsq = backend_r.fused_scores(jnp.asarray(X), y_arr, 0.0)
             geometry = DictionaryGeometry(X, backend_r, _sumsq=sumsq)
             geometry.fit_passes = 1   # the fused pass above fitted it
         else:
-            y_arr = jnp.asarray(y)
             scores = jnp.abs(geometry.backend.matvec(geometry.X, y_arr))
         geometry.query_passes += 1
         self.geometry = geometry
         self.backend = geometry.backend
         self.y = y_arr
-        self.batch = None if y_arr.ndim == 1 else y_arr.shape[0]
-        self.abs_xty = scores                     # |Xᵀy|, (p,) or (B, p)
-        if self.batch is None:
-            self.istar = int(tracing.fetch(jnp.argmax(scores)))
-            self.lam_max = float(tracing.fetch(scores[self.istar]))
-            # eq. (17) at λ₀ = λ_max, + the DOME halfspace direction
-            self.v1_at_lmax, self.ghat = _stream_fit_single(
-                self.X, jnp.asarray(self.istar, jnp.int32), self.y)
-        else:
-            istar = jnp.argmax(scores, axis=-1)               # (B,)
-            self.istar = tracing.fetch(istar)
-            self.lam_max = tracing.fetch(
-                jnp.take_along_axis(scores, istar[:, None], axis=-1)[:, 0],
-                np.float64)                                   # (B,)
-            self.v1_at_lmax, self.ghat = _stream_fit_batched(
-                self.X, istar, self.y)
+        self.batch, _ = y_arr.shape
+        self.abs_xty = scores                     # |Xᵀy|, (B, p)
+        istar = jnp.argmax(scores, axis=-1)                   # (B,)
+        self.istar = tracing.fetch(istar)
+        self.lam_max = tracing.fetch(
+            jnp.take_along_axis(scores, istar[:, None], axis=-1)[:, 0],
+            np.float64)                                       # (B,)
+        # eq. (17) at λ₀ = λ_max, + the DOME halfspace direction
+        self.v1_at_lmax, self.ghat = _stream_fit_batched(
+            self.X, istar, self.y)
 
     @property
     def X(self) -> jax.Array:
@@ -924,20 +824,12 @@ class PathWorkspace:
         return self.geometry.col_norms
 
     def lam_max_array(self) -> jax.Array:
-        """λ_max as a device array: scalar (single) or (B,) (batched)."""
+        """λ_max (B,) as a device array."""
         return jnp.asarray(self.lam_max, self.X.dtype)
 
     def state_at_lambda_max(self) -> scr.DualState:
         """β* = 0, θ* = y/λ_max (eq. 9) — from cache, no X pass."""
         lmax = self.lam_max_array()
-        if self.batch is None:
-            return scr.DualState(
-                theta=self.y / lmax,
-                lam=lmax,
-                v1=self.v1_at_lmax,
-                at_lmax=jnp.asarray(True),
-                beta_l1=jnp.zeros((), dtype=self.X.dtype),
-            )
         return scr.DualState(
             theta=self.y / scr._col(lmax),
             lam=lmax,
@@ -950,20 +842,20 @@ class PathWorkspace:
 class ScreeningEngine:
     """One entry point for every per-step screen on a Lasso λ-path.
 
-    Usage (what lasso_path does)::
+    The engine holds a batch of queries, always: ``Y`` is (B, n), and a
+    single query is a batch of one. Usage (what the path driver does)::
 
-        eng = ScreeningEngine(X, y)               # one fused pass over X
+        eng = ScreeningEngine(X, Y)               # one fused pass over X
         state = eng.state_at_lambda_max()
-        for lam in grid:
-            discard = eng.screen(lam, state, rule="edpp")   # one X pass
-            ... reduced solve -> beta ...
+        for lam in grids.T:                       # per-query λ (B,)
+            discard = eng.screen(lam, state, rule="edpp")   # (B, p)
+            ... reduced solve -> beta (B, p) ...
             state = eng.make_state(beta, lam)
 
-    Batched (one fitted dictionary, B queries): construct with ``y`` of
-    shape (B, n) — ideally passing a shared prefitted ``geometry=`` — and
-    call ``screen`` with per-query λ (B,) and a batched DualState. Each
-    screen is STILL one streaming pass over X; ``last_x_passes`` counts
-    passes per *batch*, so the per-query cost is ``last_x_passes / B``.
+    Pass a shared prefitted ``geometry=`` to skip the fit pass. Each
+    screen is one streaming pass over X for the whole batch;
+    ``last_x_passes`` counts passes per *batch*, so the per-query cost is
+    ``last_x_passes / B``.
 
     ``last_x_passes`` / ``total_x_passes`` count full HBM passes over X so
     callers (benchmarks, PathStepStats) can report data movement.
@@ -1021,12 +913,12 @@ class ScreeningEngine:
         return False
 
     @property
-    def lam_max(self):
-        """float (single query) or float64 (B,) array (batched)."""
+    def lam_max(self) -> np.ndarray:
+        """float64 (B,): each query's λ_max."""
         return self.ws.lam_max
 
     @property
-    def batch(self) -> int | None:
+    def batch(self) -> int:
         return self.ws.batch
 
     @property
@@ -1041,24 +933,18 @@ class ScreeningEngine:
         return self.ws.state_at_lambda_max()
 
     def make_state(self, beta, lam, *, fitted=None) -> scr.DualState:
-        """Sequential DualState from the solution at λ (KKT eq. 3).
-        Batched: beta (B, p), lam (B,) → batched state, still no X pass.
-        ``fitted`` (= Xβ, shaped like y) skips even the X·β matvec and
-        keeps θ's arithmetic shard-invariant (see `_make_state_fit`)."""
-        if self.ws.batch is not None:
-            lam_b = jnp.asarray(lam, self.ws.X.dtype)
-            if fitted is not None:
-                return _make_state_batched_fit(
-                    self.ws.y, fitted, beta, lam_b,
-                    self.ws.lam_max_array(), self.ws.v1_at_lmax)
-            return _make_state_batched(
-                self.ws.X, self.ws.y, beta, lam_b,
-                self.ws.lam_max_array(), self.ws.v1_at_lmax)
+        """Sequential DualState from the solution at λ (KKT eq. 3): beta
+        (B, p), lam (B,), still no X pass for the λ_max branch.
+        ``fitted`` (= Xβ, (B, n)) skips even the X·β matvec and keeps θ's
+        arithmetic shard-invariant (see `_make_state_batched_fit`)."""
+        lam_b = jnp.asarray(lam, self.ws.X.dtype)
         if fitted is not None:
-            return _make_state_fit(self.ws.y, fitted, beta, lam,
-                                   self.ws.lam_max, self.ws.v1_at_lmax)
-        return _make_state(self.ws.X, self.ws.y, beta, lam,
-                           self.ws.lam_max, self.ws.v1_at_lmax)
+            return _make_state_batched_fit(
+                self.ws.y, fitted, beta, lam_b,
+                self.ws.lam_max_array(), self.ws.v1_at_lmax)
+        return _make_state_batched(
+            self.ws.X, self.ws.y, beta, lam_b,
+            self.ws.lam_max_array(), self.ws.v1_at_lmax)
 
     def _count(self, passes: int, screen_bytes: float | None = None):
         self.n_screens += 1
@@ -1083,9 +969,7 @@ class ScreeningEngine:
         narrow full-precision pass IS the f32 decision. Returns
         (mask, extra_passes, extra_bytes)."""
         ws = self.ws
-        band_np = tracing.fetch(band)
-        cols = np.flatnonzero(
-            band_np if band_np.ndim == 1 else band_np.any(axis=0))
+        cols = np.flatnonzero(tracing.fetch(band).any(axis=0))
         self.last_fallback_cols = int(cols.size)
         if cols.size == 0:
             return dec, 0, 0.0
@@ -1105,7 +989,7 @@ class ScreeningEngine:
         return jnp.asarray(out), 1, float(ws.X.shape[0]) * bucket \
             * ws.X.dtype.itemsize
 
-    def _narrow_sup(self, cand, centre, batched):
+    def _narrow_sup(self, cand, centre):
         """Exact max(1, ‖Xᵀθ₀‖∞) from a narrow f32 gather of the argmax
         candidates (`_gap_cand`): whenever the true sup exceeds 1 — the
         only case any consumer can distinguish, all of them read the value
@@ -1118,17 +1002,13 @@ class ScreeningEngine:
         query's sup, so they never corrupt the max. Returns
         (sup_corr, gather_bytes)."""
         ws = self.ws
-        cand_np = tracing.fetch(cand)
-        cols = np.flatnonzero(
-            cand_np if cand_np.ndim == 1 else cand_np.any(axis=0))
+        cols = np.flatnonzero(tracing.fetch(cand).any(axis=0))
         p = ws.X.shape[1]
         bucket = _narrow_bucket(int(cols.size), p)
         idx = np.zeros((bucket,), dtype=np.int32)
         idx[:cols.size] = cols
         Xn = jnp.take(ws.X, jnp.asarray(idx), axis=1)
-        dot_n = ws.backend.matvec(Xn, centre)
-        sup = (jnp.max(jnp.abs(dot_n), axis=-1) if batched
-               else jnp.max(jnp.abs(dot_n)))
+        sup = jnp.max(jnp.abs(ws.backend.matvec(Xn, centre)), axis=-1)
         return sup, float(ws.X.shape[0]) * bucket * ws.X.dtype.itemsize
 
     def _sphere_screen(self, test: scr.SphereTest, eps_val,
@@ -1157,37 +1037,24 @@ class ScreeningEngine:
 
     def screen(self, lam_next, state: scr.DualState | None,
                rule: str = "edpp") -> jax.Array:
-        """Discard mask for λ_next; dispatches every rule through the
-        backend's streaming matvec with cached column norms. Single query:
-        scalar λ → bool[p]. Batched: λ (B,) → bool[B, p], one X pass for
-        the whole batch."""
+        """Discard mask bool[B, p] for per-query λ_next (B,); dispatches
+        every rule through the backend's streaming matvec with cached
+        column norms, one X pass for the whole batch."""
         ws = self.ws
-        batched = ws.batch is not None
         self.last_effective_dtype = "float32"
-        if batched:
-            lam_next = jnp.asarray(lam_next, ws.X.dtype)
+        lam_next = jnp.asarray(lam_next, ws.X.dtype)
         if rule == "none":
             self._count(0, 0.0)
-            shape = (ws.X.shape[1],) if not batched else (ws.batch,
-                                                          ws.X.shape[1])
-            return jnp.zeros(shape, dtype=bool)
+            return jnp.zeros((ws.batch, ws.X.shape[1]), dtype=bool)
         if rule == "safe":
-            lmax = ws.lam_max_array() if batched else ws.lam_max
-            test = scr.safe_sphere(ws.y, lam_next, lmax)
+            test = scr.safe_sphere(ws.y, lam_next, ws.lam_max_array())
             # eq. 15's eps margin is at λ scale: eps/λ once unit-normalised
             return self._sphere_screen(test, self.eps / lam_next, rule)
         if rule == "dome":
-            if batched:
-                lmax = ws.lam_max_array()
-                c = ws.y / scr._col(lam_next)
-                rho = jnp.linalg.norm(ws.y, axis=-1) * (
-                    1.0 / lam_next - 1.0 / lmax)
-                gnorm = jnp.linalg.norm(ws.v1_at_lmax, axis=-1) + 1e-30
-            else:
-                c = ws.y / lam_next
-                rho = jnp.linalg.norm(ws.y) * (
-                    1.0 / lam_next - 1.0 / ws.lam_max)
-                gnorm = jnp.linalg.norm(ws.v1_at_lmax) + 1e-30
+            c = ws.y / scr._col(lam_next)
+            rho = jnp.linalg.norm(ws.y, axis=-1) * (
+                1.0 / lam_next - 1.0 / ws.lam_max_array())
+            gnorm = jnp.linalg.norm(ws.v1_at_lmax, axis=-1) + 1e-30
             b_cut = 1.0 / gnorm
 
             def keep_istar(dec):
@@ -1197,16 +1064,14 @@ class ScreeningEngine:
                 # threshold there and f32 rounding could evict the
                 # λ_max-attaining feature. Pin it kept — mirrors
                 # scr.dome_mask so engine and oracle masks stay identical.
-                if batched:
-                    return dec & (jnp.arange(ws.X.shape[1])[None, :]
-                                  != jnp.asarray(ws.istar)[:, None])
-                return dec.at[ws.istar].set(False)
+                return dec & (jnp.arange(ws.X.shape[1])[None, :]
+                              != jnp.asarray(ws.istar)[:, None])
 
             if self._use_bf16(rule):
                 # both directions ride ONE stacked bf16 pass (the f32 dome
                 # spends two passes), bounded per piece by the margins
                 dot_c, gdot, stacked = self._stacked_matvec(
-                    self._x_fast, c, batched)
+                    self._x_fast, c)
                 e_c = ops.bf16_score_margin(
                     self._x_fast_err, jnp.linalg.norm(c, axis=-1))
                 e_g = ops.bf16_score_margin(
@@ -1217,7 +1082,7 @@ class ScreeningEngine:
 
                 def recompute(Xn, idx_dev):
                     dc, dg = self._split_stacked(
-                        ws.backend.matvec(Xn, stacked), batched)
+                        ws.backend.matvec(Xn, stacked))
                     return _dome_combine(
                         dc, dg, jnp.take(ws.col_norms, idx_dev), c, rho,
                         ws.ghat, b_cut, self.eps)
@@ -1232,8 +1097,7 @@ class ScreeningEngine:
             return keep_istar(_dome_combine(scores_c, gdot, ws.col_norms, c,
                                             rho, ws.ghat, b_cut, self.eps))
         if rule == "strong":
-            theta_lam = (state.theta * scr._col(state.lam) if batched
-                         else state.theta * state.lam)
+            theta_lam = state.theta * scr._col(state.lam)
             if not self._use_bf16(rule):
                 dot = ws.backend.matvec(ws.X, theta_lam)
                 self._count(1)
@@ -1265,7 +1129,7 @@ class ScreeningEngine:
             # stage 1: exact feasibility rescale from the tiny candidate
             # gather, so u and ρ in the margin combine are exact scalars
             sup_corr, sup_bytes = self._narrow_sup(
-                _gap_cand(dot, margin), state.theta, batched)
+                _gap_cand(dot, margin), state.theta)
             dec, band = _gap_combine_margin(dot, margin, sup_corr, ws.y,
                                             lam_next, state, ws.col_norms,
                                             self.eps)
@@ -1284,7 +1148,7 @@ class ScreeningEngine:
             self._count(2, self._fast_bytes() + sup_bytes + narrow_bytes)
             return dec
         if rule.endswith("_cut") and rule[:-4] in scr.SPHERE_RULES:
-            return self._cut_screen(rule[:-4], lam_next, state, batched)
+            return self._cut_screen(rule[:-4], lam_next, state)
         if rule not in scr.SPHERE_RULES:
             raise ValueError(
                 f"unknown screening rule {rule!r}; available: "
@@ -1292,32 +1156,26 @@ class ScreeningEngine:
         test = scr.make_sphere(rule, ws.y, lam_next, state)
         return self._sphere_screen(test, self.eps, rule)
 
-    def _stacked_matvec(self, X_src, centre, batched: bool):
+    def _stacked_matvec(self, X_src, centre):
         """[centre; ĝ] through ONE streaming matvec against ``X_src``.
         Returns (dot_c, gdot, stacked) — ``stacked`` so narrow fallbacks
         can replay the identical operand against gathered f32 columns."""
         ws = self.ws
-        if batched:
-            # stack-then-reshape, NOT concatenate: jnp.concatenate along a
-            # query-sharded axis miscomputes on multi-device meshes
-            # (observed on jax 0.4.37 host platforms); the (2, B, n) stack
-            # keeps the sharded axis intact and reshapes to the same
-            # [centre-rows; ghat-rows] layout.
-            stacked = jnp.stack([centre, ws.ghat]).reshape(
-                2 * ws.batch, centre.shape[-1])                   # (2B, n)
-            dot = ws.backend.matvec(X_src, stacked)
-            return dot[:ws.batch], dot[ws.batch:], stacked
-        stacked = jnp.stack([centre, ws.ghat])                    # (2, n)
-        dot = ws.backend.matvec(X_src, stacked)
-        return dot[0], dot[1], stacked
+        # stack-then-reshape, NOT concatenate: jnp.concatenate along a
+        # query-sharded axis miscomputes on multi-device meshes (observed
+        # on jax 0.4.37 host platforms); the (2, B, n) stack keeps the
+        # sharded axis intact and reshapes to the same [centre-rows;
+        # ghat-rows] layout.
+        stacked = jnp.stack([centre, ws.ghat]).reshape(
+            2 * ws.batch, centre.shape[-1])                       # (2B, n)
+        dc, dg = self._split_stacked(ws.backend.matvec(X_src, stacked))
+        return dc, dg, stacked
 
-    def _split_stacked(self, dot, batched: bool):
-        if batched:
-            return dot[:self.ws.batch], dot[self.ws.batch:]
-        return dot[0], dot[1]
+    def _split_stacked(self, dot):
+        return dot[:self.ws.batch], dot[self.ws.batch:]
 
-    def _cut_screen(self, base: str, lam_next, state: scr.DualState,
-                    batched: bool) -> jax.Array:
+    def _cut_screen(self, base: str, lam_next,
+                    state: scr.DualState) -> jax.Array:
         """``<base>_cut``: the base rule's sphere ∩ the λ_max feasibility
         cut, in ONE streaming pass — the cut normal ĝ (cached in the
         workspace since the fit) is stacked with the sphere centre into a
@@ -1337,7 +1195,7 @@ class ScreeningEngine:
             centre = test.centre
         fast = self._use_bf16(base + "_cut")
         dot_c, gdot, stacked = self._stacked_matvec(
-            self._x_fast if fast else ws.X, centre, batched)
+            self._x_fast if fast else ws.X, centre)
         if not fast:
             self._count(1)
             if base == "gap":
@@ -1356,7 +1214,7 @@ class ScreeningEngine:
             # the tiny candidate gather collapses u, ρ and t_b to exact
             # scalars before the piecewise bounds run
             sup_corr, sup_bytes = self._narrow_sup(
-                _gap_cand(dot_c, e_c), centre, batched)
+                _gap_cand(dot_c, e_c), centre)
             dec, band = _gap_cut_combine_margin(
                 dot_c, gdot, e_c, e_g, sup_corr, ws.y, lam_next, state,
                 ws.col_norms, ws.ghat, b_cut, self.eps)
@@ -1366,8 +1224,7 @@ class ScreeningEngine:
                 ws.ghat, b_cut, self.eps)
 
         def recompute(Xn, idx_dev):
-            dc, dg = self._split_stacked(ws.backend.matvec(Xn, stacked),
-                                         batched)
+            dc, dg = self._split_stacked(ws.backend.matvec(Xn, stacked))
             cn = jnp.take(ws.col_norms, idx_dev)
             if base == "gap":
                 return _gap_cut_combine_from(
@@ -1399,9 +1256,10 @@ class GroupScreeningEngine:
     queries — the spectral norms are then served from cache and only the
     per-query ``‖X_gᵀy‖`` pass runs here.
 
-    Batched (one fitted dictionary, B queries): construct with ``y`` of
-    shape (B, n). The fit is one pass over X for the batch and one read of
-    its λ̄_max (B,); ``screen`` takes per-query λ (B,) and a batched
+    The engine holds a batch of queries, always: ``y`` is (B, n), and a
+    single query is a batch of one. The fit is one pass over X for the
+    batch and one read of its λ̄_max (B,); ``screen`` takes per-query
+    λ (B,) and a batched
     :class:`~repro.core.group_screening.GroupDualState` and returns a
     (B, G) mask from ONE pass over X (``last_x_passes`` counts passes per
     batch), as :class:`ScreeningEngine` does for the Lasso.
@@ -1419,28 +1277,17 @@ class GroupScreeningEngine:
         self.y = jnp.asarray(y)
         self.m = m
         self.eps = eps
+        self.batch, _ = self.y.shape
         gscores = self.backend.group_scores(self.X, self.y, m)   # ‖X_gᵀy‖
-        if self.y.ndim == 2:
-            lmax, self.v1_at_lmax = _group_fit_batched(self.X, gscores,
-                                                       self.y, m)
-            self.lam_max = tracing.fetch(lmax, np.float64)       # (B,)
-        else:
-            gnorms = gscores / jnp.sqrt(float(m))
-            gstar = int(tracing.fetch(jnp.argmax(gnorms)))
-            self.lam_max = float(tracing.fetch(gnorms[gstar]))
-            Xstar = jax.lax.dynamic_slice_in_dim(
-                self.X, gstar * m, m, axis=1)                    # (N, m)
-            self.v1_at_lmax = Xstar @ (Xstar.T @ self.y)         # eq. (59)
+        lmax, self.v1_at_lmax = _group_fit_batched(self.X, gscores, self.y,
+                                                   m)
+        self.lam_max = tracing.fetch(lmax, np.float64)           # (B,)
         self.spec_norms = geometry.spec_norms
         self.n_screens = 0
         self.total_x_passes = 0
         self.last_x_passes = 0
         self.total_screen_bytes = 0.0
         self.last_screen_bytes = 0.0
-
-    @property
-    def batch(self) -> int | None:
-        return None if self.y.ndim == 1 else self.y.shape[0]
 
     @property
     def backend_name(self) -> str:
@@ -1455,21 +1302,13 @@ class GroupScreeningEngine:
                                    v1=self.v1_at_lmax)
 
     def make_state(self, beta, lam, *, fitted=None) -> gscr.GroupDualState:
-        """Sequential dual state from the solution at λ (KKT eq. 52).
-        Batched: beta (B, p), lam (B,) and ``fitted`` (B, n), which the
-        batched path always supplies."""
-        if self.batch is not None:
-            lmax = self._lam_max_array()
-            return _make_group_state_batched_fit(
-                self.y, fitted, jnp.asarray(lam, self.X.dtype), lmax,
-                self.y / scr._col(lmax), self.v1_at_lmax)
-        if fitted is not None:
-            return _make_group_state_fit(
-                self.y, fitted, beta, lam, self.lam_max,
-                self.y / self.lam_max, self.v1_at_lmax)
-        return _make_group_state(
-            self.X, self.y, beta, lam, self.lam_max,
-            self.y / self.lam_max, self.v1_at_lmax)
+        """Sequential dual state from the solution at λ (KKT eq. 52):
+        beta (B, p), lam (B,) and ``fitted`` = Xβ (B, n), which the path
+        driver always supplies."""
+        lmax = self._lam_max_array()
+        return _make_group_state_batched_fit(
+            self.y, fitted, jnp.asarray(lam, self.X.dtype), lmax,
+            self.y / scr._col(lmax), self.v1_at_lmax)
 
     def _count(self, passes: int):
         self.n_screens += 1
@@ -1482,16 +1321,14 @@ class GroupScreeningEngine:
 
     def screen(self, lam_next, state: gscr.GroupDualState,
                rule: str = "edpp") -> jax.Array:
-        """Discard mask for λ_next: bool[G] for a scalar λ, bool[B, G] for
-        per-query λ (B,) — one pass over X either way."""
+        """Discard mask bool[B, G] for per-query λ_next (B,) — one pass
+        over X for the whole batch."""
         G = self.X.shape[1] // self.m
         sqm = float(np.sqrt(self.m))
         if rule == "none":
             self._count(0)
-            shape = (G,) if self.batch is None else (self.batch, G)
-            return jnp.zeros(shape, dtype=bool)
-        if self.batch is not None:
-            lam_next = jnp.asarray(lam_next, self.X.dtype)
+            return jnp.zeros((self.batch, G), dtype=bool)
+        lam_next = jnp.asarray(lam_next, self.X.dtype)
         if rule == "strong":
             gscores = self.backend.group_scores(
                 self.X, state.theta * scr._col(state.lam), self.m)

@@ -31,14 +31,15 @@ Engineering notes
   shapes. We gather surviving columns (whole groups for m > 1) into
   power-of-two **buckets** (zero padded); solvers treat zero columns as fixed
   points, and jit compiles at most O(log p) program variants per path.
-* **Batched multi-query paths** (``lasso_path_batched``): one fitted
-  dictionary, B response vectors through the whole loop. Per grid step the
-  engine screens all B queries in ONE fused pass over X, the survivors are
-  **union-bucketed** into a shared buffer, and a single batched solve runs
-  with per-query λ, per-query validity masks and per-query convergence
-  freezing inside the solver ``lax.while_loop`` (converged queries become
-  fixed points — counted in ``PathStepStats.queries_converged``). Queries in
-  their trivial region (λ ≥ own λ_max) stay at β = 0. Program variants stay
+* **One query shape.** One fitted dictionary, B response vectors through
+  the whole loop; a single query is a batch of one (the session promotes
+  y (n,) to (1, n) at its edge). Per grid step the engine screens all B
+  queries in ONE fused pass over X, the survivors are **union-bucketed**
+  into a shared buffer, and a single solve runs with per-query λ,
+  per-query validity masks and per-query convergence freezing inside the
+  solver ``lax.while_loop`` (converged queries become fixed points —
+  counted in ``PathStepStats.queries_converged``). Queries in their
+  trivial region (λ ≥ own λ_max) stay at β = 0. Program variants stay
   O(log p) per batch shape (buckets are pow-2, B is fixed per call), and
   screen HBM cost is amortised ~1/B per query
   (``PathStepStats.x_passes_per_query``).
@@ -126,8 +127,8 @@ class PathResult:
     """The ONE path result type, single- and multi-query alike.
 
     :meth:`LassoSession.path <repro.core.session.LassoSession.path>` always
-    returns the batched layout — a leading batch axis on every array, B = 1
-    for a single query — so callers never branch on a second result class:
+    returns a leading batch axis on every array, B = 1 for a single query,
+    so callers never branch on a second result class:
 
         lambdas  (B, K)        per-query λ grids
         betas    (B, K, p)     per-query coefficient paths
@@ -211,8 +212,8 @@ def _step_epilogue(beta_r, iters, gap, converged, Xr, idx, valid, p: int):
     """What follows a reduced solve, as one program keyed on (B, bucket,
     n, p, dtype) alone, never on the kept count.
 
-    ``beta_r`` is the solve's (b,) or (B, b) β on the bucket, ``iters`` /
-    ``gap`` / ``converged`` its scalar or (B,) telemetry, ``idx``/``valid``
+    ``beta_r`` is the solve's (B, b) β on the bucket, ``iters`` / ``gap``
+    / ``converged`` its (B,) telemetry, ``idx``/``valid``
     the padded bucket indices of :func:`_pad_indices`. Returns
     ``beta_full`` (B, p) (β scattered back to p), ``fitted`` (B, n) = Xr·β_r
     and the packed summary (B, b + 3): β_r, then iters, gap and converged
@@ -249,9 +250,17 @@ def lambda_grid(lam_max: float, num: int = 100, lo_frac: float = 0.05,
 
 def _path_driver(X, Y, lambdas, cfg, *, m: int, screen_engine,
                  solver_engine: SolverEngine, need_kkt: bool,
-                 kkt_fn, batch: int | None = None, reshard=None,
-                 lo_gather=None):
-    """The shared screen → reduce → solve → KKT loop over a decreasing grid.
+                 kkt_fn, reshard=None, lo_gather=None):
+    """The screen → reduce → solve → KKT loop over decreasing grids, for B
+    queries against one fitted dictionary.
+
+    ``Y`` is (B, n) and ``lambdas`` the per-query grids (B, K); a single
+    query is a batch of one. Per step: one fused screen over X for the
+    whole batch, survivors UNION-bucketed into a shared buffer, one solve
+    with per-query validity masks and convergence freezing, per-query KKT
+    re-check rounds, and per-query trivial-region handling (a query whose
+    λ ≥ its own λ_max stays at β = 0 and screens everything).
+    ``cfg.checkpoint_fn`` is called per step with (k, λ (B,), β (B, p)).
 
     ``m`` is the unit size: 1 for the Lasso (units = features), the group
     size for the group Lasso (units = groups; whole groups are gathered).
@@ -272,24 +281,13 @@ def _path_driver(X, Y, lambdas, cfg, *, m: int, screen_engine,
     solver's certified bf16 phase needs (docs/solvers.md). The driver
     threads it as ``lo=`` into every reduced solve so the session-level
     copy is fitted once and shared with the bf16 screen path.
-
-    ``batch``: None runs the classic single-query path (Y (n,), lambdas
-    (K,), engine called with scalar λ). batch=B runs B queries against one
-    fitted dictionary END-TO-END: Y (B, n), per-query grids (B, K), one
-    fused screen per step for the whole batch, survivors UNION-bucketed
-    into a shared buffer, a single batched solve with per-query validity
-    masks and convergence freezing (``solve_batched``), per-query KKT
-    re-check rounds, and per-query trivial-region handling (a query whose
-    λ ≥ its own λ_max stays at β = 0 and screens everything). Internally
-    everything is (B, ·)-shaped with B = 1 for the single-query case, so
-    both modes share one loop.
     """
     X = jnp.asarray(X)
     Y = jnp.asarray(Y)
     p = X.shape[1]
     units = p // m
     assert units * m == p
-    B = 1 if batch is None else batch
+    B = Y.shape[0]
     bucket_min = cfg.bucket_min if cfg.bucket_min is not None \
         else (32 if m == 1 else 16)
     # hybrid safe+strong (Zeng et al. 2017): OR the heuristic strong-rule
@@ -297,19 +295,13 @@ def _path_driver(X, Y, lambdas, cfg, *, m: int, screen_engine,
     hybrid = bool(getattr(cfg, "hybrid_strong", False)) \
         and cfg.rule not in ("strong", "none")
     lambdas = np.asarray(lambdas, dtype=np.float64)
-    if batch is None:
-        assert np.all(np.diff(lambdas) <= 1e-12), "grid must be decreasing"
-        K = lambdas.shape[0]
-    else:
-        assert lambdas.ndim == 2 and lambdas.shape[0] == B, \
-            "batched grids must be (B, K)"
-        assert np.all(np.diff(lambdas, axis=1) <= 1e-12), \
-            "grids must be decreasing"
-        K = lambdas.shape[1]
+    assert lambdas.shape[0] == B, "grids must be (B, K)"
+    assert np.all(np.diff(lambdas, axis=1) <= 1e-12), \
+        "grids must be decreasing"
+    K = lambdas.shape[1]
 
     with tracing.span("path.prologue"):
-        lmax = np.atleast_1d(np.asarray(screen_engine.lam_max,
-                                        dtype=np.float64))      # (B,)
+        lmax = np.asarray(screen_engine.lam_max, dtype=np.float64)  # (B,)
         state = screen_engine.state_at_lambda_max()
         arange_m = np.arange(m)[None, :]
         geo_version = int(getattr(getattr(screen_engine, "geometry", None),
@@ -327,7 +319,7 @@ def _path_driver(X, Y, lambdas, cfg, *, m: int, screen_engine,
     for k in range(K):
         with tracing.step(k) as step:
             steps.append(step)
-            lam_vec = lambdas[None, k] if batch is None else lambdas[:, k]
+            lam_vec = lambdas[:, k]
             live = lam_vec < lmax          # per-query trivial region (eq. 8)
             if not live.any():             # β* = 0 for the whole batch
                 stats.append(PathStepStats(
@@ -335,17 +327,13 @@ def _path_driver(X, Y, lambdas, cfg, *, m: int, screen_engine,
                     batch_size=B, queries_converged=B,
                     geometry_version=geo_version))
                 if cfg.checkpoint_fn:
-                    if batch is None:
-                        cfg.checkpoint_fn(k, float(lam_vec[0]), np.zeros((p,)))
-                    else:
-                        cfg.checkpoint_fn(k, lam_vec, np.zeros((B, p)))
+                    cfg.checkpoint_fn(k, lam_vec, np.zeros((B, p)))
                 continue
 
             # ---- screen (one fused kernel pass over X for ALL queries) --
             with tracing.span("path.screen"):
                 t0 = time.perf_counter()
-                lam_dev = (float(lam_vec[0]) if batch is None
-                           else jnp.asarray(lam_vec, X.dtype))
+                lam_dev = jnp.asarray(lam_vec, X.dtype)
                 discard = screen_engine.screen(lam_dev, state, rule=cfg.rule)
                 screen_passes = screen_engine.last_x_passes
                 screen_dtype_eff = getattr(screen_engine,
@@ -355,8 +343,6 @@ def _path_driver(X, Y, lambdas, cfg, *, m: int, screen_engine,
                                                              rule="strong")
                     screen_passes += screen_engine.last_x_passes
                 discard_np = tracing.fetch(discard)
-                if batch is None:
-                    discard_np = discard_np[None, :]
                 # dead queries keep nothing
                 discard_np = discard_np | ~live[:, None]
                 screen_time = time.perf_counter() - t0
@@ -394,26 +380,18 @@ def _path_driver(X, Y, lambdas, cfg, *, m: int, screen_engine,
                                 lo = lo_gather(idx, valid, bucket * m)
                                 if reshard is not None:
                                     lo = (reshard(lo[0]),) + tuple(lo[1:])
-                            if batch is None:
-                                beta0 = jnp.take(beta_prev[0], idx) * valid
-                            else:
-                                # per-query validity on the union buffer: each
-                                # query solves exactly its own reduced problem
-                                kept_q = np.repeat(~discard_np[:, kept], m,
-                                                   axis=1)
-                                vq_np = np.zeros((B, bucket * m),
-                                                 dtype=np.float32)
-                                vq_np[:, : col_idx.size] = kept_q
-                                vq = jnp.asarray(vq_np)
-                                beta0 = jnp.take(beta_prev, idx, axis=1) * vq
+                            # per-query validity on the union buffer: each
+                            # query solves exactly its own reduced problem
+                            kept_q = np.repeat(~discard_np[:, kept], m,
+                                               axis=1)
+                            vq_np = np.zeros((B, bucket * m),
+                                             dtype=np.float32)
+                            vq_np[:, : col_idx.size] = kept_q
+                            vq = jnp.asarray(vq_np)
+                            beta0 = jnp.take(beta_prev, idx, axis=1) * vq
                         gather_time += gather.seconds
-                        if batch is None:
-                            res = solver_engine.solve(Xr, float(lam_vec[0]),
-                                                      beta0, m=m, lo=lo)
-                        else:
-                            res = solver_engine.solve_batched(
-                                Xr, jnp.asarray(lam_vec, X.dtype), beta0,
-                                valid=vq, m=m, lo=lo)
+                        res = solver_engine.solve(Xr, lam_dev, beta0,
+                                                  valid=vq, m=m, lo=lo)
                         # β back to p and the fitted values Xr·β_r, from the
                         # reduced bucket (replicated, shard-invariant: they
                         # feed KKT and the next dual state), then ONE read
@@ -442,15 +420,9 @@ def _path_driver(X, Y, lambdas, cfg, *, m: int, screen_engine,
                     if not need_kkt:
                         break
                     with tracing.span("path.kkt"):
-                        if batch is None:
-                            viol = tracing.fetch(kkt_fn(
-                                beta_full[0], float(lam_vec[0]),
-                                jnp.asarray(discard_np[0]),
-                                fitted[0]))[None, :]
-                        else:
-                            viol = tracing.fetch(kkt_fn(
-                                beta_full, jnp.asarray(lam_vec, X.dtype),
-                                jnp.asarray(discard_np), fitted))
+                        viol = tracing.fetch(kkt_fn(
+                            beta_full, lam_dev, jnp.asarray(discard_np),
+                            fitted))
                     viol = viol & live[:, None]
                     if not viol.any() or kkt_rounds >= cfg.max_kkt_rounds:
                         break
@@ -467,8 +439,7 @@ def _path_driver(X, Y, lambdas, cfg, *, m: int, screen_engine,
             # a dead (trivial-region) query's lane is vacuously converged
             q_converged &= conv_vec | ~live
             stats.append(PathStepStats(
-                lam=(float(lam_vec[0]) if batch is None
-                     else float(lam_vec.max())),
+                lam=float(lam_vec.max()),
                 n_discarded=int(discard_np.all(axis=0).sum()),
                 n_kept=int(kept.size),
                 solver_iters=res_iters, gap=res_gap, kkt_rounds=kkt_rounds,
@@ -492,22 +463,13 @@ def _path_driver(X, Y, lambdas, cfg, *, m: int, screen_engine,
                 member_major_solves=member_major,
             ))
             if cfg.checkpoint_fn:
-                if batch is None:
-                    cfg.checkpoint_fn(k, float(lam_vec[0]), betas[0, k])
-                else:
-                    cfg.checkpoint_fn(k, lam_vec, betas[:, k])
+                cfg.checkpoint_fn(k, lam_vec, betas[:, k])
 
             beta_prev = beta_full
             if cfg.sequential:
                 with tracing.span("path.state") as state_span:
-                    if batch is None:
-                        state = screen_engine.make_state(beta_full[0],
-                                                         float(lam_vec[0]),
-                                                         fitted=fitted[0])
-                    else:
-                        state = screen_engine.make_state(
-                            beta_full, jnp.asarray(lam_vec, X.dtype),
-                            fitted=fitted)
+                    state = screen_engine.make_state(beta_full, lam_dev,
+                                                     fitted=fitted)
                 stats[-1].state_time_s = state_span.seconds
             # basic variants keep `state` pinned at λmax (paper §4.1.1)
 
@@ -515,10 +477,6 @@ def _path_driver(X, Y, lambdas, cfg, *, m: int, screen_engine,
         st.host_syncs, st.host_sync_s = step.host_syncs, step.host_sync_s
         st.compiles, st.step_time_s = step.compiles, step.seconds
 
-    # Unified result: the leading batch axis is ALWAYS present (B = 1 for a
-    # single query — the values are bit-identical to the squeezed layout).
-    if batch is None:
-        lambdas = lambdas[None, :]
     return PathResult(lambdas=lambdas, betas=betas, stats=stats, masks=masks,
                       query_converged=q_converged)
 
@@ -567,10 +525,9 @@ def lasso_path_batched(X, Y, lambdas=None, cfg=None, *,
     """
     from .session import LassoSession
     _deprecated("lasso_path_batched", "LassoSession.fit(X).path(Y)")
-    Y = jnp.asarray(Y)
-    assert Y.ndim == 2, "lasso_path_batched needs Y of shape (B, n)"
     sess = LassoSession.fit(X, config=cfg, geometry=geometry)
-    return sess.path(Y, lambdas, num_lambdas=num_lambdas, lo_frac=lo_frac)
+    return sess.path(jnp.asarray(Y), lambdas, num_lambdas=num_lambdas,
+                     lo_frac=lo_frac)
 
 
 def group_lasso_path(X, y, m: int, lambdas, cfg=None) -> PathResult:

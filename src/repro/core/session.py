@@ -13,14 +13,16 @@ and re-plumb that state. This module is the redesign:
     sess = LassoSession.fit(X, config=PathConfig(
         screen=ScreenSpec(rule="edpp"),
         solve=SolveSpec(strategy="fista", tol=1e-8)))
-    res  = sess.path(y)         # (n,)   -> single-query path, B = 1
-    res  = sess.path(Y)         # (B, n) -> batched multi-query path
+    res  = sess.path(y)         # (n,)   -> a batch of one, B = 1
+    res  = sess.path(Y)         # (B, n) -> B queries in one path run
     one  = res.squeeze()        # drop the batch axis of a B = 1 result
 
-Dispatch is purely structural — input rank picks single vs batched,
-``fit(..., groups=m)`` picks the group drivers, ``fit(..., mesh=mesh)``
-places the dictionary column-sharded over the mesh's feature axes (a 2D
-``Mesh(('query', 'feature'))`` additionally shards query batches) and
+Every query runs one shape, (B, ·): ``path`` promotes a single y (n,) to
+(1, n) at its edge, and every layer below it sees a batch. Dispatch is
+purely structural — ``fit(..., groups=m)`` picks the group engines,
+``fit(..., mesh=mesh)`` places the dictionary column-sharded over the
+mesh's feature axes (a 2D ``Mesh(('query', 'feature'))`` additionally
+shards query batches) and
 resolves the screen backend to the PER-SHARD dispatcher
 :func:`repro.core.distributed.sharded_backend` — the same Pallas/jnp tile
 kernels as the single-chip engines, run on each local block under
@@ -391,8 +393,8 @@ class LassoSession:
     Construct with :meth:`fit` (the ``__init__`` is not public API)::
 
         sess = LassoSession.fit(X)                  # fused fit pass, ONCE
-        res  = sess.path(y, lambdas)                # single query
-        res  = sess.path(Y)                         # (B, n): batched
+        res  = sess.path(y, lambdas)                # one query: B = 1
+        res  = sess.path(Y)                         # (B, n): B queries
         grp  = LassoSession.fit(X, groups=m)        # group Lasso
         dist = LassoSession.fit(X, mesh=mesh)       # column-sharded X
 
@@ -589,26 +591,26 @@ class LassoSession:
              config: PathConfig | None = None) -> PathResult:
         """Solve the λ-path(s) for one query or a batch, with screening.
 
-        Dispatch is structural: ``Y`` of shape (n,) runs the single-query
-        driver, (B, n) the batched driver (one fused screen over X per
-        grid step for the whole batch); a session fitted with ``groups=m``
-        uses the group drivers; a session fitted with ``mesh`` runs on the
-        placed (column-sharded) dictionary.
+        ``Y`` is one query (n,) or a batch (B, n). This is the only place
+        that knows the difference: a single query is promoted to a batch
+        of one, (1, n), and runs the same driver as any batch (one fused
+        screen over X per grid step for the whole batch). A session fitted
+        with ``groups=m`` uses the group engines; a session fitted with
+        ``mesh`` runs on the placed (column-sharded) dictionary.
 
         ``lambdas`` is a decreasing grid — (K,) shared, (B, K) per-query —
         or None for the paper's grid over each query's own λ_max
         (``lambda_grid(λ_max, num_lambdas, lo_frac, hi_frac)``). Returns
         the unified :class:`PathResult`, leading batch axis always present
-        (B = 1 for a single query; ``squeeze()`` drops it).
+        (B = 1 for a single query; ``squeeze()`` drops it). For a single
+        query ``config.checkpoint_fn`` is called as ``(k, float λ, β (p,))``,
+        for a batch as ``(k, λ (B,), β (B, p))``.
         """
         cfg = config if config is not None else self.config
         if not isinstance(cfg, PathConfig):
             raise TypeError(f"config must be a PathConfig, got "
                             f"{type(cfg).__name__}")
         Y = jnp.asarray(Y)
-        if self.mesh is not None:
-            from . import distributed as dist
-            Y = dist.place_queries(self.mesh, Y)
         if Y.ndim not in (1, 2):
             raise ValueError(
                 f"queries must be (n,) or (B, n), got shape {Y.shape}")
@@ -616,15 +618,19 @@ class LassoSession:
             raise ValueError(
                 f"query length {Y.shape[-1]} != dictionary rows "
                 f"{self.X.shape[0]}")
+        if Y.ndim == 1:
+            Y = Y[None, :]
+            if cfg.checkpoint_fn is not None:
+                cfg = PathConfig(cfg.screen, cfg.solve,
+                                 _single_query_checkpoint(cfg.checkpoint_fn))
+        if self.mesh is not None:
+            from . import distributed as dist
+            Y = dist.place_queries(self.mesh, Y)
         grid_kw = dict(num=num_lambdas, lo_frac=lo_frac, hi_frac=hi_frac)
         if self.groups > 1:
             _check_group_rule(cfg)     # per-call overrides validate too
-            if Y.ndim == 1:
-                return self._group_path(Y, lambdas, cfg, grid_kw)
-            return self._group_path_batched(Y, lambdas, cfg, grid_kw)
-        if Y.ndim == 1:
-            return self._lasso_path(Y, lambdas, cfg, grid_kw)
-        return self._lasso_path_batched(Y, lambdas, cfg, grid_kw)
+            return self._group_path(Y, lambdas, cfg, grid_kw)
+        return self._lasso_path(Y, lambdas, cfg, grid_kw)
 
     def reset_solver_cache(self) -> None:
         """Drop the warm-started per-bucket Lipschitz eigenpairs.
@@ -808,44 +814,12 @@ class LassoSession:
         hybrid = cfg.screen.strong and rule not in ("strong", "none")
         return heuristic or hybrid or cfg.screen.paranoid
 
-    def _lasso_path(self, y, lambdas, cfg, grid_kw) -> PathResult:
-        with tracing.span("path.prologue"):
-            eng = ScreeningEngine(self.X, y, eps=cfg.screen.eps,
-                                  geometry=self._geometry(cfg.screen.backend),
-                                  screen_dtype=cfg.screen.screen_dtype)
-            if lambdas is None:
-                lambdas = lambda_grid(float(eng.lam_max), **grid_kw)
-            solver = self._solver_engine(y, cfg)
-        X = self.X
-
-        def kkt_fn(beta_full, lam, discard, fitted=None):
-            return _kkt_violations(X, y, beta_full, lam, discard,
-                                   cfg.screen.kkt_tol, fitted)
-
-        return _path_driver(
-            X, y, lambdas, cfg, m=1, screen_engine=eng,
-            solver_engine=solver, need_kkt=self._need_kkt(cfg),
-            kkt_fn=kkt_fn, reshard=self._reshard(),
-            lo_gather=self._lo_gather(cfg))
-
-    def _lasso_path_batched(self, Y, lambdas, cfg, grid_kw) -> PathResult:
-        B = Y.shape[0]
-        if B == 1:
-            # Degenerate-batch fast path (ISSUE 6 / BENCH_batch.json's 0.2×
-            # at B = 1): with one live query the union-bucketed batched
-            # driver only adds overhead — per-query validity masks, the
-            # batched solver state, the (B, ·) kernel variants — so route
-            # through the single-query driver. The unified PathResult
-            # already carries the B = 1 leading batch axis, and masks are
-            # bit-identical by the batched==single contract
-            # (tests/test_batched_path.py).
-            return self._lasso_path(Y[0], _squeeze_grid(lambdas), cfg,
-                                    grid_kw)
+    def _lasso_path(self, Y, lambdas, cfg, grid_kw) -> PathResult:
         with tracing.span("path.prologue"):
             eng = ScreeningEngine(self.X, Y, eps=cfg.screen.eps,
                                   geometry=self._geometry(cfg.screen.backend),
                                   screen_dtype=cfg.screen.screen_dtype)
-            lambdas = _batch_grids(lambdas, eng.lam_max, B, grid_kw)
+            lambdas = _batch_grids(lambdas, eng.lam_max, grid_kw)
             solver = self._solver_engine(Y, cfg)
         X = self.X
 
@@ -856,45 +830,21 @@ class LassoSession:
         return _path_driver(
             X, Y, lambdas, cfg, m=1, screen_engine=eng,
             solver_engine=solver, need_kkt=self._need_kkt(cfg),
-            kkt_fn=kkt_fn, batch=B, reshard=self._reshard(),
+            kkt_fn=kkt_fn, reshard=self._reshard(),
             lo_gather=self._lo_gather(cfg))
 
-    def _group_path(self, y, lambdas, cfg, grid_kw) -> PathResult:
-        m = self.groups
-        with tracing.span("path.prologue"):
-            eng = GroupScreeningEngine(
-                self.X, y, m, eps=cfg.screen.eps,
-                geometry=self._geometry(cfg.screen.backend))
-            if lambdas is None:
-                lambdas = lambda_grid(float(eng.lam_max), **grid_kw)
-            solver = self._solver_engine(y, cfg)
-        X = self.X
-
-        def kkt_fn(beta_full, lam, discard, fitted=None):
-            return _group_kkt_violations(X, y, beta_full, lam, discard, m,
-                                         cfg.screen.kkt_tol, fitted)
-
-        return _path_driver(
-            X, y, lambdas, cfg, m=m, screen_engine=eng,
-            solver_engine=solver, need_kkt=self._need_kkt(cfg),
-            kkt_fn=kkt_fn, reshard=self._reshard())
-
-    def _group_path_batched(self, Y, lambdas, cfg, grid_kw) -> PathResult:
+    def _group_path(self, Y, lambdas, cfg, grid_kw) -> PathResult:
         """B group paths against one fitted dictionary, batched end to
         end as the Lasso's are: per λ step one group screen over X for the
-        whole batch, the kept groups union-bucketed, one batched
-        block-FISTA solve with per-query validity and convergence
-        freezing, per-query KKT rounds and trivial regions."""
-        B = Y.shape[0]
-        if B == 1:   # degenerate batch: same fast path as the Lasso driver
-            return self._group_path(Y[0], _squeeze_grid(lambdas), cfg,
-                                    grid_kw)
+        whole batch, the kept groups union-bucketed, one block-FISTA solve
+        with per-query validity and convergence freezing, per-query KKT
+        rounds and trivial regions."""
         m = self.groups
         with tracing.span("path.prologue"):
             eng = GroupScreeningEngine(
                 self.X, Y, m, eps=cfg.screen.eps,
                 geometry=self._geometry(cfg.screen.backend))
-            lambdas = _batch_grids(lambdas, eng.lam_max, B, grid_kw)
+            lambdas = _batch_grids(lambdas, eng.lam_max, grid_kw)
             solver = self._solver_engine(Y, cfg)
         X = self.X
 
@@ -905,26 +855,21 @@ class LassoSession:
         return _path_driver(
             X, Y, lambdas, cfg, m=m, screen_engine=eng,
             solver_engine=solver, need_kkt=self._need_kkt(cfg),
-            kkt_fn=kkt_fn, batch=B, reshard=self._reshard())
+            kkt_fn=kkt_fn, reshard=self._reshard())
 
 
-def _batch_grids(lambdas, lam_max, B: int, grid_kw) -> np.ndarray:
-    """The (B, K) grids of a batched path: the paper's grid over each
-    query's own λ_max when ``lambdas`` is None, a shared (K,) grid
-    broadcast, or per-query (B, K) grids as given."""
+def _batch_grids(lambdas, lam_max, grid_kw) -> np.ndarray:
+    """The (B, K) grids of a path: the paper's grid over each query's own
+    λ_max (B,) when ``lambdas`` is None, a shared (K,) grid broadcast, or
+    per-query (B, K) grids as given."""
     if lambdas is None:
         return np.stack([lambda_grid(float(lm), **grid_kw)
-                         for lm in np.atleast_1d(lam_max)])
+                         for lm in lam_max])
     lambdas = np.asarray(lambdas, dtype=np.float64)
-    if lambdas.ndim == 1:
-        lambdas = np.broadcast_to(lambdas, (B, lambdas.shape[0])).copy()
-    return lambdas
+    return np.broadcast_to(lambdas, (len(lam_max), lambdas.shape[-1])).copy()
 
 
-def _squeeze_grid(lambdas):
-    """A (1, K) per-query grid viewed as the single-query (K,) grid the
-    fast-path drivers take ((K,) and None pass through)."""
-    if lambdas is None:
-        return None
-    lam = np.asarray(lambdas, dtype=np.float64)
-    return lam[0] if lam.ndim == 2 else lam
+def _single_query_checkpoint(fn):
+    """Adapt a checkpoint hook to a single query's call, ``(k, float λ,
+    β (p,))``, from the driver's batch of one, ``(k, λ (1,), β (1, p))``."""
+    return lambda k, lam, beta: fn(k, float(lam[0]), beta[0])

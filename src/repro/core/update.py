@@ -65,7 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .engine import _stream_fit_batched, _stream_fit_single
+from .engine import _stream_fit_batched
 
 __all__ = [
     "UpdatePlan",
@@ -262,12 +262,6 @@ def _attach_cold(ws):
     scores = jnp.abs(geom.backend.matvec(geom.X, ws.y))
     geom.query_passes += 1
     ws.abs_xty = scores
-    if ws.batch is None:
-        ws.istar = int(jnp.argmax(scores))
-        ws.lam_max = float(scores[ws.istar])
-        ws.v1_at_lmax, ws.ghat = _stream_fit_single(
-            geom.X, jnp.asarray(ws.istar, jnp.int32), ws.y)
-        return
     istar = jnp.argmax(scores, axis=-1)
     ws.istar = np.asarray(istar)
     ws.lam_max = np.asarray(
@@ -341,43 +335,24 @@ def update_workspace(ws, plan: UpdatePlan, X_add=None):
                               scores_add)
     ws.abs_xty = abs_xty
 
-    if ws.batch is None:
-        rescan = bool(plan.dropped(ws.istar))
-        if rescan:
-            istar = int(jnp.argmax(abs_xty))
-        else:
-            istar = int(ws.istar)         # pure recycle: slots don't move
-            if plan.n_add:
-                st = np.asarray(abs_xty[jnp.asarray(touched)])
-                jt = int(touched[int(np.argmax(st))])
-                si = float(abs_xty[istar])
-                # cold argmax breaks ties toward the lower index; a
-                # recycled slot can sit BELOW the surviving argmax, so
-                # the tie goes to whichever position is lower
-                if (float(st.max()) > si
-                        or (float(st.max()) == si and jt < istar)):
-                    istar = jt
-        ws.istar = istar
-        ws.lam_max = float(abs_xty[istar])
-        ws.v1_at_lmax, ws.ghat = _stream_fit_single(
-            geom.X, jnp.asarray(istar, jnp.int32), ws.y)
-    else:
-        scores = np.asarray(abs_xty)
-        B = ws.batch
-        rows = np.arange(B)
-        dropped = plan.dropped(np.asarray(ws.istar))
-        istar = np.where(dropped, 0, np.asarray(ws.istar))
-        if plan.n_add:
-            st = scores[:, touched]
-            jt = touched[st.argmax(axis=-1)]
-            sj = scores[rows, jt]
-            si = scores[rows, istar]
-            take_add = (sj > si) | ((sj == si) & (jt < istar))
-            istar = np.where(take_add, jt, istar)
-        if dropped.any():
-            istar = np.where(dropped, scores.argmax(axis=-1), istar)
-        ws.istar = istar
-        ws.lam_max = scores[rows, istar].astype(np.float64)
-        ws.v1_at_lmax, ws.ghat = _stream_fit_batched(
-            geom.X, jnp.asarray(istar, jnp.int32), ws.y)
+    scores = np.asarray(abs_xty)
+    rows = np.arange(ws.batch)
+    dropped = plan.dropped(np.asarray(ws.istar))
+    istar = np.where(dropped, 0, np.asarray(ws.istar))
+    if plan.n_add:
+        # pure recycle: slots don't move. A cold argmax breaks ties toward
+        # the lower index, and a recycled slot can sit BELOW the surviving
+        # argmax, so a tie goes to whichever position is lower.
+        st = scores[:, touched]
+        jt = touched[st.argmax(axis=-1)]
+        sj = scores[rows, jt]
+        si = scores[rows, istar]
+        take_add = (sj > si) | ((sj == si) & (jt < istar))
+        istar = np.where(take_add, jt, istar)
+    if dropped.any():
+        istar = np.where(dropped, scores.argmax(axis=-1), istar)
+    ws.istar = istar
+    ws.lam_max = scores[rows, istar].astype(np.float64)
+    ws.v1_at_lmax, ws.ghat = _stream_fit_batched(
+        geom.X, jnp.asarray(istar, jnp.int32), ws.y)
     return n_dropped_argmax

@@ -189,7 +189,7 @@ def main(argv=None):
         args.b_max = 16
         # NOTE: keep the default solver tol — at 1e-5 the sequential-rule
         # state (built from the previous step's gap-ε β) drifts enough
-        # between the batched and single drivers to flip mask bits, which
+        # between a full batch and a batch of one to flip mask bits, which
         # would break the bitwise parity gate below
         args.check_masks = 0            # replay every query
         args.mode = "compare"

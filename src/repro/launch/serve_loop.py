@@ -18,8 +18,8 @@ This module turns batch formation into an explicit, testable policy:
   padding     live batches are padded up to the next power of two
               (repeating the last query; padded lanes are dropped), so the
               compiled program set stays O(log p · log B) — and a batch
-              that degenerates to ONE live query dispatches unpadded,
-              which the session routes through its single-query fast path;
+              that degenerates to ONE live query dispatches unpadded: a
+              batch of one, the same computation as ``session.path(y)``;
   pipelining  dispatch is decoupled from completion: up to
               ``max_in_flight`` batches ride concurrently, the loop polls
               handles instead of blocking (no ``jax.block_until_ready``
@@ -294,8 +294,8 @@ class DelayedHandle:
 
 class SessionExecutor:
     """The real executor: one dispatched batch = one ``session.path(Y)``
-    call (the PR 3/5 batched driver; a 1-live batch arrives as (1, n) and
-    takes the session's single-query fast path). The padded host buffer is
+    call (a 1-live batch arrives as (1, n): a batch of one, like every
+    other). The padded host buffer is
     handed to the device via ``jnp.asarray`` and dropped here — the loop
     never retains it (the donated-buffer point). Failures are captured
     into the handle so the loop's isolation path owns recovery."""

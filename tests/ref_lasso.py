@@ -14,15 +14,52 @@ def soft(u, t):
     return np.sign(u) * np.maximum(np.abs(u) - t, 0.0)
 
 
+def _rel_gap(X, y, beta, lam):
+    """Relative duality gap (P − D)/(½‖y‖²) of β, with the residual
+    scaled into the dual polytope."""
+    r = y - X @ beta
+    corr = np.abs(X.T @ r).max()
+    s = min(1.0, lam / (corr + 1e-300))
+    theta = s * r / lam
+    primal = 0.5 * r @ r + lam * np.abs(beta).sum()
+    dual = 0.5 * y @ y - 0.5 * lam**2 * ((theta - y / lam) ** 2).sum()
+    return (primal - dual) / (0.5 * y @ y + 1e-300)
+
+
+def _support_solve(X, y, beta, lam):
+    """The Lasso's KKT equations on β's support and signs, solved exactly:
+    β_A = (X_AᵀX_A)⁻¹(X_Aᵀy − λ·sign(β_A)), zero elsewhere. None when the
+    system is singular or the solution flips a sign (the support or the
+    signs are not yet the optimum's)."""
+    A = np.flatnonzero(beta)
+    if A.size == 0 or A.size > X.shape[0]:
+        return None
+    XA, sA = X[:, A], np.sign(beta[A])
+    try:
+        bA = np.linalg.solve(XA.T @ XA, XA.T @ y - lam * sA)
+    except np.linalg.LinAlgError:
+        return None
+    if np.any(np.sign(bA) != sA):
+        return None
+    out = np.zeros_like(beta)
+    out[A] = bA
+    return out
+
+
 def cd_lasso(X, y, lam, max_epochs=5000, tol=1e-13):
-    """Cyclic coordinate descent to (relative) duality gap ``tol``."""
+    """Cyclic coordinate descent to (relative) duality gap ``tol``.
+
+    CD alone crawls where the support is as large as n and X_A is ill
+    conditioned (n = 8, p = 53, λ = 0.055·λ_max stalls at a gap of 1e-5
+    after 5000 epochs). After each epoch that misses ``tol``, the KKT
+    equations on the iterate's support and signs are solved exactly; the
+    solution is taken only when its own duality gap certifies ``tol``."""
     X = np.asarray(X, np.float64)
     y = np.asarray(y, np.float64)
     n, p = X.shape
     beta = np.zeros(p)
     r = y.copy()
     sq = np.einsum("ij,ij->j", X, X)
-    scale = 0.5 * y @ y + 1e-300
     for _ in range(max_epochs):
         for j in range(p):
             if sq[j] == 0:
@@ -33,14 +70,11 @@ def cd_lasso(X, y, lam, max_epochs=5000, tol=1e-13):
             if bn != bj:
                 r += X[:, j] * (bj - bn)
                 beta[j] = bn
-        # duality gap
-        corr = np.abs(X.T @ r).max()
-        s = min(1.0, lam / (corr + 1e-300))
-        theta = s * r / lam
-        primal = 0.5 * r @ r + lam * np.abs(beta).sum()
-        dual = 0.5 * y @ y - 0.5 * lam**2 * ((theta - y / lam) ** 2).sum()
-        if primal - dual <= tol * scale:
+        if _rel_gap(X, y, beta, lam) <= tol:
             break
+        exact = _support_solve(X, y, beta, lam)
+        if exact is not None and _rel_gap(X, y, exact, lam) <= tol:
+            return exact
     return beta
 
 

@@ -13,7 +13,8 @@ The contract (ISSUE 4 acceptance / docs/serving.md):
   * per-query λ-grids: a query in its trivial region (λ ≥ its own λ_max)
     stays at β = 0 and discards everything;
   * the batched screen costs ONE X pass for the whole batch
-    (``x_passes_per_query`` = 1/B).
+    (``x_passes_per_query`` = 1/B);
+  * a single query (n,) is a batch of one: ``path(y)`` ≡ ``path(y[None])``.
 """
 
 import numpy as np
@@ -47,7 +48,8 @@ def test_batched_screens_match_per_query(backend):
     Yf = jnp.asarray(Y, jnp.float32)
     geom = DictionaryGeometry(Xf, backend)
     eng = ScreeningEngine(Xf, Yf, backend=backend, geometry=geom)
-    singles = [ScreeningEngine(Xf, Yf[b], backend=backend) for b in range(B)]
+    singles = [ScreeningEngine(Xf, Yf[b:b + 1], backend=backend)
+               for b in range(B)]
     state = eng.state_at_lambda_max()
     states = [e.state_at_lambda_max() for e in singles]
     lam_vec = jnp.asarray(eng.lam_max * 0.5, jnp.float32)
@@ -55,8 +57,8 @@ def test_batched_screens_match_per_query(backend):
         got = np.asarray(eng.screen(lam_vec, state, rule))
         assert got.shape == (B, P)
         for b in range(B):
-            want = np.asarray(singles[b].screen(float(lam_vec[b]),
-                                                states[b], rule))
+            want = np.asarray(singles[b].screen(lam_vec[b:b + 1],
+                                                states[b], rule))[0]
             np.testing.assert_array_equal(got[b], want,
                                           err_msg=f"{rule} query {b}")
     # one fused pass for the whole batch
@@ -136,10 +138,10 @@ def test_converged_query_beta_untouched_by_more_iterations():
     lam = jnp.asarray(fracs * lmaxes, jnp.float32)
     short = SolverEngine(Yf, solver="fista", backend="jnp", tol=1e-7,
                          max_iter=300)
-    res_short = short.solve_batched(Xf, lam)
+    res_short = short.solve(Xf, lam)
     longer = SolverEngine(Yf, solver="fista", backend="jnp", tol=1e-7,
                           max_iter=5000)
-    res_long = longer.solve_batched(Xf, lam)
+    res_long = longer.solve(Xf, lam)
     conv = np.asarray(res_short.converged)
     assert conv[:3].all(), "easy queries should converge inside 300 iters"
     assert not conv.all(), "the hard query must still be iterating"
@@ -193,23 +195,25 @@ def test_per_query_grids_scale_with_own_lam_max():
 
 
 # ---------------------------------------------------------------------------
-# solve_batched fallback path for strategies without a batched twin
+# registered strategies: one strategy per solver, over the batch
 # ---------------------------------------------------------------------------
 
 def test_solve_batched_fallback_loops_single_strategy():
+    """A strategy registered under a new name solves the whole batch as
+    the built-in one does."""
     from repro.core import SOLVERS, register_solver
     X, Y = _stream_problem(b=3, seed=13)
     Xf = jnp.asarray(X, jnp.float32)
     Yf = jnp.asarray(Y, jnp.float32)
     lam = jnp.asarray([0.5 * float(lambda_max(Xf, Yf[b]))
                        for b in range(3)], jnp.float32)
-    register_solver("fista_noname", SOLVERS["fista"])   # no batched twin
+    register_solver("fista_noname", SOLVERS["fista"])
     try:
         eng = SolverEngine(Yf, solver="fista_noname", backend="jnp",
                            tol=1e-6, max_iter=20000)
-        res = eng.solve_batched(Xf, lam)
+        res = eng.solve(Xf, lam)
         native = SolverEngine(Yf, solver="fista", backend="jnp", tol=1e-6,
-                              max_iter=20000).solve_batched(Xf, lam)
+                              max_iter=20000).solve(Xf, lam)
         assert res.beta.shape == native.beta.shape
         np.testing.assert_allclose(np.asarray(res.beta),
                                    np.asarray(native.beta), atol=5e-3)
@@ -218,12 +222,9 @@ def test_solve_batched_fallback_loops_single_strategy():
 
 
 def test_fallback_solver_through_strong_rule_path():
-    """Regression: the fallback must solve each query's OWN reduced problem
-    (union-buffer columns a query screened out are zeroed per query) and
-    must not leak the per-bucket Lipschitz cache between differently-masked
-    buffers — a cached eigenvector from another query's mask lies in this
-    query's null space and a warm power iteration would return eig ≈ 0
-    (divergent FISTA step → NaN)."""
+    """A registered strategy under the strong rule's KKT loop solves each
+    query's OWN reduced problem (union-buffer columns a query screened out
+    are pinned per query) and reproduces the single-query runs."""
     from repro.core import SOLVERS, register_solver
     X, Y = _stream_problem(b=4, seed=21, n=40, p=150)
     grids = _inside_grids(X, Y, 6)
@@ -243,40 +244,45 @@ def test_fallback_solver_through_strong_rule_path():
 
 
 # ---------------------------------------------------------------------------
-# degenerate B = 1 batch: fast path + parity (ISSUE 6)
+# one query is a batch of one
 # ---------------------------------------------------------------------------
 
-def test_b1_batch_routes_through_single_query_fast_path(monkeypatch):
-    """A (1, n) batch must take the single-query driver (the union-bucketed
-    batched machinery is pure overhead at B = 1 — BENCH_batch.json showed
-    0.2×) while keeping the unified batched result layout."""
+_COUNTERS = ("lam", "n_discarded", "n_kept", "solver_iters", "gap",
+             "kkt_rounds", "x_passes", "gap_checks", "gram_step_frac",
+             "bucket", "solver_x_passes", "batch_size", "queries_converged",
+             "x_passes_per_query", "host_syncs", "member_major_solves")
+
+
+@pytest.mark.parametrize("groups", [None, 4], ids=["lasso", "group"])
+def test_single_query_is_a_batch_of_one(groups):
+    """``path(y)`` and ``path(y[None])`` run the same driver: masks and β
+    bit-identical, the same per-step counters, and the checkpoint hook
+    called with the single-query shapes for the 1-D call."""
     from repro.core import LassoSession
     X, Y = _stream_problem(b=1, seed=17)
-    sess = LassoSession.fit(jnp.asarray(X, jnp.float32))
-    grids = _inside_grids(X, Y, 5)
+    y = jnp.asarray(Y[0])
+    calls = {1: [], 2: []}
 
-    calls = []
-    orig = LassoSession._lasso_path
+    def run(q, key):
+        # a fresh session per call: the Lipschitz eig-cache warms on use,
+        # which shifts the power iteration's start for the next call
+        cfg = PathConfig(solver_tol=1e-8, checkpoint_fn=lambda k, lam, b:
+                         calls[key].append((k, np.shape(lam), b.shape)))
+        sess = LassoSession.fit(jnp.asarray(X, jnp.float32), groups=groups,
+                                config=cfg)
+        return sess.path(q, num_lambdas=6, hi_frac=0.95)
 
-    def spy(self, y, lambdas, cfg, grid_kw):
-        calls.append(np.asarray(y).shape)
-        return orig(self, y, lambdas, cfg, grid_kw)
-
-    monkeypatch.setattr(LassoSession, "_lasso_path", spy)
-    res_b = sess.path(jnp.asarray(Y), grids)        # (1, n) batch
-    assert calls == [(N,)], "B=1 batch must reroute to the single driver"
-    assert res_b.batched and res_b.batch == 1
-    assert res_b.query_converged.shape == (1,)
-
-    # reference from a FRESH session: the Lipschitz eig-cache is warm after
-    # the first call, which shifts the power iteration's start — a fresh
-    # session replays the exact first-use computation
-    sess2 = LassoSession.fit(jnp.asarray(X, jnp.float32))
-    res_1 = sess2.path(jnp.asarray(Y[0]), grids[0])  # direct single query
-    # same driver, same inputs → bitwise identical (β included, not just
-    # the masks-only contract of the true batched driver)
-    np.testing.assert_array_equal(res_b.masks, res_1.masks)
-    np.testing.assert_array_equal(res_b.betas, res_1.betas)
+    one, batch = run(y, 1), run(y[None], 2)
+    assert one.batched and one.batch == 1
+    for attr in ("lambdas", "betas", "masks", "query_converged"):
+        np.testing.assert_array_equal(getattr(one, attr),
+                                      getattr(batch, attr), err_msg=attr)
+    assert any(s.n_kept for s in one.stats)
+    for s1, sb in zip(one.stats, batch.stats, strict=True):
+        for f in _COUNTERS:
+            assert getattr(s1, f) == getattr(sb, f), f
+    assert calls[1] == [(k, (), (P,)) for k in range(6)]
+    assert calls[2] == [(k, (1,), (1, P)) for k in range(6)]
 
 
 def test_query_converged_reports_per_query():

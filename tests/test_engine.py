@@ -1,7 +1,9 @@
 """ScreeningEngine correctness: engine masks must be IDENTICAL to the
 pure-jnp oracle masks of repro.core.screening, for every rule and every
 backend (jnp reference + Pallas interpret), on states built both at λ_max
-and from exact sequential solutions (tests/ref_lasso.py oracles)."""
+and from exact sequential solutions (tests/ref_lasso.py oracles). The
+engines hold a batch of queries; one query is a batch of one, (1, n), and
+its row 0 is compared with the single-query oracle."""
 
 import numpy as np
 import jax.numpy as jnp
@@ -27,6 +29,11 @@ def _problem(seed=0, n=40, p=150):
     return jnp.asarray(X, jnp.float32), jnp.asarray(y, jnp.float32), X, y
 
 
+def _lam(lam):
+    """One query's λ as the engines take it: per-query λ (1,)."""
+    return jnp.asarray([lam], jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # workspace caching
 # ---------------------------------------------------------------------------
@@ -34,20 +41,22 @@ def _problem(seed=0, n=40, p=150):
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_workspace_caches_path_geometry(backend):
     Xf, yf, _, _ = _problem()
-    ws = PathWorkspace(Xf, yf, backend=backend)
+    ws = PathWorkspace(Xf, yf[None], backend=backend)
     np.testing.assert_allclose(np.asarray(ws.col_norms),
                                np.asarray(jnp.linalg.norm(Xf, axis=0)),
                                rtol=2e-5)
     # atol for near-zero correlations: f32 summation order differs per backend
-    np.testing.assert_allclose(np.asarray(ws.abs_xty),
+    np.testing.assert_allclose(np.asarray(ws.abs_xty)[0],
                                np.asarray(jnp.abs(Xf.T @ yf)),
                                rtol=2e-5, atol=1e-4)
-    assert abs(ws.lam_max - float(lambda_max(Xf, yf))) < 1e-4 * ws.lam_max
+    lmax = ws.lam_max[0]
+    assert abs(lmax - float(lambda_max(Xf, yf))) < 1e-4 * lmax
     st = ws.state_at_lambda_max()
     st_ref = DualState.at_lambda_max(Xf, yf)
-    np.testing.assert_allclose(np.asarray(st.theta), np.asarray(st_ref.theta),
-                               rtol=1e-5)
-    np.testing.assert_array_equal(np.asarray(st.v1), np.asarray(st_ref.v1))
+    np.testing.assert_allclose(np.asarray(st.theta)[0],
+                               np.asarray(st_ref.theta), rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(st.v1)[0],
+                                  np.asarray(st_ref.v1))
 
 
 # ---------------------------------------------------------------------------
@@ -58,16 +67,17 @@ def test_workspace_caches_path_geometry(backend):
 @pytest.mark.parametrize("rule", ALL_RULES)
 def test_engine_matches_oracle_from_lmax(rule, backend):
     Xf, yf, _, _ = _problem(seed=1)
-    eng = ScreeningEngine(Xf, yf, backend=backend)
+    eng = ScreeningEngine(Xf, yf[None], backend=backend)
+    lmax = float(eng.lam_max[0])
     state = eng.state_at_lambda_max()
     state_ref = DualState.at_lambda_max(Xf, yf)
     for frac in (0.9, 0.5, 0.15):
-        lam = frac * eng.lam_max
-        got = np.asarray(eng.screen(lam, state, rule))
+        lam = frac * lmax
+        got = np.asarray(eng.screen(_lam(lam), state, rule))[0]
         if rule == "safe":
-            want = safe_mask(Xf, yf, lam, eng.lam_max)
+            want = safe_mask(Xf, yf, lam, lmax)
         elif rule == "dome":
-            want = dome_mask(Xf, yf, lam, eng.lam_max)
+            want = dome_mask(Xf, yf, lam, lmax)
         else:
             want = RULES[rule](Xf, yf, lam, state_ref)
         np.testing.assert_array_equal(got, np.asarray(want), err_msg=rule)
@@ -78,13 +88,13 @@ def test_engine_matches_oracle_from_lmax(rule, backend):
 def test_engine_matches_oracle_sequential(rule, backend):
     """Sequential states from exact float64 solves (ref_lasso oracle)."""
     Xf, yf, X, y = _problem(seed=2)
-    eng = ScreeningEngine(Xf, yf, backend=backend)
-    lmax = eng.lam_max
+    eng = ScreeningEngine(Xf, yf[None], backend=backend)
+    lmax = float(eng.lam_max[0])
     for frac0, frac1 in [(0.7, 0.5), (0.4, 0.2)]:
         beta0 = jnp.asarray(cd_lasso(X, y, frac0 * lmax), jnp.float32)
-        state = eng.make_state(beta0, frac0 * lmax)
+        state = eng.make_state(beta0[None], _lam(frac0 * lmax))
         state_ref = make_dual_state(Xf, yf, beta0, frac0 * lmax, lmax)
-        got = np.asarray(eng.screen(frac1 * lmax, state, rule))
+        got = np.asarray(eng.screen(_lam(frac1 * lmax), state, rule))[0]
         want = np.asarray(RULES[rule](Xf, yf, frac1 * lmax, state_ref))
         np.testing.assert_array_equal(got, want, err_msg=rule)
 
@@ -111,14 +121,14 @@ def test_sphere_constructors_match_masks(backend):
 
 def test_gap_rule_safety_and_discards():
     Xf, yf, X, y = _problem(seed=4, p=200)
-    eng = ScreeningEngine(Xf, yf)
-    lmax = eng.lam_max
+    eng = ScreeningEngine(Xf, yf[None])
+    lmax = float(eng.lam_max[0])
     beta0 = jnp.asarray(cd_lasso(X, y, 0.5 * lmax), jnp.float32)
-    state = eng.make_state(beta0, 0.5 * lmax)
+    state = eng.make_state(beta0[None], _lam(0.5 * lmax))
     lam = 0.4 * lmax
     oracle = cd_lasso(X, y, lam)
     active = np.abs(oracle) > 1e-10
-    mask = np.asarray(eng.screen(lam, state, "gap"))
+    mask = np.asarray(eng.screen(_lam(lam), state, "gap"))[0]
     assert not np.any(mask & active), "gap discarded an active feature"
     assert mask.sum() > 0, "gap should fire near the previous grid point"
 
@@ -160,7 +170,7 @@ def test_engine_single_pass_accounting():
 def test_unknown_backend_raises():
     Xf, yf, _, _ = _problem(seed=7)
     with pytest.raises(ValueError, match="unknown screening backend"):
-        ScreeningEngine(Xf, yf, backend="mosaic-gpu")
+        ScreeningEngine(Xf, yf[None], backend="mosaic-gpu")
 
 
 # ---------------------------------------------------------------------------
@@ -176,14 +186,15 @@ def test_group_engine_matches_oracle(rule, backend):
     y = (X[:, :8] @ rng.uniform(-1, 1, 8)
          + 0.1 * rng.standard_normal(n)).astype(np.float32)
     Xf, yf = jnp.asarray(X), jnp.asarray(y)
-    eng = GroupScreeningEngine(Xf, yf, m, backend=backend)
-    assert abs(eng.lam_max - float(group_lambda_max(Xf, yf, m))) < 1e-5
+    eng = GroupScreeningEngine(Xf, yf[None], m, backend=backend)
+    lmax = float(eng.lam_max[0])
+    assert abs(lmax - float(group_lambda_max(Xf, yf, m))) < 1e-5
     state = eng.state_at_lambda_max()
     state_ref = group_state_at_lambda_max(Xf, yf, m)
     sn = group_spectral_norms(Xf, m)
     for frac in (0.8, 0.4):
-        lam = frac * eng.lam_max
-        got = np.asarray(eng.screen(lam, state, rule))
+        lam = frac * lmax
+        got = np.asarray(eng.screen(_lam(lam), state, rule))[0]
         want = np.asarray(group_screen(Xf, yf, lam, state_ref, m,
                                        rule=rule, spec_norms=sn))
         np.testing.assert_array_equal(got, want, err_msg=rule)

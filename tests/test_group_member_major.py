@@ -25,8 +25,7 @@ from repro.core.group_lasso import (member_major_gap_from_residual,
                                     member_major_norms,
                                     member_major_soft_threshold,
                                     to_group_major, to_member_major)
-from repro.core.solver import (SolveResult, _group_fista_solve,
-                               _group_fista_solve_batched)
+from repro.core.solver import SolveResult, _group_fista_solve_batched
 
 CADENCE = 10
 MAX_ITER = 3000
@@ -35,43 +34,8 @@ MS = (2, 3, 10)
 
 
 # ---------------------------------------------------------------------------
-# The group-major block-FISTA bodies the member-major ones replaced (oracle)
+# The group-major block-FISTA body the member-major one replaced (oracle)
 # ---------------------------------------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("m", "max_iter", "cadence"))
-def group_major_solve(X, y, lam, m, beta0, lipschitz, tol, max_iter,
-                      cadence):
-    dtype = X.dtype
-    step = 1.0 / jnp.maximum(lipschitz, 1e-12)
-    scale = 0.5 * jnp.sum(jnp.square(y)) + 1e-30
-
-    def gap_of(beta):
-        r = y - X @ beta
-        return group_gap_from_residual(r, X.T @ r, beta, lam, m, y)
-
-    def one_step(carry, _):
-        beta, z, t = carry
-        g = X.T @ (X @ z - y)
-        beta_new = group_soft_threshold(z - step * g, step * lam, m)
-        t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
-        z_new = beta_new + ((t - 1.0) / t_new) * (beta_new - beta)
-        return (beta_new, z_new, t_new), None
-
-    def cond(state):
-        _, _, _, k, gap, _ = state
-        return jnp.logical_and(k < max_iter, gap > tol * scale)
-
-    def body(state):
-        beta, z, t, k, _, checks = state
-        (beta, z, t), _ = jax.lax.scan(one_step, (beta, z, t), None,
-                                       length=cadence)
-        return beta, z, t, k + cadence, gap_of(beta), checks + 1
-
-    t0 = jnp.asarray(1.0, dtype=dtype)
-    state = (beta0, beta0, t0, jnp.asarray(0), gap_of(beta0), jnp.asarray(1))
-    beta, _, _, k, gap, checks = jax.lax.while_loop(cond, body, state)
-    return SolveResult(beta, gap, k, gap <= tol * scale, checks)
-
 
 @functools.partial(jax.jit, static_argnames=("m", "max_iter", "cadence"))
 def group_major_solve_batched(X, Y, lam, m, beta0, valid, lipschitz, tol,
@@ -122,18 +86,6 @@ def group_major_solve_batched(X, Y, lam, m, beta0, valid, lipschitz, tol,
     beta, _, _, _, gap, conv, iters, checks = jax.lax.while_loop(
         cond, body, state)
     return SolveResult(beta, gap, iters, conv, checks)
-
-
-def _group_fista_solve_member_major(*args):
-    solve = (_group_fista_solve_batched if len(args) == 10
-             else _group_fista_solve)
-    return solve(*args)
-
-
-def group_major_oracle(*args):
-    solve = (group_major_solve_batched if len(args) == 10
-             else group_major_solve)
-    return solve(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -188,8 +140,8 @@ def _compare(run, Y, tol=TOL):
     both sit within that quarter of the threshold: the gap of an
     accelerated method ripples, and a check on the threshold can fall
     either way under another summation order."""
-    res = run(_group_fista_solve_member_major, tol, MAX_ITER)
-    ref = run(group_major_oracle, tol, MAX_ITER)
+    res = run(_group_fista_solve_batched, tol, MAX_ITER)
+    ref = run(group_major_solve_batched, tol, MAX_ITER)
     beta, beta_ref = np.asarray(res.beta), np.asarray(ref.beta)
     assert beta.shape == beta_ref.shape
     np.testing.assert_allclose(beta, beta_ref, rtol=2e-3,
@@ -214,9 +166,9 @@ def _compare(run, Y, tol=TOL):
                        CADENCE):
             # never stopping (tol < 0): the gaps at iteration k
             g = np.atleast_1d(np.asarray(
-                run(_group_fista_solve_member_major, -1.0, k).gap))[q]
+                run(_group_fista_solve_batched, -1.0, k).gap))[q]
             g_ref = np.atleast_1d(np.asarray(
-                run(group_major_oracle, -1.0, k).gap))[q]
+                run(group_major_solve_batched, -1.0, k).gap))[q]
             assert abs(g - g_ref) <= band[q]
             if (g <= thresh[q]) != (g_ref <= thresh[q]):
                 assert max(abs(g - thresh[q]),
@@ -275,17 +227,22 @@ def test_member_major_prox_and_gap_match_group_major(m):
 # The solves against the group-major oracle
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("B", [4, 1])
 @pytest.mark.parametrize("m", MS)
-def test_batched_member_major_solve_matches_group_major(m):
-    X, Y, lam, L = _bucket(m, seed=100 + m)
+def test_batched_member_major_solve_matches_group_major(m, B):
+    """B = 4 queries with per-query kept groups, and one query as a batch
+    of one (every group kept, the zero-padded tail included)."""
+    X, Y, lam, L = _bucket(m, seed=100 + m if B > 1 else 300 + m, B=B)
     G = X.shape[1] // m
-    valid = _valid(X, Y, lam, L, m, seed=m)
+    valid = (_valid(X, Y, lam, L, m, seed=m) if B > 1
+             else jnp.ones((1, X.shape[1]), jnp.float32))
     beta0 = jnp.zeros_like(valid)
     res, ref = _compare(lambda solve, tol, it: solve(
         X, Y, lam, m, beta0, valid, L, tol, it, CADENCE), Y)
     assert bool(np.all(np.asarray(ref.converged)))
-    # queries stopped at different checks: the early ones froze
-    assert len(set(np.asarray(ref.iters).tolist())) > 1
+    if B > 1:
+        # queries stopped at different checks: the early ones froze
+        assert len(set(np.asarray(ref.iters).tolist())) > 1
     # screened-out and padded groups stay exactly zero
     assert np.all(np.asarray(res.beta)[np.asarray(valid) == 0] == 0.0)
     assert np.all(np.asarray(res.beta)[:, (G - 3) * m:] == 0.0)
@@ -301,18 +258,6 @@ def test_batched_member_major_solve_warm_start_matches_group_major(m):
                                      MAX_ITER, CADENCE).beta
     _compare(lambda solve, tol, it: solve(
         X, Y, lam, m, warm, valid, L, tol, it, CADENCE), Y)
-
-
-@pytest.mark.parametrize("m", MS)
-def test_single_member_major_solve_matches_group_major(m):
-    X, Y, lam, L = _bucket(m, seed=300 + m, B=2)
-    beta0 = jnp.zeros((X.shape[1],), jnp.float32)
-    for q in range(2):
-        _, ref = _compare(lambda solve, tol, it: solve(
-            X, Y[q], lam[q], m, beta0, L, tol, it, CADENCE), Y[q])
-        assert bool(ref.converged)
-    # a zero-padded tail stays zero
-    assert np.all(np.asarray(ref.beta)[(X.shape[1] // m - 3) * m:] == 0.0)
 
 
 def test_unconverged_queries_match_group_major():

@@ -281,11 +281,13 @@ def test_bf16_engine_masks_bit_identical(backend, rule):
     n, p = 48, 320
     X = jnp.asarray(rng.standard_normal((n, p)), jnp.float32)
     y = jnp.asarray(rng.standard_normal(n), jnp.float32)
-    e32 = ScreeningEngine(X, y, backend=backend)
-    e16 = ScreeningEngine(X, y, backend=backend, screen_dtype="bfloat16")
+    # one query: a batch of one
+    e32 = ScreeningEngine(X, y[None], backend=backend)
+    e16 = ScreeningEngine(X, y[None], backend=backend,
+                          screen_dtype="bfloat16")
     st = e32.state_at_lambda_max()
     for frac in (0.8, 0.5, 0.2):
-        lam = frac * e32.lam_max
+        lam = jnp.asarray(frac * e32.lam_max, jnp.float32)
         m32 = np.asarray(e32.screen(lam, st, rule))
         m16 = np.asarray(e16.screen(lam, st, rule))
         np.testing.assert_array_equal(m16, m32, err_msg=f"{rule}@{frac}")
@@ -321,12 +323,12 @@ def test_bf16_adversarial_band_fallback(backend):
     n_plant = 24
     for j, d in enumerate(np.linspace(-band, band, n_plant)):
         X[:, j] = ((alpha_star + d / slope) * yn).astype(np.float32)
-    Xf, yf = jnp.asarray(X), jnp.asarray(y)
+    Xf, yf = jnp.asarray(X), jnp.asarray(y)[None]     # a batch of one
     e32 = ScreeningEngine(Xf, yf, backend=backend)
     e16 = ScreeningEngine(Xf, yf, backend=backend, screen_dtype="bfloat16")
-    lam = 0.5 * e32.lam_max
-    m32 = np.asarray(e32.screen(lam, None, "safe"))
-    m16 = np.asarray(e16.screen(lam, None, "safe"))
+    lam = jnp.asarray(0.5 * e32.lam_max, jnp.float32)
+    m32 = np.asarray(e32.screen(lam, None, "safe"))[0]
+    m16 = np.asarray(e16.screen(lam, None, "safe"))[0]
     np.testing.assert_array_equal(m16, m32)
     assert e16.last_fallback_cols > 0, "planted band never triggered"
     assert e16.last_x_passes == 2      # wide bf16 pass + narrow f32 re-test
@@ -394,12 +396,13 @@ def test_bf16_adversarial_dome_boundary(backend):
     corr = np.abs(X.T @ y)
     assert int(np.argmax(corr)) == istar
     assert float(np.max(corr[cols])) < 0.9 * lmax
-    Xf, yf = jnp.asarray(X), jnp.asarray(y)
+    Xf, yf = jnp.asarray(X), jnp.asarray(y)[None]     # a batch of one
     e32 = ScreeningEngine(Xf, yf, backend=backend)
     e16 = ScreeningEngine(Xf, yf, backend=backend, screen_dtype="bfloat16")
     st = e32.state_at_lambda_max()
-    m32 = np.asarray(e32.screen(lam, st, "dome"))
-    m16 = np.asarray(e16.screen(lam, st, "dome"))
+    lam = jnp.asarray([lam], jnp.float32)
+    m32 = np.asarray(e32.screen(lam, st, "dome"))[0]
+    m16 = np.asarray(e16.screen(lam, st, "dome"))[0]
     np.testing.assert_array_equal(m16, m32)
     assert e16.last_fallback_cols > 0, "planted dome band never triggered"
     planted = m32[cols]
@@ -454,12 +457,13 @@ def test_bf16_adversarial_cut_corner(backend):
     corr2 = np.abs(X.T @ y)
     assert int(np.argmax(corr2)) == istar
     assert float(np.max(corr2[cols])) < 0.9 * lmax
-    Xf, yf = jnp.asarray(X), jnp.asarray(y)
+    Xf, yf = jnp.asarray(X), jnp.asarray(y)[None]     # a batch of one
     e32 = ScreeningEngine(Xf, yf, backend=backend)
     e16 = ScreeningEngine(Xf, yf, backend=backend, screen_dtype="bfloat16")
     st = e32.state_at_lambda_max()
-    m32 = np.asarray(e32.screen(lam, st, "edpp_cut"))
-    m16 = np.asarray(e16.screen(lam, st, "edpp_cut"))
+    lam = jnp.asarray([lam], jnp.float32)
+    m32 = np.asarray(e32.screen(lam, st, "edpp_cut"))[0]
+    m16 = np.asarray(e16.screen(lam, st, "edpp_cut"))[0]
     np.testing.assert_array_equal(m16, m32)
     assert e16.last_fallback_cols > 0, "planted corner band never triggered"
     planted = m32[cols]

@@ -91,14 +91,10 @@ def _run(case, monkeypatch, reference: bool):
                            p):
             size = int(np.asarray(valid).sum())
             col_idx = np.asarray(idx)[:size]
-            if beta_r.ndim == 1:
-                beta_full = (jnp.zeros((p,), Xr.dtype).at[col_idx]
-                             .set(beta_r[:size]))[None, :]
-                fitted = (Xr @ beta_r)[None, :]
-            else:
-                beta_full = (jnp.zeros((beta_r.shape[0], p), Xr.dtype)
-                             .at[:, col_idx].set(beta_r[:, :size]))
-                fitted = beta_r @ Xr.T
+            beta_full = (jnp.zeros((beta_r.shape[0], p), Xr.dtype)
+                         .at[:, col_idx].set(beta_r[:, :size]))
+            fitted = ((Xr @ beta_r[0])[None, :] if beta_r.shape[0] == 1
+                      else beta_r @ Xr.T)
             # the dense copy-out: the step's last solve is what it read
             dense[tracing._local.step.k] = np.asarray(beta_full, np.float64)
             B = beta_full.shape[0]

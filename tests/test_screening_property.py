@@ -12,7 +12,7 @@ import pytest
 
 hypothesis = pytest.importorskip(
     "hypothesis", reason="property tests need the hypothesis package")
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import example, given, settings, strategies as st  # noqa: E402
 
 from repro.core import (DualState, dpp_mask, edpp_mask, imp1_mask, imp2_mask,
                         lambda_max, make_dual_state, v2_perp)
@@ -48,7 +48,7 @@ def _make(n, p, seed, corr):
     return X, y
 
 
-@settings(max_examples=25, deadline=None)
+@settings(max_examples=25, deadline=None, derandomize=True)
 @given(problem)
 def test_safety_from_lambda_max(args):
     n, p, seed, frac, corr = args
@@ -64,7 +64,7 @@ def test_safety_from_lambda_max(args):
         assert not np.any(mask & active), fn.__name__
 
 
-@settings(max_examples=15, deadline=None)
+@settings(max_examples=15, deadline=None, derandomize=True)
 @given(problem)
 def test_safety_sequential(args):
     n, p, seed, frac, corr = args
@@ -82,7 +82,7 @@ def test_safety_sequential(args):
     assert not np.any(mask & active)
 
 
-@settings(max_examples=30, deadline=None)
+@settings(max_examples=30, deadline=None, derandomize=True)
 @given(problem)
 def test_radius_hierarchy(args):
     """‖v₂⊥‖ ≤ ‖v₂‖ and EDPP's radius = ½‖v₂⊥‖ ≤ DPP's |1/λ−1/λ₀|‖y‖."""
@@ -99,8 +99,9 @@ def test_radius_hierarchy(args):
     assert 0.5 * np.linalg.norm(vp) <= dpp_r + 1e-4
 
 
-@settings(max_examples=30, deadline=None)
+@settings(max_examples=30, deadline=None, derandomize=True)
 @given(problem)
+@example((8, 53, 8, 0.0546875, 0.0))   # support of size n: CD alone stalls
 def test_dual_point_feasible(args):
     """θ*(λ) estimated from an exact solve is feasible: ‖Xᵀθ‖∞ ≤ 1+ε."""
     n, p, seed, frac, corr = args

@@ -152,7 +152,7 @@ def test_deadline_fires_partial_batch():
     assert (first.reason, first.n_live, first.padded_b, first.t) \
         == ("deadline", 3, 4, 0.5)
     # the straggler arrives into an exhausted source → drain, unpadded
-    # (1-live batches take the session's B=1 fast path)
+    # (a 1-live batch is a batch of one)
     assert (second.reason, second.n_live, second.padded_b, second.t) \
         == ("drain", 1, 1, 10.0)
     assert [t.latency_s for t in rep.tickets] == [0.5, 0.5, 0.5, 0.0]
@@ -359,7 +359,7 @@ def _queries(X, count, seed=1):
 def test_served_masks_bit_identical_to_direct_session():
     """The exactness contract through the WHOLE serve stack: every served
     query's masks — full fill batch, pow-2-padded partial, and the 1-live
-    drain batch on the session's B=1 fast path — equal a direct
+    drain batch (a batch of one) — equal a direct
     ``session.path`` call on the same grid, bit for bit."""
     import jax.numpy as jnp
     sess, X = _tiny_session()

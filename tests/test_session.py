@@ -399,12 +399,11 @@ def test_grid_endpoint_contract_pins_hi_frac():
     X, Y = _problem(seed=7)
     sess = LassoSession.fit(X)
     # (a) single vs batched λ_max agree to working-precision rounding, not
-    # necessarily bitwise: one comes from a (p,) reduction, the other from
-    # a (B, p) one (f32 on the kernel backends — hence the 1e-6 scale)
+    # necessarily bitwise: one comes from a (1, p) reduction, the other
+    # from a (B, p) one (f32 on the kernel backends — hence the 1e-6 scale)
     from repro.core import ScreeningEngine
-    lm_single = float(ScreeningEngine(X, jnp.asarray(Y[0])).lam_max)
-    lm_batched = float(np.atleast_1d(
-        ScreeningEngine(X, jnp.asarray(Y)).lam_max)[0])
+    lm_single = float(ScreeningEngine(X, jnp.asarray(Y[:1])).lam_max[0])
+    lm_batched = float(ScreeningEngine(X, jnp.asarray(Y)).lam_max[0])
     np.testing.assert_allclose(lm_single, lm_batched, rtol=1e-6)
     # (b) interior grids (hi_frac = 0.95): full bitwise parity
     grids = _grids(X, Y, hi_frac=0.95)

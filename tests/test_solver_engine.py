@@ -3,7 +3,8 @@ strategy × backend results must match the certified float64 oracles
 (tests/ref_lasso.py) to solver tolerance, lasso paths must agree across
 solver backends, the Gram-CD crossover must fire where advertised, warm
 starts must be a no-op at tight tolerance across bucket transitions, and
-the gap-check cadence must be counted in PathStepStats.gap_checks."""
+the gap-check cadence must be counted in PathStepStats.gap_checks. The
+engine holds a batch of queries; one query is a batch of one, (1, n)."""
 
 import numpy as np
 import jax.numpy as jnp
@@ -26,6 +27,11 @@ def _problem(seed=0, n=30, p=80):
     return jnp.asarray(X, jnp.float32), jnp.asarray(y, jnp.float32), X, y
 
 
+def _lam(lam):
+    """One query's λ as the engine takes it: per-query λ (1,)."""
+    return jnp.asarray([lam], jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # engine solve == float64 oracle, strategies × backends
 # ---------------------------------------------------------------------------
@@ -35,16 +41,16 @@ def _problem(seed=0, n=30, p=80):
 def test_engine_matches_oracle(backend, solver):
     Xf, yf, X, y = _problem(seed=1)
     tol = 1e-9 if solver == "fista" else 1e-11
-    eng = SolverEngine(yf, solver=solver, backend=backend, tol=tol,
+    eng = SolverEngine(yf[None], solver=solver, backend=backend, tol=tol,
                        max_iter=20000)
     assert eng.backend_name == backend
     for frac in (0.8, 0.5, 0.2):
         lam = frac * float(lambda_max(Xf, yf))
-        res = eng.solve(Xf, lam)
+        res = eng.solve(Xf, _lam(lam))
         oracle = cd_lasso(X, y, lam)
-        np.testing.assert_allclose(np.asarray(res.beta), oracle,
+        np.testing.assert_allclose(np.asarray(res.beta)[0], oracle,
                                    rtol=2e-3, atol=2e-4)
-        assert float(res.gap) >= -1e-5
+        assert float(res.gap[0]) >= -1e-5
         assert int(res.gap_checks) >= 1
 
 
@@ -55,12 +61,12 @@ def test_group_engine_matches_oracle(backend):
     X = rng.standard_normal((n, p))
     y = X[:, :8] @ rng.uniform(-1, 1, 8) + 0.1 * rng.standard_normal(n)
     Xf, yf = jnp.asarray(X, jnp.float32), jnp.asarray(y, jnp.float32)
-    eng = SolverEngine(yf, solver="group_fista", backend=backend, tol=1e-9,
-                       max_iter=20000)
+    eng = SolverEngine(yf[None], solver="group_fista", backend=backend,
+                       tol=1e-9, max_iter=20000)
     lam = 0.4 * float(group_lambda_max(Xf, yf, m))
-    res = eng.solve(Xf, lam, m=m)
+    res = eng.solve(Xf, _lam(lam), m=m)
     oracle = fista_group(X, y, lam, m)
-    np.testing.assert_allclose(np.asarray(res.beta), oracle,
+    np.testing.assert_allclose(np.asarray(res.beta)[0], oracle,
                                rtol=2e-3, atol=2e-4)
 
 
@@ -70,18 +76,18 @@ def test_group_engine_matches_oracle(backend):
 
 def test_cd_gram_crossover(rng):
     Xf, yf, X, y = _problem(seed=3, n=40, p=120)
-    eng = SolverEngine(yf, solver="cd", backend="jnp", tol=1e-11,
+    eng = SolverEngine(yf[None], solver="cd", backend="jnp", tol=1e-11,
                        max_iter=20000)
     lam = 0.5 * float(lambda_max(Xf, yf))
-    res_wide = eng.solve(Xf, lam)                 # bucket 120 > n 40: matvec
+    res_wide = eng.solve(Xf, _lam(lam))           # bucket 120 > n 40: matvec
     assert not eng.last_used_gram
-    res_narrow = eng.solve(Xf[:, :32], lam)       # bucket 32 ≤ n 40: Gram
+    res_narrow = eng.solve(Xf[:, :32], _lam(lam))  # bucket 32 ≤ n 40: Gram
     assert eng.last_used_gram
     # the two regimes agree where they overlap
     oracle = cd_lasso(X[:, :32], y, lam)
-    np.testing.assert_allclose(np.asarray(res_narrow.beta), oracle,
+    np.testing.assert_allclose(np.asarray(res_narrow.beta)[0], oracle,
                                rtol=2e-3, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(res_wide.beta[:32]),
+    np.testing.assert_allclose(np.asarray(res_wide.beta)[0, :32],
                                np.asarray(cd_lasso(X, y, lam))[:32],
                                rtol=2e-3, atol=2e-4)
     assert eng.gram_solves == 1 and eng.n_solves == 2
@@ -194,13 +200,13 @@ def test_gap_check_cadence_counted():
 # ---------------------------------------------------------------------------
 
 def test_unknown_solver_raises():
-    yf = jnp.zeros((4,), jnp.float32)
+    yf = jnp.zeros((1, 4), jnp.float32)
     with pytest.raises(ValueError, match="unknown solver"):
         SolverEngine(yf, solver="lars")
 
 
 def test_unknown_solver_backend_raises():
-    yf = jnp.zeros((4,), jnp.float32)
+    yf = jnp.zeros((1, 4), jnp.float32)
     with pytest.raises(ValueError, match="unknown solver backend"):
         SolverEngine(yf, backend="mosaic-gpu")
 
@@ -208,9 +214,9 @@ def test_unknown_solver_backend_raises():
 def test_register_solver_dispatches():
     calls = []
 
-    def traced_fista(eng, Xr, lam, beta0, m):
+    def traced_fista(eng, Xr, lam, beta0, valid, m):
         calls.append(Xr.shape)
-        return SOLVERS["fista"](eng, Xr, lam, beta0, m)
+        return SOLVERS["fista"](eng, Xr, lam, beta0, valid, m)
 
     register_solver("traced_fista", traced_fista)
     try:
@@ -245,7 +251,7 @@ def test_power_iteration_warm_start_and_plumbing():
 
 def test_engine_lipschitz_cache_per_bucket():
     Xf, yf, X, y = _problem(seed=12, n=40, p=128)
-    eng = SolverEngine(yf, solver="fista", backend="jnp")
+    eng = SolverEngine(yf[None], solver="fista", backend="jnp")
     L1 = float(eng.lipschitz(Xf[:, :64]))
     assert set(eng._eig_cache) == {64}
     L2 = float(eng.lipschitz(Xf[:, :64]))       # warm re-estimate, same bucket

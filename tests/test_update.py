@@ -121,7 +121,7 @@ def test_oracle_refit_exactness(problem, backend, case):
 
     sess = _fit(X, cfg)
     geom = sess.geometry
-    ws = PathWorkspace(None, y, geometry=geom)
+    ws = PathWorkspace(None, y[None], geometry=geom)     # a batch of one
     wsb = PathWorkspace(None, Y, geometry=geom)
     rep = sess.update(add=add, drop=drop, workspaces=[ws, wsb])
 
@@ -140,7 +140,7 @@ def test_oracle_refit_exactness(problem, backend, case):
     _bitwise(geom.screen_err(jnp.bfloat16), cg.screen_err(jnp.bfloat16),
              "bf16 screen err")
 
-    cws = PathWorkspace(None, y, geometry=cg)
+    cws = PathWorkspace(None, y[None], geometry=cg)
     cwsb = PathWorkspace(None, Y, geometry=cg)
     for carried, fresh, tag in [(ws, cws, "single"), (wsb, cwsb, "batched")]:
         _bitwise(carried.abs_xty, fresh.abs_xty, f"{tag} |Xᵀy|")
@@ -165,18 +165,19 @@ def test_argmax_dropped_rescans(problem, balanced):
     X, y, Y, add3, _ = problem
     cfg = PathConfig(backend="jnp", solver_backend="jnp", solver_tol=1e-8)
     sess = _fit(X, cfg)
-    ws = PathWorkspace(None, y, geometry=sess.geometry)
-    istar = int(ws.istar)
+    ws = PathWorkspace(None, y[None], geometry=sess.geometry)
+    istar = int(ws.istar[0])
     drop = [istar, (istar + 1) % P, (istar + 2) % P] if balanced \
         else [istar]
     rep = sess.update(add=add3 if balanced else None, drop=drop,
                       workspaces=[ws])
     assert rep.argmax_rescans >= 1
     X_ed = edited_oracle(X, drop, add3 if balanced else None)
-    cws = PathWorkspace(None, y,
+    cws = PathWorkspace(None, y[None],
                         geometry=LassoSession.fit(X_ed, config=cfg).geometry)
     _bitwise(ws.abs_xty, cws.abs_xty, "|Xᵀy| after argmax drop")
-    assert ws.istar == cws.istar and ws.lam_max == cws.lam_max
+    _bitwise(ws.istar, cws.istar, "argmax after argmax drop")
+    _bitwise(ws.lam_max, cws.lam_max, "λ_max after argmax drop")
 
 
 def test_balanced_update_skips_rescan(problem):
@@ -185,8 +186,8 @@ def test_balanced_update_skips_rescan(problem):
     X, y, _, add3, _ = problem
     cfg = PathConfig(backend="jnp", solver_backend="jnp")
     sess = _fit(X, cfg)
-    ws = PathWorkspace(None, y, geometry=sess.geometry)
-    istar = int(ws.istar)
+    ws = PathWorkspace(None, y[None], geometry=sess.geometry)
+    istar = int(ws.istar[0])
     drop = sorted({(istar + j) % P for j in (1, 2, 3)})
     rep = sess.update(add=add3, drop=drop, workspaces=[ws])
     assert rep.argmax_rescans == 0
@@ -273,7 +274,7 @@ def test_eig_cache_warm_across_update(problem):
 def test_update_workspace_requires_updated_geometry(problem):
     X, y, _, add3, _ = problem
     sess = _fit(X, PathConfig(backend="jnp"))
-    ws = PathWorkspace(None, y, geometry=sess.geometry)
+    ws = PathWorkspace(None, y[None], geometry=sess.geometry)
     plan, X_add = make_plan(P, add=None, drop=[0, 1])  # p shrinks by 2
     with pytest.raises(ValueError, match="update the geometry first"):
         update_workspace(ws, plan, X_add)
